@@ -119,7 +119,6 @@ class Plan:
 
             programs.bind_strategy(
                 alg, self.fingerprint_key, store=program_store,
-                content_key=programs.matrix_content_key(S),
             )
         return alg
 

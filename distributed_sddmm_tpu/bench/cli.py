@@ -757,7 +757,7 @@ def build_parser() -> argparse.ArgumentParser:
     bf = sub.add_parser(
         "backfill",
         help="ingest the committed historical records (BENCH_r0*.json, "
-        "MULTICHIP_r0*.json, artifacts/bench_midround) into the run store",
+        "MULTICHIP_r0*.json) into the run store",
     )
     _store_arg(bf)
     bf.add_argument("--root", default=None, metavar="DIR",
@@ -913,6 +913,9 @@ def main(argv=None) -> int:
 
     if args.cmd in _BENCH_CMDS:
         from distributed_sddmm_tpu.obs import store as obs_store
+        from distributed_sddmm_tpu.utils.platform import enable_compile_cache
+
+        enable_compile_cache()
 
         if getattr(args, "no_runstore", False):
             # Explicit opt-out must beat the env var: the harness's

@@ -1,97 +1,62 @@
-"""Version-compat shims over the small set of jax APIs that moved.
+"""The few jax entry points the package reaches through one name.
 
-The package targets the current jax surface (top-level ``jax.shard_map``
-with the varying-mesh-axes checker, ``lax.pcast``), but must also run on
-jax 0.4.x containers where ``shard_map`` lives in ``jax.experimental``
-and takes ``check_rep`` instead of ``check_vma``. Everything in the
-package imports these names from here instead of hard-coding one jax
-generation's layout.
+Everything in the package imports these from here, so the keyword
+surface the package relies on lives in one place.
 
-* :func:`shard_map` — accepts the modern keyword surface
-  (``check_vma``); on old jax it maps onto the experimental entry point
-  with ``check_rep=False``. Replication checking is disabled there
-  because the old checker has no equivalent of ``lax.pcast`` for
-  loop-carried inits (see :func:`pvary`), so rolled ring loops cannot
-  satisfy it; the check is a static optimization aid, not a correctness
-  requirement.
+* :func:`shard_map` — ``jax.shard_map`` with the varying-mesh-axes
+  checker (``check_vma``).
 * :func:`pvary` — marks an array device-varying over mesh axes
-  (``lax.pcast(..., to="varying")``). Identity on jax generations whose
-  shard_map has no varying-axes type system: there is nothing to mark.
+  (``lax.pcast(..., to="varying")``), for loop-carried inits of rolled
+  ring loops.
+* :func:`pallas_tpu_compiler_params` — the Mosaic compiler-params struct.
+* :func:`deserialize_and_load` — loads a serialized executable onto the
+  devices it was compiled for.
 """
 
 from __future__ import annotations
 
+import jax
 from jax import lax
-
-try:  # modern jax: top-level export, check_vma keyword
-    from jax import shard_map as _shard_map
-
-    _HAS_VMA = True
-except ImportError:  # jax 0.4.x: experimental module, check_rep keyword
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _HAS_VMA = False
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with one keyword surface across jax generations."""
-    if _HAS_VMA:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    return _shard_map(
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=check_vma,
     )
 
 
-if hasattr(lax, "pcast"):
-
-    def pvary(x, axes):
-        return lax.pcast(x, axes, to="varying")
-
-elif hasattr(lax, "pvary"):
-
-    def pvary(x, axes):
-        return lax.pvary(x, axes)
-
-else:
-
-    def pvary(x, axes):
-        return x
+def pvary(x, axes):
+    return lax.pcast(x, axes, to="varying")
 
 
 def pallas_tpu_compiler_params(**kwargs):
-    """Mosaic compiler-params struct across jax generations: modern jax
-    exports ``pallas.tpu.CompilerParams``, 0.4.x calls the same struct
-    ``TPUCompilerParams`` (and very old generations take a plain dict).
-    """
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cls is None:
-        return dict(kwargs)
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
-def deserialize_and_load(serialized, in_tree, out_tree, *, backend=None,
-                         execution_devices=None):
-    """``jax.experimental.serialize_executable.deserialize_and_load``
-    across jax generations: modern jax takes ``execution_devices``;
-    0.4.x only ``backend`` (the executable's baked-in device assignment
-    applies, which is the single-device case the AOT load path uses)."""
-    import inspect
-
+def deserialize_and_load(serialized, in_tree, out_tree, *, execution_devices):
+    """``serialize_executable.deserialize_and_load`` onto
+    ``execution_devices`` — the devices the executable was compiled for,
+    in its device-assignment order. jax's own default (every device of
+    the client) is wrong for any program compiled for fewer devices: its
+    launch then expects one shard per client device."""
     from jax.experimental import serialize_executable as se
 
-    kwargs = {"backend": backend}
-    if (
-        execution_devices is not None
-        and "execution_devices"
-        in inspect.signature(se.deserialize_and_load).parameters
-    ):
-        kwargs["execution_devices"] = execution_devices
-    return se.deserialize_and_load(serialized, in_tree, out_tree, **kwargs)
+    execution_devices = list(execution_devices)
+    return se.deserialize_and_load(
+        serialized, in_tree, out_tree,
+        backend=execution_devices[0].client,
+        execution_devices=execution_devices,
+    )
+
+
+def executable_device_ids(compiled) -> list[int]:
+    """Ids of the devices ``compiled`` runs on, in assignment order — what
+    :func:`deserialize_and_load` needs back at load time."""
+    try:
+        devices = compiled.runtime_executable().local_devices()
+    except AttributeError:  # compiled for a described, unattached topology
+        devices = jax.tree.leaves(compiled.input_shardings)[0]._device_assignment
+    return [d.id for d in devices]

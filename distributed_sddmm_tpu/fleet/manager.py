@@ -60,6 +60,7 @@ from distributed_sddmm_tpu.dist.elastic import (
     collect_output, free_port, last_json_line, spawn_process,
 )
 from distributed_sddmm_tpu.obs import log as obs_log
+from distributed_sddmm_tpu.utils import platform
 
 
 class Replica:
@@ -216,6 +217,7 @@ class FleetManager:
             env.pop("DSDDMM_TUNER", None)
         if self.env_overlay is not None:
             env.update(self.env_overlay(name, port, role, tuner) or {})
+        self._check_chip_room(env)
         proc = spawn_process(
             list(self.replica_argv(name, port, role)), env=env, cwd=self.cwd,
         )
@@ -226,6 +228,26 @@ class FleetManager:
         obs_log.info("fleet", "replica spawned", name=name, port=port,
                      role=role, generation=generation, tuner=tuner)
         return rep
+
+    def _check_chip_room(self, env: dict) -> None:
+        """Refuse a TPU replica when every chip on the host is taken: a
+        chip belongs to one process, so the extra replica would fail or
+        hang at backend start. Alive replicas hold one chip each, the
+        quarantined ones included, and this process holds whatever TPU
+        devices its own backend took. CPU replicas are not limited."""
+        if not platform.uses_tpu(env):
+            return
+        chips = platform.tpu_chips_on_host(env)
+        replicas = sum(1 for r in self._replicas.values() if r.alive)
+        own = platform.tpu_chips_held()
+        if replicas + own >= chips:
+            raise RuntimeError(
+                f"cannot start another TPU replica: the host's {chips} TPU "
+                f"chip(s) are held by {replicas} replica process(es) and "
+                f"{own} by this process, and a chip serves one process; "
+                "pin this process or the replicas to the CPU with "
+                "JAX_PLATFORMS=cpu, or run fewer replicas"
+            )
 
     def wait_ready(self, timeout_s: float = 120.0,
                    names: Optional[list] = None) -> bool:

@@ -262,7 +262,10 @@ class DistributedALS:
     # ------------------------------------------------------------------ #
 
     def _cg_iter_program(self, mode: MatMode, lam: float):
-        """ONE jitted program for a full CG iteration: the fused Gram
+        """``(prog, operands)``: ONE jitted program for a full CG
+        iteration, ``prog(X, other, r, p, rsold, *operands)``, with the
+        tile operands passed as arguments (see ``DenseShift15D.
+        fused_program``). It chains the fused Gram
         operator (via the strategy's raw ``fused_program``) chained with
         every vector update. Same math as the open-coded loop below —
         the difference is dispatch: one compiled call per iteration
@@ -291,14 +294,14 @@ class DistributedALS:
             return self._cg_programs[key]
         d = self.d_ops
         ones = d.like_s_values(1.0) if mode == MatMode.A else d.like_st_values(1.0)
-        fused = d.fused_program(ones, mode)
+        fused, operands = d.fused_program(ones, mode)
         eps = 1e-8
 
-        def one_iter(X, other, r, p, rsold):
+        def one_iter(X, other, r, p, rsold, *operands):
             if mode == MatMode.A:
-                out, _ = fused(p, other)
+                out, _ = fused(p, other, *operands)
             else:
-                out, _ = fused(other, p)
+                out, _ = fused(other, p, *operands)
             Mp = out + lam * p
             return _cg_vector_update(X, r, p, rsold, Mp, eps)
 
@@ -311,8 +314,8 @@ class DistributedALS:
             d, f"cgStep-{mode.name}-{lam:g}-{'don' if donate else 'nodon'}",
             prog,
         )
-        self._cg_programs[key] = prog
-        return prog
+        self._cg_programs[key] = prog, operands
+        return prog, operands
 
     def _guard_active(self) -> bool:
         if self._guard == "auto":
@@ -343,7 +346,8 @@ class DistributedALS:
             rsold = _batch_dot(r, r)
 
             use_programs = self._use_programs
-            prog = self._cg_iter_program(mode, lam) if use_programs else None
+            prog, operands = (self._cg_iter_program(mode, lam)
+                              if use_programs else (None, ()))
             other = self.B if mode == MatMode.A else self.A
             if use_programs and donation_enabled():
                 # The donating program consumes its carry buffers; the
@@ -362,7 +366,7 @@ class DistributedALS:
                     # B half-steps run the fused pair on the transposed
                     # tiles; the cost-op alias charges that layout's comm.
                     X, r, p, rsold = self.d_ops._timed(
-                        "cgStep", prog, X, other, r, p, rsold,
+                        "cgStep", prog, X, other, r, p, rsold, *operands,
                         _comm_op="cgStep" if mode == MatMode.A else "cgStepB",
                     )
                 else:

@@ -147,7 +147,10 @@ class GAT:
         return jnp.maximum(h, 0)  # gat.hpp:103
 
     def _layer_program(self, i: int):
-        """ONE jitted program for layer ``i``: every head's projection,
+        """``(prog, operands)``: ONE jitted program for layer ``i``,
+        ``prog(X, weights, *operands)``, with the tile operands passed as
+        arguments (see ``DenseShift15D.fused_program``). It runs every
+        head's projection,
         SDDMM logits, LeakyReLU, SpMM aggregation and ReLU, plus the head
         concat — the raw-program composition of
         :meth:`compute_self_attention_head` (same math, one dispatch).
@@ -177,20 +180,20 @@ class GAT:
         mode = MatMode.A
 
         d.set_r_value(layer.input_features)
-        sddmm = d.sddmm_program(mode)
-        spmm = d.spmm_program(mode)
-        ones = d.like_s_values(1.0)
+        sddmm, struct = d.sddmm_program(mode)
+        spmm, _ = d.spmm_program(mode)  # the same tile set's structure
+        operands = (d.like_s_values(1.0), struct)
 
-        def head(X, w):
+        def head(X, w, ones, struct):
             A = d._skew_cols(
                 jnp.einsum("...r,rk->...k", d._unskew_cols(X, mode), w), mode
             )
-            logits = sddmm(A, A, ones)  # A==B: GAT mandates M == N
+            logits = sddmm(A, A, ones, *struct)  # A==B: GAT mandates M == N
             att = jnp.maximum(logits, 0) + jnp.minimum(logits, 0) * alpha
-            return jnp.maximum(spmm(A, att), 0)
+            return jnp.maximum(spmm(A, att, *struct), 0)
 
-        def layer_fn(X, *weights):
-            heads = [head(X, w) for w in weights]
+        def layer_fn(X, weights, ones, struct):
+            heads = [head(X, w, ones, struct) for w in weights]
             return d._skew_cols(
                 jnp.concatenate(
                     [d._unskew_cols(h, mode) for h in heads], axis=-1
@@ -211,8 +214,8 @@ class GAT:
             d, f"gatLayer-{i}-a{alpha:g}-{'don' if donate else 'nodon'}",
             prog,
         )
-        self._layer_programs[key] = prog
-        return prog
+        self._layer_programs[key] = prog, operands
+        return prog, operands
 
     def forward(self, X: jax.Array | None = None) -> jax.Array:
         """Full forward pass (`gat.hpp:106-112`).
@@ -244,10 +247,10 @@ class GAT:
                 # reduction + host sync on the hot path. The layer runs
                 # one fused SDDMM+SpMM pair per head; _pairs scales the
                 # comm/FLOP charge accordingly.
-                prog = self._layer_program(i)
+                prog, operands = self._layer_program(i)
                 d.set_r_value(layer.output_features)
                 X = d._timed(
-                    "gatLayer", prog, X, *layer.weights,
+                    "gatLayer", prog, X, layer.weights, *operands,
                     _pairs=float(layer.num_heads),
                 )
             else:

@@ -30,6 +30,8 @@ _SRC_DIR = pathlib.Path(__file__).parent.parent / "native"
 _lock = threading.Lock()
 _lib = None
 _tried = False
+#: Why the library is not loaded (build or load failure), else None.
+_error: str | None = None
 
 _i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
 _f64p = np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS")
@@ -55,7 +57,9 @@ def _try_build() -> bool:
     """
     if _is_fresh():
         return True
+    global _error
     if not (_SRC_DIR / "Makefile").exists():
+        _error = f"no {_SRC_DIR / 'Makefile'}"
         return False
     tmp = _LIB_DIR / f"libhnh_native.build{os.getpid()}.so"
     try:
@@ -66,14 +70,20 @@ def _try_build() -> bool:
             timeout=120,
         )
         os.replace(tmp, _LIB_PATH)
-    except (subprocess.SubprocessError, OSError):
+    except subprocess.CalledProcessError as e:
         tmp.unlink(missing_ok=True)
+        tail = (e.stderr or b"").decode(errors="replace").strip()
+        _error = f"make failed (rc={e.returncode}): {tail[-500:]}"
+        return False
+    except (subprocess.SubprocessError, OSError) as e:
+        tmp.unlink(missing_ok=True)
+        _error = f"make failed: {type(e).__name__}: {e}"
         return False
     return _LIB_PATH.exists()
 
 
 def _load():
-    global _lib, _tried
+    global _lib, _tried, _error
     with _lock:
         if _tried:
             return _lib
@@ -86,7 +96,8 @@ def _load():
             return None
         try:
             lib = ctypes.CDLL(str(_LIB_PATH))
-        except OSError:
+        except OSError as e:
+            _error = f"load failed: {e}"
             return None
         lib.hnh_rmat.argtypes = [
             ctypes.c_int64, ctypes.c_int64,
@@ -125,6 +136,13 @@ def _load():
 
 def available() -> bool:
     return _load() is not None
+
+
+def build_error() -> str | None:
+    """Why the native library is not in use (None when it is, or when
+    ``HNH_NO_NATIVE=1`` turned it off)."""
+    _load()
+    return _error
 
 
 # --------------------------------------------------------------------- #

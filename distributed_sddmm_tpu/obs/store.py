@@ -531,8 +531,7 @@ _METRIC_RE = (
 
 def _doc_from_headline(run_id: str, parsed: dict, source: str,
                        rc=None, epoch: float = 0.0) -> dict:
-    """One run document from a BENCH_r0x ``parsed`` headline (or the
-    mid-round banked record, same schema). ``epoch`` is a tiny
+    """One run document from a BENCH_r0x ``parsed`` headline. ``epoch`` is a tiny
     deterministic ordinal (round number), NOT the ingest time: history
     sorts by ``created_epoch``, and backfilled rounds must sort *before*
     every live run — `resolve("latest")` returning a years-old record
@@ -584,10 +583,9 @@ def _doc_from_headline(run_id: str, parsed: dict, source: str,
 
 
 def backfill_historical(store: RunStore, root=None) -> list[dict]:
-    """Ingest the committed round 1–5 records — BENCH_r0*.json,
-    MULTICHIP_r0*.json, and the banked mid-round TPU measurement — so
-    ``bench history`` opens with the repo's real trajectory instead of
-    an empty store. Idempotent: run ids are derived from file names, so
+    """Ingest the committed round records — BENCH_r0*.json and
+    MULTICHIP_r0*.json under ``root`` — so ``bench history`` opens with
+    the repo's real trajectory instead of an empty store. Idempotent: run ids are derived from file names, so
     re-running overwrites in place. Returns the ingested documents."""
     root = pathlib.Path(root) if root else _REPO
 
@@ -607,16 +605,6 @@ def backfill_historical(store: RunStore, root=None) -> list[dict]:
             rc=rec.get("rc"), epoch=_round(f.stem),
         )
         docs.append(store.ingest_prebuilt(doc))
-    mid = root / "artifacts" / "bench_midround" / "record.json"
-    try:
-        parsed = json.loads(mid.read_text())
-        docs.append(store.ingest_prebuilt(_doc_from_headline(
-            "backfill-bench-midround-r05", parsed,
-            source="artifacts/bench_midround/record.json",
-            epoch=5.5,  # mid-round 5, between r05 and any live run
-        )))
-    except (OSError, json.JSONDecodeError):
-        pass
     for f in sorted(root.glob("MULTICHIP_r0*.json")):
         try:
             rec = json.loads(f.read_text())

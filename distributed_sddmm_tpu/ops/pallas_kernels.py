@@ -552,6 +552,15 @@ def _attn_call(
     return outs if op == "attn_reduce" else outs[0]
 
 
+#: Chunks whose packed metadata one kernel launch prefetches into scalar
+#: memory (SMEM). The metadata is one int32 per chunk and a v5e core has
+#: 1 MiB of SMEM, which 2^18 chunks fill exactly (the logM=18 R-mat at
+#: 512x512 blocks is 262,144 chunks, and Mosaic refuses it). A longer
+#: chunk list runs as consecutive launches of at most this many chunks
+#: (:func:`_tile_call`).
+MAX_PREFETCH_CHUNKS = 1 << 17
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -569,7 +578,15 @@ def _tile_call(
     (one semaphore round-trip and one chunk-data DMA amortized over G
     chunks); each sub-chunk gets its own bt window via a per-sub-chunk
     BlockSpec, while the at/out windows are shared (gr-aligned groups).
-    Returns op-dependent outputs."""
+    Returns op-dependent outputs.
+
+    A chunk list longer than :data:`MAX_PREFETCH_CHUNKS` runs as one
+    launch per segment of at most that many chunks. Each segment flushes
+    its accumulator at its own ends (its first chunk zeroes, its last
+    flushes), so a row-block group cut by a segment boundary leaves one
+    partial sum in each side's output; a segment's output is read only
+    at the row blocks it flushed (the rest of it is never written), and
+    the partials of a block add up."""
     C = lr.shape[0]
     G = group
     if C % G:
@@ -580,7 +597,6 @@ def _tile_call(
     lc3 = lc.reshape(steps, G, CHUNK)
     sv3 = sv.reshape(steps, G, CHUNK)
 
-    chunk_spec = pl.BlockSpec((1, G, CHUNK), lambda t, m: (t, 0, 0))
     at_spec = pl.BlockSpec((R, bm), lambda t, m: (0, _meta_gr(m, t * G)))
     bt_specs = [
         pl.BlockSpec((R, bn), (lambda j: lambda t, m: (0, _meta_gc(m, t * G + j)))(j))
@@ -588,7 +604,6 @@ def _tile_call(
     ]
     out_spec = pl.BlockSpec((R, bm), lambda t, m: (0, _meta_gr(m, t * G)))
     out_shape = jax.ShapeDtypeStruct((R, gr_blocks * bm), jnp.float32)
-    mid_shape = jax.ShapeDtypeStruct((steps, G, CHUNK), jnp.float32)
 
     if op == "fused":
         if single_step:
@@ -598,16 +613,12 @@ def _tile_call(
                 _make_fused_body_batched if batch_step else _make_fused_body
             )(G, scatter_form)
             scratch = [pltpu.VMEM((R, bm), jnp.float32)]
-        in_specs = [chunk_spec, chunk_spec, chunk_spec, at_spec, *bt_specs]
-        operands = (lr3, lc3, sv3, at, *([bt] * G))
-        out_specs, out_shapes = [out_spec, chunk_spec], [out_shape, mid_shape]
+        dense_specs, dense = [at_spec, *bt_specs], (at, *([bt] * G))
     elif op == "sddmm":
         body = (
             _make_sddmm_body_batched(G) if batch_step else _make_sddmm_body(G)
         )
-        in_specs = [chunk_spec, chunk_spec, chunk_spec, at_spec, *bt_specs]
-        operands = (lr3, lc3, sv3, at, *([bt] * G))
-        out_specs, out_shapes, scratch = [chunk_spec], [mid_shape], []
+        dense_specs, dense, scratch = [at_spec, *bt_specs], (at, *([bt] * G)), []
     elif op == "spmm":
         if single_step:
             body, scratch = _make_spmm_body_single(G, scatter_form), []
@@ -616,29 +627,65 @@ def _tile_call(
                 _make_spmm_body_batched if batch_step else _make_spmm_body
             )(G, scatter_form)
             scratch = [pltpu.VMEM((R, bm), jnp.float32)]
-        in_specs = [chunk_spec, chunk_spec, chunk_spec, *bt_specs]
-        operands = (lr3, lc3, sv3, *([bt] * G))
-        out_specs, out_shapes = [out_spec], [out_shape]
+        dense_specs, dense = list(bt_specs), tuple([bt] * G)
     else:
         raise ValueError(op)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(steps,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
-    outs = pl.pallas_call(
-        body,
-        grid_spec=grid_spec,
-        out_shape=out_shapes,
-        compiler_params=compat.pallas_tpu_compiler_params(
-            dimension_semantics=("arbitrary",)
-        ),
-        interpret=interpret,
-    )(meta, *operands)
-    return outs
+    def launch(m, s0, n):
+        """Steps ``[s0, s0 + n)`` with ``m`` = their metadata."""
+        chunk_in = pl.BlockSpec((1, G, CHUNK), lambda t, m: (t + s0, 0, 0))
+        chunk_out = pl.BlockSpec((1, G, CHUNK), lambda t, m: (t, 0, 0))
+        mid_shape = jax.ShapeDtypeStruct((n, G, CHUNK), jnp.float32)
+        out_specs, out_shapes = {
+            "fused": ([out_spec, chunk_out], [out_shape, mid_shape]),
+            "sddmm": ([chunk_out], [mid_shape]),
+            "spmm": ([out_spec], [out_shape]),
+        }[op]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n,),
+            in_specs=[chunk_in, chunk_in, chunk_in, *dense_specs],
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+        )
+        return pl.pallas_call(
+            body,
+            grid_spec=grid_spec,
+            out_shape=out_shapes,
+            compiler_params=compat.pallas_tpu_compiler_params(
+                dimension_semantics=("arbitrary",)
+            ),
+            interpret=interpret,
+        )(m, lr3, lc3, sv3, *dense)
+
+    seg = max(MAX_PREFETCH_CHUNKS // G, 1)
+    if steps <= seg:
+        return launch(meta, 0, steps)
+
+    outs, mids = [], []
+    for s0 in range(0, steps, seg):
+        n = min(seg, steps - s0)
+        m = meta[s0 * G:(s0 + n) * G]
+        if op != "sddmm" and not single_step:
+            m = m.at[0].set(m[0] | 1).at[-1].set(m[-1] | 2)
+        res = launch(m, s0, n)
+        if op != "spmm":
+            mids.append(res[-1])
+        if op != "sddmm":
+            # Row blocks this segment wrote: every step's window in the
+            # single-step bodies, the flushed groups' windows otherwise.
+            wrote = (jnp.ones(m.shape, bool) if single_step
+                     else ((m >> 1) & 1) == 1)
+            flushed = jnp.zeros(gr_blocks, bool).at[
+                _meta_gr(m, slice(None))].max(wrote)
+            part = res[0].reshape(R, gr_blocks, bm)
+            outs.append(jnp.where(flushed[None, :, None], part, 0.0))
+    result = []
+    if op != "sddmm":
+        result.append(sum(outs[1:], outs[0]).reshape(R, gr_blocks * bm))
+    if op != "spmm":
+        result.append(jnp.concatenate(mids, axis=0))
+    return result
 
 
 def _flat_indices(geom, meta, lr, lc):
@@ -773,11 +820,13 @@ _fused_op.defvjp(_fused_fwd, _fused_bwd)
 class PallasKernel:
     """TPU-native local kernel (one-hot MXU formulation).
 
-    Implements the flat :class:`~distributed_sddmm_tpu.ops.kernels.LocalKernel`
-    protocol by falling back to XLA formulas (so it is a drop-in anywhere),
-    plus the blocked tile-level entry points ``sddmm_tile`` / ``spmm_tile`` /
-    ``fused_tile`` that the distributed algorithms call when blocked
-    metadata is available.
+    Its entry points are the blocked tile-level ones (``sddmm_tile`` /
+    ``spmm_tile`` / ``fused_tile``) that the distributed algorithms call
+    on tile sets carrying blocked metadata. The flat
+    :class:`~distributed_sddmm_tpu.ops.kernels.LocalKernel` protocol runs
+    XLA formulas, and only in interpreter mode (CPU test meshes): on the
+    TPU a tile set without blocked metadata raises instead of running XLA
+    under the Pallas name.
 
     ``precision``: "bf16" (exact one-hot selection, dense values rounded to
     bf16) or "f32" (full f32 MXU, ~4x slower). Default: bf16 on TPU, f32 in
@@ -833,13 +882,24 @@ class PallasKernel:
         self._xla = XlaKernel()
         self.name = f"pallas-{precision}"
 
-    # -------------------- flat protocol (XLA fallback) ------------------- #
+    # ------------- flat protocol (XLA, interpreter mode only) ------------ #
+
+    def _flat(self) -> XlaKernel:
+        if not self.interpret:
+            raise RuntimeError(
+                f"{self.name}: this tile set has no blocked (chunk-list) "
+                "metadata, so the Pallas kernel cannot run it, and on the "
+                "TPU it does not fall back to XLA under its own name. The "
+                "blocked build was skipped (see parallel/sharding."
+                "_try_build_blocked); pass kernel='xla' to run it on XLA."
+            )
+        return self._xla
 
     def sddmm(self, rows, cols, vals, A, B):
-        return self._xla.sddmm(rows, cols, vals, A, B)
+        return self._flat().sddmm(rows, cols, vals, A, B)
 
     def spmm(self, rows, cols, vals, B, out_rows: int):
-        return self._xla.spmm(rows, cols, vals, B, out_rows)
+        return self._flat().spmm(rows, cols, vals, B, out_rows)
 
     # ----------------------- blocked tile protocol ----------------------- #
 
@@ -945,13 +1005,14 @@ class PallasKernel:
         )
         return self._unchunk(blk, probs, out_dtype)
 
-    # Flat-protocol attention softmax (XLA fallback, like sddmm/spmm).
+    # Flat-protocol attention softmax (interpreter mode only, like
+    # sddmm/spmm).
 
     def attn_stats(self, rows, gate, logits, out_rows: int):
-        return self._xla.attn_stats(rows, gate, logits, out_rows)
+        return self._flat().attn_stats(rows, gate, logits, out_rows)
 
     def attn_normalize(self, rows, gate, logits, m, d):
-        return self._xla.attn_normalize(rows, gate, logits, m, d)
+        return self._flat().attn_normalize(rows, gate, logits, m, d)
 
     def attn_softmax(self, rows, gate, logits, out_rows: int):
-        return self._xla.attn_softmax(rows, gate, logits, out_rows)
+        return self._flat().attn_softmax(rows, gate, logits, out_rows)

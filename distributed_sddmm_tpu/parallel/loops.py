@@ -141,8 +141,8 @@ def abl_psum_scatter(x, axis_name, *, scatter_dimension, tiled=True, size,
 
 def vary(x, axes):
     """Mark loop-carry inits as device-varying over ``axes`` so rolled
-    fori_loop carries type-match after collectives touch them (identity on
-    jax generations without the varying-axes type system — compat.pvary)."""
+    fori_loop carries type-match after collectives touch them
+    (``lax.pcast(..., to="varying")`` via compat.pvary)."""
     from distributed_sddmm_tpu.compat import pvary
 
     return pvary(x, axes)

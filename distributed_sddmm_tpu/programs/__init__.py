@@ -29,7 +29,6 @@ from distributed_sddmm_tpu.programs.store import (  # noqa: F401
     cost_log_len,
     disable,
     enable,
-    matrix_content_key,
     stored,
     strategy_config_tag,
     xla_cost_summary,

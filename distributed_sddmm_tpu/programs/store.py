@@ -23,9 +23,9 @@ suite):
   wrong-backend entry reads as a **miss and is evicted**; the caller
   falls through to a live compile. The store is a pure accelerator —
   it can cost a compile, never an error,
-* deserialization runs through ``compat.deserialize_and_load`` (the
-  jax-generation shim), and any failure there also evicts and falls
-  through.
+* deserialization runs through ``compat.deserialize_and_load`` onto
+  the device ids the entry recorded at save time, and any failure there
+  also evicts and falls through.
 
 Counters land in ``obs.metrics.GLOBAL``: ``program_store_hits`` (disk),
 ``program_store_misses`` (absent/evicted), ``live_compiles`` (an
@@ -65,7 +65,7 @@ from distributed_sddmm_tpu.programs import keys as keys_mod
 from distributed_sddmm_tpu.utils.atomic import atomic_write_bytes, atomic_write_json
 
 #: Entry payload schema generation; readers evict entries they cannot read.
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2  # 2: entries carry the executable's device ids
 
 _REPO = pathlib.Path(__file__).resolve().parents[2]
 DEFAULT_ROOT = _REPO / "artifacts" / "programs"
@@ -331,7 +331,8 @@ class ProgramStore:
         Never raises for entry-content reasons.
 
         ``device`` pins deserialization to one device (the bench AOT
-        re-homing path); default is the process's first device.
+        re-homing path); default is the devices the executable was
+        compiled for, by the ids the entry recorded.
         """
         from distributed_sddmm_tpu import compat
         from distributed_sddmm_tpu.obs import log as obs_log
@@ -360,13 +361,13 @@ class ProgramStore:
             import jax
 
             serialized, in_tree, out_tree = entry["payload"]
-            client = (
-                device.client if device is not None
-                else jax.devices()[0].client
-            )
+            if device is not None:
+                devices = [device]
+            else:
+                by_id = {d.id: d for d in jax.devices()}
+                devices = [by_id[i] for i in entry["device_ids"]]
             loaded = compat.deserialize_and_load(
-                serialized, in_tree, out_tree, backend=client,
-                execution_devices=[device] if device is not None else None,
+                serialized, in_tree, out_tree, execution_devices=devices,
             )
         except Exception as e:  # noqa: BLE001 — any failure -> live compile
             obs_log.warn(
@@ -412,6 +413,8 @@ class ProgramStore:
         try:
             from jax.experimental import serialize_executable as se
 
+            from distributed_sddmm_tpu import compat
+
             payload = se.serialize(compiled)
             cost = _cost_analysis(compiled)
             register_cost(key, cost)
@@ -423,6 +426,7 @@ class ProgramStore:
                 "meta": dict(meta or {}),
                 "cost": cost,
                 "payload": payload,
+                "device_ids": compat.executable_device_ids(compiled),
             }
             atomic_write_bytes(self._path(key), pickle.dumps(entry))
             self._update_index(entry)
@@ -635,32 +639,8 @@ def strategy_config_tag(alg) -> str:
     return "-".join(bits)
 
 
-def matrix_content_key(S) -> str:
-    """Content digest of one sparse matrix (indices + values + shape).
-
-    The strategy's shard_map programs take the tile arrays as
-    *arguments*, so their store entries are content-generic — but the
-    jit-chained app programs (``cgStep``, ``gatLayer``) trace through
-    the raw-program accessors' closures and bake the concrete tile
-    index/mask arrays into the executable as constants. Two matrices
-    with identical coarse fingerprints (same M, N, nnz, R, p) would
-    otherwise alias one chained entry and serve the wrong sparsity
-    pattern; this digest keys them apart.
-    """
-    import hashlib
-
-    import numpy as np
-
-    h = hashlib.sha256()
-    for arr in (S.rows, S.cols, S.vals):
-        h.update(np.ascontiguousarray(arr).tobytes())
-    h.update(f"{S.M}x{S.N}".encode())
-    return h.hexdigest()[:12]
-
-
 def bind_strategy(alg, fingerprint_key: str,
-                  store: "ProgramStore | None" = None,
-                  content_key: str | None = None) -> bool:
+                  store: "ProgramStore | None" = None) -> bool:
     """Install a program binder on a strategy: every shard_map program
     the strategy builds from now on resolves through the store under
     ``plan:<fingerprint_key>:<config>-<op>:<sig>`` keys. Returns False
@@ -703,10 +683,6 @@ def bind_strategy(alg, fingerprint_key: str,
     alg._program_store_meta = {
         "store": store, "fingerprint_key": fingerprint_key,
         "config": cfg, "backend": backend, "dist": dist,
-        # Matrix-content digest (:func:`matrix_content_key`), consumed
-        # by :func:`chained_program` — see there for why the chains
-        # need it and the strategy programs do not.
-        "content": content_key or "",
     }
     return True
 
@@ -717,21 +693,17 @@ def chained_program(alg, op: str, jit_fn):
     (fingerprint + config tag) PLUS the ``models/`` code generation —
     the chain bakes the app-side math (CG vector algebra, the GAT layer
     body) into the executable, which the plan-scope ``code_hash`` in
-    the fingerprint deliberately does not cover. Returns ``jit_fn``
+    the fingerprint deliberately does not cover. The tile operands are
+    the chain's arguments, as they are the strategy programs', so an
+    entry serves any matrix of the same shapes. Returns ``jit_fn``
     unchanged when the strategy is unbound — the pre-store behavior,
     byte for byte."""
     meta = getattr(alg, "_program_store_meta", None)
     if not meta:
         return jit_fn
-    if not meta.get("content"):
-        # No content digest recorded at bind time: the chain would bake
-        # this matrix's tile constants under a content-blind key — a
-        # same-shape different-content matrix could then recall the
-        # wrong sparsity pattern. Stay on the plain jit instead.
-        return jit_fn
     from distributed_sddmm_tpu.autotune.fingerprint import models_code_hash
 
-    op = f"{op}-m{models_code_hash()}-x{meta['content']}"
+    op = f"{op}-m{models_code_hash()}"
 
     def key_fn(sig: str) -> str:
         return keys_mod.plan_program_key(

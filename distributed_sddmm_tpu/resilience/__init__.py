@@ -3,7 +3,7 @@
 The production counterpart of the reference's healthy-MPI-world assumption
 (Bharadwaj et al., IPDPS 2022 run from step 0 on a clean communicator):
 the strategies, apps, bench harness, and autotuner all execute through
-this package's hooks so that a preempted chip, a flaky tunneled backend, a
+this package's hooks so that a preempted chip, a lost device, a
 torn cache write, or a diverging solver degrades a run instead of
 poisoning or hanging it.
 

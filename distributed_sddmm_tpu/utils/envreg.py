@@ -224,9 +224,6 @@ KNOBS: dict[str, Knob] = {k.name: k for k in [
     _K("DSDDMM_MP_INIT_TIMEOUT", "int", "300",
        "jax.distributed init timeout for the two-process test worker",
        scope="test"),
-    _K("DSDDMM_TPU_BANK_WINDOW", "flag", "0",
-       "declare a live TPU window: banked-record staleness becomes a "
-       "hard failure (test_banked_record.py)", scope="test"),
 ]}
 
 

@@ -15,6 +15,17 @@ import numpy as np
 from distributed_sddmm_tpu.utils.coo import HostCOO
 
 
+#: Nonzeros per slice in the oracles below. Slicing bounds the
+#: ``[slice, R]`` row gathers (a whole-matrix gather of an 8 M-nonzero
+#: matrix at R=128 is 8.6 GB in float64) and keeps every element's
+#: arithmetic and accumulation order unchanged.
+_SLICE = 1 << 18
+
+
+def _slices(nnz: int):
+    return (slice(lo, lo + _SLICE) for lo in range(0, nnz, _SLICE))
+
+
 def sddmm(S: HostCOO, A: np.ndarray, B: np.ndarray) -> np.ndarray:
     """``out_vals[k] = S.vals[k] * <A[S.rows[k], :], B[S.cols[k], :]>``.
 
@@ -22,21 +33,25 @@ def sddmm(S: HostCOO, A: np.ndarray, B: np.ndarray) -> np.ndarray:
     values, then are multiplied elementwise by the input values
     (`sparse_kernels.cpp:44-55`, `15D_dense_shift.hpp:364-368`).
     """
-    dots = np.einsum("kr,kr->k", A[S.rows], B[S.cols])
+    dots = np.empty(S.nnz, dtype=np.result_type(A, B))
+    for sl in _slices(S.nnz):
+        dots[sl] = np.einsum("kr,kr->k", A[S.rows[sl]], B[S.cols[sl]])
     return S.vals * dots
 
 
 def spmm_a(S: HostCOO, B: np.ndarray, A_in: np.ndarray | None = None) -> np.ndarray:
     """``A += S @ B`` (accumulate semantics, beta=1; `sparse_kernels.cpp:94-121`)."""
     out = np.zeros((S.M, B.shape[1])) if A_in is None else A_in.copy()
-    np.add.at(out, S.rows, S.vals[:, None] * B[S.cols])
+    for sl in _slices(S.nnz):
+        np.add.at(out, S.rows[sl], S.vals[sl, None] * B[S.cols[sl]])
     return out
 
 
 def spmm_b(S: HostCOO, A: np.ndarray, B_in: np.ndarray | None = None) -> np.ndarray:
     """``B += S^T @ A``."""
     out = np.zeros((S.N, A.shape[1])) if B_in is None else B_in.copy()
-    np.add.at(out, S.cols, S.vals[:, None] * A[S.rows])
+    for sl in _slices(S.nnz):
+        np.add.at(out, S.cols[sl], S.vals[sl, None] * A[S.rows[sl]])
     return out
 
 

@@ -47,12 +47,36 @@ def fingerprint_algorithm(alg, S: HostCOO) -> dict[str, float]:
     _, yb = alg.de_shift(None, yb, KernelMode.SPMM_B)
     out["spmmB"] = oracle.fingerprint(alg.host_b(yb))
 
-    A_s, B_s = alg.initial_shift(A, B, KernelMode.SDDMM_A)
-    fz, fmid = alg.fused_spmm(A_s, B_s, s_ones, MatMode.A)
-    fz, _ = alg.de_shift(fz, None, KernelMode.SPMM_A)
-    out["fusedSpMM"] = oracle.fingerprint(alg.host_a(fz))
-    out["fusedSpMM_mid"] = oracle.fingerprint(alg.gather_s_values(fmid))
+    out.update(fused_fingerprints(alg))
     return out
+
+
+def fused_fingerprints(alg) -> dict[str, float]:
+    """The fused SDDMM->SpMM pair's two fingerprints (``fusedSpMM`` and
+    its SDDMM half ``fusedSpMM_mid``) on dummy-initialized operands —
+    the part of the verify protocol the headline program runs."""
+    A = alg.dummy_initialize(MatMode.A)
+    B = alg.dummy_initialize(MatMode.B)
+    A_s, B_s = alg.initial_shift(A, B, KernelMode.SDDMM_A)
+    fz, fmid = alg.fused_spmm(A_s, B_s, alg.like_s_values(1.0), MatMode.A)
+    fz, _ = alg.de_shift(fz, None, KernelMode.SPMM_A)
+    return {
+        "fusedSpMM": oracle.fingerprint(alg.host_a(fz)),
+        "fusedSpMM_mid": oracle.fingerprint(alg.gather_s_values(fmid)),
+    }
+
+
+def oracle_fused_fingerprints(S: HostCOO, R: int) -> dict[str, float]:
+    """:func:`fused_fingerprints` computed by the host oracle."""
+    A = oracle.dummy_dense(S.M, R)
+    B = oracle.dummy_dense(S.N, R)
+    S1 = S.with_values(np.ones_like(S.vals))
+    mid = oracle.sddmm(S1, A, B)
+    return {
+        "fusedSpMM": oracle.fingerprint(
+            oracle.spmm_a(S1.with_values(mid), B)),
+        "fusedSpMM_mid": oracle.fingerprint(mid),
+    }
 
 
 def oracle_fingerprints(S: HostCOO, R: int) -> dict[str, float]:
@@ -61,13 +85,12 @@ def oracle_fingerprints(S: HostCOO, R: int) -> dict[str, float]:
     A = oracle.dummy_dense(S.M, R)
     B = oracle.dummy_dense(S.N, R)
     S1 = S.with_values(np.ones_like(S.vals))
-    mid = oracle.sddmm(S1, A, B)
+    fused = oracle_fused_fingerprints(S, R)
     return {
-        "sddmmA": oracle.fingerprint(mid),
+        "sddmmA": fused["fusedSpMM_mid"],
         "spmmA": oracle.fingerprint(oracle.spmm_a(S1, B)),
         "spmmB": oracle.fingerprint(oracle.spmm_b(S1, A)),
-        "fusedSpMM": oracle.fingerprint(oracle.fused_spmm_a(S1, A, B)),
-        "fusedSpMM_mid": oracle.fingerprint(mid),
+        **fused,
     }
 
 

@@ -5,7 +5,7 @@ every distinct strategy program it touches: 6 for ALS (sddmm/spmm/fused,
 both orientations), 1 per heatmap R value. This script builds those
 executables locally against a v5e topology device — the run_pallas.py
 retarget pattern — so the TPU process can `inject_program` them and spend
-the health window measuring (GAT is excluded: its per-layer feature widths
+its chip time measuring (GAT is excluded: its per-layer feature widths
 retrace, and the injection wrapper's jit fallback covers it anyway).
 
 CPU-pinned. The DenseShift15D arg orders below mirror its public op

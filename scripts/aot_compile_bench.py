@@ -3,12 +3,10 @@
 Companion to bench.py's BENCH_AOT_DIR mode: construct the identical
 headline strategy (bench.build_headline, same env knobs), retarget its
 mesh at one v5e topology device (the run_pallas.py pattern), and
-AOT-compile + serialize `bench.make_headline_chain` for both trip counts —
-locally, in seconds, while the on-device route costs minutes of remote
-Mosaic compile per distinct program.
+AOT-compile + serialize `bench.make_headline_chain` for both trip counts,
+on a host without a chip. Point the worker at OUT_DIR with BENCH_AOT_DIR.
 
-CPU-pinned; invoked by bench.py's orchestrator when AOT_LOAD.json records
-that re-homed loads work on this backend.
+CPU-pinned.
 
 Usage: python scripts/aot_compile_bench.py OUT_DIR
 Env: BENCH_LOG_M/BENCH_NNZ_PER_ROW/BENCH_R/BENCH_TRIALS + DSDDMM_* knobs,
@@ -49,8 +47,8 @@ def main() -> int:
     from distributed_sddmm_tpu.parallel.mesh import make_grid
 
     # Compile exactly what the on-device worker would run: get_kernel
-    # ("auto") resolves to the bf16 Mosaic kernel on TPU; the Mosaic-outage
-    # rescue rung exports BENCH_KERNEL=xla and gets the flat XLA program.
+    # ("auto") resolves to the bf16 Mosaic kernel on TPU; BENCH_KERNEL=xla
+    # gets the flat XLA program.
     if os.environ.get("BENCH_KERNEL", "auto") == "xla":
         kernel = XlaKernel()
     else:

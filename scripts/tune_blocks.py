@@ -11,8 +11,8 @@ When ``TUNE_LOAD_DIR`` is set, the chained-trial programs are NOT compiled
 on-device: pre-serialized AOT executables (built offline by
 `scripts/aot_compile_kernels.py`, validated by `scripts/aot_load_probe.py`)
 are loaded onto the chip instead — same programs, same timing protocol
-(`bench.aot.chain_time_loaded`), minutes of remote Mosaic compile saved per
-config. Any load failure falls back to the on-device path.
+(`bench.aot.chain_time_loaded`), without compiling on the chip's clock.
+Any load failure falls back to the on-device path.
 
 Usage: python scripts/tune_blocks.py [logM npr R trials]
 """

@@ -25,6 +25,12 @@ os.environ["DSDDMM_RUNSTORE"] = "0"
 # the store construct ProgramStore(tmp_path) or re-enable explicitly.
 os.environ["DSDDMM_PROGRAMS"] = "0"
 
+# And for JAX's persistent compilation cache, should the environment name
+# one: XLA:CPU executables read back from it can fail to run (see
+# utils.platform.enable_compile_cache). The env form reaches the bench
+# subprocesses tests start, too.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+
 from distributed_sddmm_tpu.utils.platform import force_cpu_platform  # noqa: E402
 
 force_cpu_platform(n_devices=8, replace=True)

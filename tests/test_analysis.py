@@ -397,8 +397,8 @@ def test_render_markdown_scope():
 
     runtime = envreg.render_markdown()
     test = envreg.render_markdown(scope="test")
-    assert "DSDDMM_TPU_BANK_WINDOW" not in runtime
-    assert "DSDDMM_TPU_BANK_WINDOW" in test
+    assert "DSDDMM_MP_INIT_TIMEOUT" not in runtime
+    assert "DSDDMM_MP_INIT_TIMEOUT" in test
     assert "DSDDMM_TRACE" not in test
 
 

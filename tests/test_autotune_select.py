@@ -108,7 +108,7 @@ def test_rank_prefers_cheaper_communication():
 
 
 def test_measure_timeout_degrades_to_model_ranking(tmp_path):
-    """Flaky backend simulation: every trial times out; selection falls
+    """Failing-backend simulation: every trial times out; selection falls
     back to the cost model instead of raising or hanging, and the backoff
     path was exercised."""
     S = HostCOO.rmat(log_m=6, edge_factor=4, seed=0)

@@ -125,6 +125,41 @@ def test_cli_verify(capsys):
     assert "OK" in capsys.readouterr().out
 
 
+class TestHeadlineOrchestrator:
+    """bench.py measures the chip or nothing, and its parent process
+    leaves the chip to the worker."""
+
+    ROOT = __import__("pathlib").Path(__file__).resolve().parents[1]
+
+    def test_exits_nonzero_without_tpu(self):
+        import subprocess
+        import sys
+
+        proc = subprocess.run(
+            [sys.executable, str(self.ROOT / "bench.py")],
+            capture_output=True, text=True, timeout=300,
+            env={**__import__("os").environ, "JAX_PLATFORMS": "cpu"},
+        )
+        assert proc.returncode != 0
+        assert proc.stdout.strip() == ""  # no record, live or banked
+        assert "no attempt produced a TPU record" in proc.stderr
+
+    def test_orchestrator_never_imports_jax(self):
+        import subprocess
+        import sys
+
+        code = (
+            "import importlib.util, sys\n"
+            f"spec = importlib.util.spec_from_file_location('b', "
+            f"{str(self.ROOT / 'bench.py')!r})\n"
+            "m = importlib.util.module_from_spec(spec)\n"
+            "spec.loader.exec_module(m)\n"
+            "m._knob_env_defaults(); m._best_measured_env()\n"
+            "assert 'jax' not in sys.modules, 'orchestrator imported jax'\n"
+        )
+        subprocess.run([sys.executable, "-c", code], check=True, timeout=60)
+
+
 class TestBestMeasuredEnv:
     """bench.py steers the headline measurement from KERNELS_TPU.jsonl; the
     selection must pick the fastest matching Pallas record and tolerate

@@ -39,11 +39,12 @@ def test_cg_step_donates_all_four_carries():
     m = DistributedALS(alg, S_host=S)
     m.initialize_embeddings()
     assert donation_enabled()
-    prog = m._cg_iter_program(MatMode.A, m.ridge_lambda)
+    prog, ops = m._cg_iter_program(MatMode.A, m.ridge_lambda)
     X = m.A
     rsold = jnp.zeros(X.shape[:-1], jnp.float32)
-    hlo = prog.lower(X, m.B, X, X, rsold).compile().as_text()
-    # X (0), r (2), p (3), rsold (4) donate; `other` (1) must NOT.
+    hlo = prog.lower(X, m.B, X, X, rsold, *ops).compile().as_text()
+    # X (0), r (2), p (3), rsold (4) donate; `other` (1) and the tile
+    # operands must NOT.
     assert _aliased_params(hlo) == [0, 2, 3, 4]
 
 
@@ -51,9 +52,9 @@ def test_gat_square_layer_donates_activation_carry():
     S = HostCOO.erdos_renyi(64, 64, 5, seed=2, values="normal")
     layers = [GATLayer(input_features=8, features_per_head=4, num_heads=2)]
     gat = GAT(layers, DenseShift15D(S, R=8, c=1, fusion_approach=2))
-    prog = gat._layer_program(0)
+    prog, ops = gat._layer_program(0)
     X = gat.d_ops.dummy_initialize(MatMode.A)
-    hlo = prog.lower(X, *layers[0].weights).compile().as_text()
+    hlo = prog.lower(X, layers[0].weights, *ops).compile().as_text()
     assert _aliased_params(hlo) == [0]
 
 
@@ -64,10 +65,10 @@ def test_gat_nonsquare_layer_skips_donation():
     S = HostCOO.erdos_renyi(64, 64, 5, seed=2, values="normal")
     layers = [GATLayer(input_features=8, features_per_head=8, num_heads=2)]
     gat = GAT(layers, DenseShift15D(S, R=8, c=1, fusion_approach=2))
-    prog = gat._layer_program(0)
+    prog, ops = gat._layer_program(0)
     gat.d_ops.set_r_value(layers[0].input_features)
     X = gat.d_ops.dummy_initialize(MatMode.A)
-    hlo = prog.lower(X, *layers[0].weights).compile().as_text()
+    hlo = prog.lower(X, layers[0].weights, *ops).compile().as_text()
     assert not any("input_output_alias" in l for l in hlo.splitlines()[:1]) \
         or _aliased_params(hlo) == []
 
@@ -132,9 +133,9 @@ def test_donation_kill_switch(monkeypatch):
     alg = DenseShift15D(S, R=8, c=1, fusion_approach=2)
     m = DistributedALS(alg, S_host=S)
     m.initialize_embeddings()
-    prog = m._cg_iter_program(MatMode.A, m.ridge_lambda)
+    prog, ops = m._cg_iter_program(MatMode.A, m.ridge_lambda)
     X = m.A
     rsold = jnp.zeros(X.shape[:-1], jnp.float32)
-    hlo = prog.lower(X, m.B, X, X, rsold).compile().as_text()
+    hlo = prog.lower(X, m.B, X, X, rsold, *ops).compile().as_text()
     header = hlo.splitlines()[0]
     assert "input_output_alias" not in header

@@ -91,6 +91,44 @@ class TestLifecycle:
         assert manager.replicas() == []
 
 
+class TestChipGuard:
+    @pytest.mark.parametrize("platforms,refused", [("tpu", True),
+                                                   ("cpu", False)])
+    def test_tpu_replicas_capped_at_chip_count(self, monkeypatch,
+                                               platforms, refused):
+        from distributed_sddmm_tpu.utils import platform
+
+        monkeypatch.setattr(platform, "tpu_chips_on_host", lambda env: 1)
+        monkeypatch.setattr(platform, "tpu_chips_held", lambda: 0)
+        mgr = FleetManager(
+            _argv, tuner_canary=False,
+            env_overlay=lambda *a: {"JAX_PLATFORMS": platforms})
+        try:
+            mgr.spawn()
+            if refused:
+                with pytest.raises(RuntimeError, match="TPU chip"):
+                    mgr.spawn()
+                assert len(mgr.replicas()) == 1
+            else:
+                mgr.spawn()
+                assert len(mgr.replicas()) == 2
+        finally:
+            mgr.stop_all(timeout_s=10.0)
+
+
+    def test_parent_holding_the_chip_refuses_the_first_replica(
+            self, monkeypatch):
+        from distributed_sddmm_tpu.utils import platform
+
+        monkeypatch.setattr(platform, "tpu_chips_on_host", lambda env: 1)
+        monkeypatch.setattr(platform, "tpu_chips_held", lambda: 1)
+        mgr = FleetManager(_argv, tuner_canary=False,
+                           env_overlay=lambda *a: {"JAX_PLATFORMS": "tpu"})
+        with pytest.raises(RuntimeError, match="1 by this process"):
+            mgr.spawn()
+        assert mgr.replicas() == []
+
+
 class TestGrayFailure:
     def test_quarantine_lifecycle(self, manager):
         manager.spawn()

@@ -310,16 +310,31 @@ class TestSuppression:
         assert obs_store.active() is not None
 
 
+def _write_rounds(root):
+    """Round records in the committed files' shape (bench.py's parsed
+    headline; the multi-chip dry-run verdict)."""
+    head = ("fused SDDMM+SpMM GFLOP/s/chip (R-mat 2^16, nnz/row=32, "
+            "R=128, pallas-bf16 kernel, 1 tpu chip(s))")
+    for n, value in ((1, 0.0), (5, 168.729)):
+        (root / f"BENCH_r0{n}.json").write_text(json.dumps({
+            "rc": 0, "parsed": {
+                "metric": head, "value": value, "unit": "GFLOP/s/chip",
+                "vs_baseline": value / 6.47, "backend": "tpu",
+                "code_hash": "02ab040eb9"}}))
+    (root / "MULTICHIP_r05.json").write_text(json.dumps(
+        {"n_devices": 8, "rc": 0, "ok": True, "skipped": False}))
+    return root
+
+
 class TestBackfill:
     def test_backfill_ingests_committed_rounds(self, tmp_path):
-        """The repo's own BENCH_r0*/MULTICHIP_r0* records become store
-        history — the round 1–5 trajectory the dashboard opens with."""
-        store = RunStore(tmp_path)
-        docs = backfill_historical(store, root=ROOT)
+        """BENCH_r0*/MULTICHIP_r0* records become store history — the
+        round trajectory the dashboard opens with."""
+        store = RunStore(tmp_path / "s")
+        docs = backfill_historical(store, root=_write_rounds(tmp_path))
         ids = {d["run_id"] for d in docs}
-        assert "backfill-bench_r01" in ids
-        assert "backfill-multichip_r05" in ids
-        assert "backfill-bench-midround-r05" in ids
+        assert ids == {"backfill-bench_r01", "backfill-bench_r05",
+                       "backfill-multichip_r05"}
         # The r05 headline parsed into a fingerprinted, valued doc.
         r5 = store.get("backfill-bench_r05")
         assert r5["record"]["overall_throughput"] == pytest.approx(168.729)
@@ -333,9 +348,10 @@ class TestBackfill:
         assert r5["code_hash"] != code_hash()
 
     def test_backfill_idempotent(self, tmp_path):
-        store = RunStore(tmp_path)
-        n1 = len(backfill_historical(store, root=ROOT))
-        n2 = len(backfill_historical(store, root=ROOT))
+        store = RunStore(tmp_path / "s")
+        root = _write_rounds(tmp_path)
+        n1 = len(backfill_historical(store, root=root))
+        n2 = len(backfill_historical(store, root=root))
         assert n1 == n2 == len(store.index())
 
     def test_backfill_empty_root_is_noop(self, tmp_path):
@@ -345,8 +361,8 @@ class TestBackfill:
     def test_backfill_sorts_before_live_runs(self, tmp_path):
         """Historical rounds are history: `latest` must keep resolving
         to the live run even when the backfill ran a second ago."""
-        store = RunStore(tmp_path)
+        store = RunStore(tmp_path / "s")
         store.put(_doc("live-run"))  # real created_epoch (now)
-        backfill_historical(store, root=ROOT)
+        backfill_historical(store, root=_write_rounds(tmp_path))
         assert store.resolve("latest")["run_id"] == "live-run"
         assert store.index()[0]["run_id"].startswith("backfill-")

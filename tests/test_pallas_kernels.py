@@ -196,8 +196,50 @@ class TestPallasTileKernels:
             atol=tol,
         )
 
+    @pytest.mark.parametrize("max_chunks", [4, 12])
+    @pytest.mark.parametrize("group,batch", [(1, False), (4, True)])
+    def test_segmented_launches_match_one_launch(self, monkeypatch, group,
+                                                 batch, max_chunks):
+        """A chunk list longer than MAX_PREFETCH_CHUNKS (SMEM) runs as
+        several launches; row-block groups cut by a segment boundary add
+        their partial sums, and the result is the single launch's."""
+        from distributed_sddmm_tpu.ops import pallas_kernels
+
+        rows, cols, meta, blk, vals, rng = _tile_setup(group=group, nnz=6000)
+        A = jnp.array(rng.standard_normal((700, 16)).astype(np.float32))
+        B = jnp.array(rng.standard_normal((500, 16)).astype(np.float32))
+        k = PallasKernel(precision="f32", interpret=True, batch_step=batch)
+        vj = jnp.array(vals)
+
+        def run():
+            fo, fm = k.fused_tile(blk, vj, A, B)
+            return [np.asarray(x) for x in (
+                fo, fm, k.spmm_tile(blk, vj, B, 700),
+                k.sddmm_tile(blk, vj, A, B))]
+
+        one = run()
+        monkeypatch.setattr(pallas_kernels, "MAX_PREFETCH_CHUNKS", max_chunks)
+        pallas_kernels._tile_call.clear_cache()
+        try:
+            assert meta.n_chunks > 2 * max_chunks
+            for got, want in zip(run(), one):
+                np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+        finally:
+            pallas_kernels._tile_call.clear_cache()
+
+    def test_flat_protocol_refused_off_interpret(self):
+        """On the TPU a tile set without blocked metadata raises instead of
+        running XLA under the Pallas kernel's name."""
+        k = PallasKernel(precision="bf16", interpret=False)
+        rows = jnp.array([0, 1], jnp.int32)
+        with pytest.raises(RuntimeError, match="no blocked"):
+            k.sddmm(rows, rows, jnp.ones(2), jnp.ones((2, 4)),
+                    jnp.ones((2, 4)))
+        with pytest.raises(RuntimeError, match="no blocked"):
+            k.spmm(rows, rows, jnp.ones(2), jnp.ones((2, 4)), 2)
+
     def test_flat_protocol_fallback(self):
-        # PallasKernel is a drop-in LocalKernel: flat calls route to XLA.
+        # In interpret mode (CPU test meshes) flat calls route to XLA.
         k = PallasKernel(interpret=True)
         rows = jnp.array([0, 1, 1], jnp.int32)
         cols = jnp.array([0, 0, 2], jnp.int32)

@@ -237,11 +237,11 @@ def test_chained_keys_invalidate_on_models_code_generation(tmp_path,
     assert "ffffffffffff" in key_after
 
 
-def test_chained_keys_separate_matrix_content_and_ring_build(tmp_path):
-    """Two same-shape matrices (identical coarse fingerprint) and the
-    two ring builds (overlap/sequential) must all produce distinct
-    chained-program keys: the chains bake tile constants and the ring
-    structure into the executable where avals cannot see them."""
+def test_chained_keys_share_matrix_content_separate_ring_build(tmp_path):
+    """Two same-shape matrices (identical coarse fingerprint) share one
+    chained-program key — the tile operands are arguments — while the
+    two ring builds (overlap/sequential), whose structure avals cannot
+    see, get distinct keys."""
     from distributed_sddmm_tpu.parallel.dense_shift_15d import DenseShift15D
 
     S1 = HostCOO.erdos_renyi(48, 32, 4, seed=1, values="normal")
@@ -252,26 +252,19 @@ def test_chained_keys_separate_matrix_content_and_ring_build(tmp_path):
 
     def key_for(S, overlap):
         alg = DenseShift15D(S, R=8, c=1, fusion_approach=2, overlap=overlap)
-        programs.bind_strategy(
-            alg, "samefingerprint", store=store,
-            content_key=programs.matrix_content_key(S),
-        )
+        programs.bind_strategy(alg, "samefingerprint", store=store)
         return programs.chained_program(alg, "cgStep", jit_fn)._key_fn("s")
 
-    assert key_for(S1, False) != key_for(S2, False)  # content
+    assert key_for(S1, False) == key_for(S2, False)  # content
     assert key_for(S1, False) != key_for(S1, True)   # ring build
 
 
-def test_chained_program_stays_on_jit_without_content_key(tmp_path):
-    """A binding with no matrix-content digest must NOT persist chained
-    programs (they would bake tile constants under a content-blind
-    key); the chain falls back to the plain jit."""
+def test_chained_program_passes_over_unbound_strategy(tmp_path):
+    """An unbound strategy's chain is the plain jit, byte for byte."""
     from distributed_sddmm_tpu.parallel.dense_shift_15d import DenseShift15D
 
     S = HostCOO.erdos_renyi(48, 32, 4, seed=1, values="normal")
-    store = programs.ProgramStore(tmp_path)
     alg = DenseShift15D(S, R=8, c=1, fusion_approach=2)
-    programs.bind_strategy(alg, "fpkey", store=store)  # no content_key
     jit_fn = lambda x: x  # noqa: E731
     assert programs.chained_program(alg, "cgStep", jit_fn) is jit_fn
 
