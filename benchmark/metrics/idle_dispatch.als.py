@@ -1,0 +1,7 @@
+"""idle_dispatch.als: % of the traced window in which the device is idle under a program `<op>:dispatch` span (the host launching a program), mean over devices."""
+
+from benchmark.span_metrics import DISPATCH, idle_under
+
+
+def read(ctx):
+    return idle_under(ctx, DISPATCH)

@@ -298,12 +298,13 @@ class DistributedALS:
         eps = 1e-8
 
         def one_iter(X, other, r, p, rsold, *operands):
-            if mode == MatMode.A:
-                out, _ = fused(p, other, *operands)
-            else:
-                out, _ = fused(other, p, *operands)
-            Mp = out + lam * p
-            return _cg_vector_update(X, r, p, rsold, Mp, eps)
+            with jax.named_scope("cgStep"):
+                if mode == MatMode.A:
+                    out, _ = fused(p, other, *operands)
+                else:
+                    out, _ = fused(other, p, *operands)
+                Mp = out + lam * p
+                return _cg_vector_update(X, r, p, rsold, Mp, eps)
 
         prog = jax.jit(
             one_iter, donate_argnums=(0, 2, 3, 4) if donate else ()

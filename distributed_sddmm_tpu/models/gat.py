@@ -193,13 +193,14 @@ class GAT:
             return jnp.maximum(spmm(A, att, *struct), 0)
 
         def layer_fn(X, weights, ones, struct):
-            heads = [head(X, w, ones, struct) for w in weights]
-            return d._skew_cols(
-                jnp.concatenate(
-                    [d._unskew_cols(h, mode) for h in heads], axis=-1
-                ),
-                mode,
-            )
+            with jax.named_scope("gatLayer"):
+                heads = [head(X, w, ones, struct) for w in weights]
+                return d._skew_cols(
+                    jnp.concatenate(
+                        [d._unskew_cols(h, mode) for h in heads], axis=-1
+                    ),
+                    mode,
+                )
 
         d.set_r_value(layer.output_features)
         prog = jax.jit(
@@ -221,8 +222,13 @@ class GAT:
         """Full forward pass (`gat.hpp:106-112`).
 
         ``X`` is node features in A-layout with R = layers[0].input_features;
-        defaults to a deterministic dummy fill.
+        defaults to a deterministic dummy fill. Runs under a
+        ``gat:forward`` span.
         """
+        with obs_trace.span("gat:forward", layers=len(self.layers)):
+            return self._forward(X)
+
+    def _forward(self, X: jax.Array | None) -> jax.Array:
         d = self.d_ops
         if X is None:
             d.set_r_value(self.layers[0].input_features)

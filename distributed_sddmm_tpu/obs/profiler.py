@@ -1,6 +1,6 @@
-"""Optional ``jax.profiler`` integration: capture + named annotations.
+"""Optional ``jax.profiler`` capture.
 
-Three pieces, all no-ops unless explicitly armed:
+Two pieces, both no-ops unless explicitly armed:
 
 * :func:`capture` / :func:`maybe_capture` — a context manager around a
   whole run that starts a ``jax.profiler`` trace into a log directory
@@ -13,11 +13,10 @@ Three pieces, all no-ops unless explicitly armed:
   *around* the anomaly without paying whole-run capture overhead.
   Refuses (returns False) while another capture is active — two
   concurrent ``jax.profiler`` sessions is an error in jax itself.
-* :func:`annotate` — a named ``jax.profiler.TraceAnnotation`` wrapped
-  around each compiled-program dispatch (``cgStep``, ``gatLayer``, the
-  sddmm/spmm/fused programs) so device timelines carry the framework's
-  op names. Only constructed while a capture is active
-  (:func:`active`), so the hot path pays one boolean check otherwise.
+
+The program's names reach a capture through ``obs.trace.span``, which
+always enters a ``TraceAnnotation``, whoever started the capture; a
+capture changes nothing the program runs.
 
 Everything degrades gracefully: a jax without the profiler API (or a
 backend that refuses to start one — :func:`capture_available` probes
@@ -37,20 +36,8 @@ _capturing = False
 
 
 def active() -> bool:
-    """True while a profiler capture is running (annotations worth it)."""
+    """True while a capture started here is running (one at a time)."""
     return _capturing
-
-
-def annotate(name: str):
-    """A ``TraceAnnotation(name)`` while capturing, else a null context."""
-    if not _capturing:
-        return contextlib.nullcontext()
-    try:
-        import jax.profiler
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — profiling is best-effort
-        return contextlib.nullcontext()
 
 
 @contextlib.contextmanager

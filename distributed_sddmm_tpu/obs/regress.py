@@ -36,6 +36,8 @@ from __future__ import annotations
 
 import statistics
 
+from distributed_sddmm_tpu.obs import trace as obs_trace
+
 #: Gate exit codes (stable contract for CI).
 GATE_PASS = 0
 GATE_REGRESSION = 2
@@ -61,9 +63,11 @@ def _optional_axis(name: str) -> bool:
     ``serve:burn_rate`` prefix covers the per-tenant sub-axes
     (``serve:burn_rate:<tenant>``) — a run without that tenant declared
     simply did not measure it; ``fleet:`` axes exist only for ``bench
-    fleet`` records."""
+    fleet`` records. An op's ``:dispatch`` / ``:fetch`` leaf spans exist
+    only in traces written since ``_timed`` opened them."""
     return (
-        name.startswith("xla:")
+        obs_trace.is_leaf(name)
+        or name.startswith("xla:")
         or name.startswith("tuner:")
         or name.startswith("comm:")
         or name.startswith("fleet:")

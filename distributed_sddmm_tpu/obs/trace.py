@@ -1,5 +1,12 @@
 """Process-wide structured tracer: nested spans, thread-safe JSONL.
 
+:func:`span` is the program's one span API, and it has two sinks. Every
+span enters a ``jax.profiler.TraceAnnotation`` of its name, always: a
+``jax.profiler`` capture, whoever started it, shows the span on the host
+line of the thread that opened it, on the trace's clock. With no capture
+running that costs the C++ ``TraceMe``'s one check. The JSONL sink below
+is the optional second one.
+
 One tracer per process, activated by the ``DSDDMM_TRACE`` environment
 variable (``1`` → the default ``artifacts/traces/<run_id>.jsonl``; any
 other value is used as the output path, a directory landing the default
@@ -8,11 +15,11 @@ programmatically via :func:`enable`.
 
 Design constraints, in order:
 
-1. **Near-zero overhead when disabled.** :func:`span` and :func:`event`
-   check one module-level boolean and return a shared no-op object —
-   no allocation, no lock, no clock read. Strategy dispatch calls these
-   on every compiled-program call; the disabled path must cost
-   nanoseconds (pinned by a test).
+1. **Near-zero overhead when JSONL is off.** :func:`span` then checks
+   one module-level boolean and returns a profiler-only span: no file,
+   no lock, no clock read; :func:`event` returns at once. Strategy
+   dispatch opens three spans on every compiled-program call; a test
+   pins a budget for the JSONL-off path.
 2. **Thread-safe emission.** Retry workers, autotune trials and the
    checkpoint writer all emit from non-main threads; records are
    serialized under one lock and written as complete lines, so a trace
@@ -70,6 +77,7 @@ import errno
 import json
 import os
 import pathlib
+import sys
 import threading
 import time
 from typing import Optional
@@ -134,33 +142,48 @@ def _make_run_id() -> str:
     )
 
 
-class _NoopSpan:
-    """Shared do-nothing span: the disabled-tracer return value."""
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation(name)``, or None in a process
+    that has not imported ``jax.profiler`` (no capture can run there, and
+    a JAX-free process stays JAX-free)."""
+    prof = sys.modules.get("jax.profiler")
+    return None if prof is None else prof.TraceAnnotation(name)
 
-    __slots__ = ()
+
+class _ProfilerSpan:
+    """The span with JSONL off: its profiler annotation alone."""
+
+    __slots__ = ("_me",)
+
+    def __init__(self, name: str):
+        self._me = _annotation(name)
 
     def __enter__(self):
+        if self._me is not None:
+            self._me.__enter__()
         return self
 
     def __exit__(self, *exc):
+        if self._me is not None:
+            self._me.__exit__(*exc)
         return False
 
     def set(self, **attrs) -> None:
         pass
 
 
-NOOP_SPAN = _NoopSpan()
-
-
 class Span:
-    """A live span; emitted as one JSONL record when it closes."""
+    """A live span; emitted as one JSONL record when it closes, and shown
+    in a profiler capture as its annotation."""
 
-    __slots__ = ("tracer", "name", "attrs", "id", "parent", "tid", "_t0")
+    __slots__ = ("tracer", "name", "attrs", "id", "parent", "tid", "_t0",
+                 "_me")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self.tracer = tracer
         self.name = name
         self.attrs = attrs
+        self._me = _annotation(name)
 
     def set(self, **attrs) -> None:
         """Attach attributes mid-span (e.g. kernel vs overhead splits
@@ -168,6 +191,8 @@ class Span:
         self.attrs.update(attrs)
 
     def __enter__(self) -> "Span":
+        if self._me is not None:
+            self._me.__enter__()
         tr = self.tracer
         self.id = tr.next_id()
         self.tid = threading.get_ident()
@@ -185,17 +210,21 @@ class Span:
             stack.pop()
         if exc and exc[0] is not None:
             self.attrs.setdefault("error", exc[0].__name__)
-        tr.emit({
-            "type": "span",
-            "name": self.name,
-            "id": self.id,
-            "parent": self.parent,
-            "tid": self.tid,
-            "t0": round(self._t0 - tr.t0, 9),
-            "t1": round(t1 - tr.t0, 9),
-            "dur_s": round(t1 - self._t0, 9),
-            "attrs": self.attrs,
-        })
+        try:
+            tr.emit({
+                "type": "span",
+                "name": self.name,
+                "id": self.id,
+                "parent": self.parent,
+                "tid": self.tid,
+                "t0": round(self._t0 - tr.t0, 9),
+                "t1": round(t1 - tr.t0, 9),
+                "dur_s": round(t1 - self._t0, 9),
+                "attrs": self.attrs,
+            })
+        finally:
+            if self._me is not None:
+                self._me.__exit__(*exc)
         return False
 
 
@@ -562,8 +591,21 @@ def find_shard(directory, pid: int) -> Optional[str]:
     return None
 
 
+#: Suffixes of the leaf spans ``DistributedSparse._timed`` opens inside
+#: an op's span: ``<op>:dispatch`` around the program call, ``<op>:fetch``
+#: around the barrier that waits for its outputs. Host phases of an op,
+#: never ops themselves (no ``kernel_s``; absent from pre-split traces).
+LEAF_SUFFIXES = (":dispatch", ":fetch")
+
+
+def is_leaf(name: str) -> bool:
+    """True for an ``<op>:dispatch`` / ``<op>:fetch`` leaf span name."""
+    return name.endswith(LEAF_SUFFIXES)
+
+
 def span(name: str, **attrs):
-    """A context manager timing a nested region; no-op when disabled.
+    """A context manager around a nested region: always a profiler
+    annotation named ``name``; a JSONL record too when tracing is on.
 
     Usage::
 
@@ -573,7 +615,7 @@ def span(name: str, **attrs):
     """
     tr = tracer()
     if tr is None:
-        return NOOP_SPAN
+        return _ProfilerSpan(name)
     return Span(tr, name, attrs)
 
 

@@ -485,6 +485,14 @@ def _make_attn_norm_body(G):
     return body
 
 
+def kernel_name(op: str) -> str:
+    """A launch's name, ``<op>_tiles``: the kernel's Mosaic name and the
+    name of its custom-call instruction in compiled HLO and in a device
+    trace (``sddmm_tiles.3``), so a trace tells the ``fused``, ``sddmm``,
+    ``spmm``, ``attn_reduce`` and ``attn_norm`` kernels apart."""
+    return f"{op}_tiles"
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("op", "bm", "gr_blocks", "group", "interpret",
@@ -548,6 +556,7 @@ def _attn_call(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
+        name=kernel_name(op),
     )(meta, *operands)
     return outs if op == "attn_reduce" else outs[0]
 
@@ -656,6 +665,7 @@ def _tile_call(
                 dimension_semantics=("arbitrary",)
             ),
             interpret=interpret,
+            name=kernel_name(op),
         )(m, lr3, lc3, sv3, *dense)
 
     seg = max(MAX_PREFETCH_CHUNKS // G, 1)

@@ -34,7 +34,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from distributed_sddmm_tpu.common import KernelMode, MatMode
 from distributed_sddmm_tpu.obs import log as obs_log
 from distributed_sddmm_tpu.obs import metrics as obs_metrics
-from distributed_sddmm_tpu.obs import profiler as obs_profiler
 from distributed_sddmm_tpu.obs import trace as obs_trace
 from distributed_sddmm_tpu.obs import watchdog as obs_watchdog
 from distributed_sddmm_tpu.ops.kernels import LocalKernel, XlaKernel
@@ -725,28 +724,40 @@ class DistributedSparse(abc.ABC):
     ):
         """Dispatch one compiled program with full attribution: kernel
         time (the successful attempt) separate from retry/fault overhead,
-        comm words + FLOPs from the layout model, a trace span when
-        tracing, a profiler annotation when capturing. ``_pairs`` scales
-        the comm/FLOP charge for multi-pair programs (GAT layers dispatch
-        one fused pair per head); ``_comm_op`` overrides the cost-op name
-        when the counter name does not determine the layout (B-mode fused
-        dispatches charge ``fusedSpMMB``/``cgStepB`` while still counting
-        under the public op name)."""
+        comm words + FLOPs from the layout model, and spans. ``_pairs``
+        scales the comm/FLOP charge for multi-pair programs (GAT layers
+        dispatch one fused pair per head); ``_comm_op`` overrides the
+        cost-op name when the counter name does not determine the layout
+        (B-mode fused dispatches charge ``fusedSpMMB``/``cgStepB`` while
+        still counting under the public op name).
+
+        Spans (``obs.trace.span``: a profiler annotation always, a JSONL
+        record when tracing), on either path and per attempt when
+        retrying: ``<name>`` around the whole call, and inside it the two
+        leaves ``<name>:dispatch`` around ``fn(*args)`` and
+        ``<name>:fetch`` around ``force_fetch(out)``. The suffixes are a
+        contract: the benchmark's per-layer readers attribute device idle
+        time and count fetches by them. A profiler capture does not
+        change the path taken."""
         from distributed_sddmm_tpu.resilience import faults, guards
         from distributed_sddmm_tpu.utils.platform import force_fetch
 
         cost_op = _comm_op or name
         resilient = faults.active() is not None or guards.enabled()
         wd = obs_watchdog.active()
-        if not (resilient or obs_trace.enabled() or obs_profiler.active()):
-            # Hot path: two clock reads + one locked counter update.
-            t0 = time.perf_counter()
-            out = fn(*args)
-            # Execution barrier over every output leaf
-            # (utils.platform.force_fetch); one scalar per leaf is
-            # negligible next to any timed op.
-            force_fetch(out)
-            kernel_s = time.perf_counter() - t0
+        if not (resilient or obs_trace.enabled()):
+            # Hot path: two clock reads, three profiler annotations, one
+            # locked counter update.
+            with obs_trace.span(name):
+                t0 = time.perf_counter()
+                with obs_trace.span(name + ":dispatch"):
+                    out = fn(*args)
+                # Execution barrier over every output leaf
+                # (utils.platform.force_fetch); one scalar per leaf is
+                # negligible next to any timed op.
+                with obs_trace.span(name + ":fetch"):
+                    force_fetch(out)
+                kernel_s = time.perf_counter() - t0
             words, nbytes, extra, flops = self._op_cost(cost_op, _pairs)
             self.metrics.record(
                 name, kernel_s, comm_words=words, comm_bytes=nbytes,
@@ -768,8 +779,9 @@ class DistributedSparse(abc.ABC):
             if resilient:
                 out, kernel_s, attempts = self._resilient_call(name, fn, *args)
             else:
-                with obs_profiler.annotate(name):
+                with obs_trace.span(name + ":dispatch"):
                     out = fn(*args)
+                with obs_trace.span(name + ":fetch"):
                     force_fetch(out)
                 kernel_s = time.perf_counter() - t0
                 attempts = 1
@@ -823,9 +835,10 @@ class DistributedSparse(abc.ABC):
             attempts[0] += 1
             t0 = time.perf_counter()
             faults.maybe_raise(f"execute:{name}")
-            with obs_profiler.annotate(name):
+            with obs_trace.span(name + ":dispatch"):
                 out = fn(*args)
                 out = faults.corrupt_outputs(f"output:{name}", out)
+            with obs_trace.span(name + ":fetch"):
                 force_fetch(out)
             if guards.enabled():
                 # raise-mode trips the retry below; repair-mode degrades

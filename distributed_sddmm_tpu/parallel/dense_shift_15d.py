@@ -364,6 +364,7 @@ class DenseShift15D(DistributedSparse):
             # fusion 2, "local kernel overlap": SDDMM + SpMM per tile inside
             # one ring traversal (`15D_dense_shift.hpp:199-227`).
 
+            @jax.named_scope("fused")
             def prog(stat, mov, t_rows, t_cols, t_vals):
                 t_rows, t_cols, t_vals = squeeze(t_rows), squeeze(t_cols), squeeze(t_vals)
                 stat_rep = replicate(stat)
@@ -691,6 +692,7 @@ class DenseShift15D(DistributedSparse):
 
         elif op == "fused":
 
+            @jax.named_scope("fused")
             def prog(stat, mov, blr, blc, bmeta, t_vals):
                 fields = squeeze_blk(blr, blc, bmeta)
                 t_vals = t_vals.reshape(T, max_nnz)

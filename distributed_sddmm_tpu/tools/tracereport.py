@@ -527,6 +527,7 @@ def _model_words_per_pair(meta: dict) -> float | None:
 def aggregate(trace: dict) -> dict:
     """Per-phase table + model comparison + events summary, JSON-ready."""
     from distributed_sddmm_tpu.obs import metrics as obs_metrics
+    from distributed_sddmm_tpu.obs import trace as obs_trace
 
     phases: dict[str, dict] = {}
     for sp in trace["spans"]:
@@ -538,7 +539,10 @@ def aggregate(trace: dict) -> dict:
         })
         ph["calls"] += 1
         ph["total_s"] += sp["dur_s"]
-        ph["kernel_s"] += a.get("kernel_s", sp["dur_s"])
+        # An op's :dispatch / :fetch leaves are host phases of its call,
+        # whose kernel time the op's own span already carries.
+        ph["kernel_s"] += a.get(
+            "kernel_s", 0.0 if obs_trace.is_leaf(sp["name"]) else sp["dur_s"])
         ph["overhead_s"] += a.get("overhead_s", 0.0)
         ph["retries"] += a.get("retries", 0)
         ph["comm_words"] += a.get("comm_words", 0.0)

@@ -125,6 +125,60 @@ def test_gat_layer_compiles_for_v5e(smoke_alg):
     _check_compiled(prog.lower(*args).compile())
 
 
+@pytest.fixture(scope="module")
+def one_chip_mesh(topo):
+    """A one-device mesh on the described v5e, JAX's persistent cache off
+    around its compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import Mesh
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield Mesh([topo.devices[0]], ("x",))
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize(
+    "op", ["fused", "sddmm", "spmm", "attn_reduce", "attn_norm"])
+def test_compiled_launch_is_named_by_kind(one_chip_mesh, op):
+    """Each kind's launch, compiled for a described v5e at a small size,
+    is a custom call named by its kernel (what a device trace's ``XLA
+    Ops`` line shows), with ``tpu_custom_call`` as its target."""
+    import re
+
+    import jax.numpy as jnp
+
+    from distributed_sddmm_tpu.ops import pallas_kernels as pk
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(one_chip_mesh, P()))
+
+    C, bm, G = 16, 512, 4
+    meta, lr = sds((C,), jnp.int32), sds((C, pk.CHUNK), jnp.int32)
+    sv = sds((C, pk.CHUNK), jnp.float32)
+    if op.startswith("attn"):
+        md = sds((4 * bm, 1), jnp.float32)
+        stats = op == "attn_norm"
+        fn = jax.jit(lambda meta, lr, gv, zv, m, d: pk._attn_call(
+            meta, lr, gv, zv, m if stats else None, d if stats else None,
+            op=op, bm=bm, gr_blocks=4, group=G, interpret=False))
+        args = (meta, lr, sv, sv, md, md)
+    else:
+        dense = sds((128, 4 * bm), jnp.bfloat16)
+        geom = (bm, bm, 4, 4, G, False, "bt", False, False)
+        fn = jax.jit(lambda meta, lr, lc, sv, at, bt: pk._geom_call(
+            geom, op, meta, lr, lc, sv, at, bt))
+        args = (meta, lr, lr, sv, dense, dense)
+    text = fn.lower(*args).compile().as_text()
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert calls and all(c.split(".")[0] == pk.kernel_name(op)
+                         for c in calls), calls
+
+
 def test_smoke_phases_on_cpu_mesh(tmp_path, monkeypatch):
     """Every phase of chip_smoke.py, its checks included, at logM=8 with
     the Pallas kernel in interpret mode (f32, so the oracle tolerance is

@@ -172,14 +172,22 @@ class TestServeCLIEndToEnd:
             {"site": "execute:serveBatch", "kind": "delay", "at": [8],
              "param": 0.5},
         ])
-        rc = cli.main([
-            "serve", "--app", "als", "--log-m", "6", "--edge-factor", "6",
-            "--R", "8", "--duration", "2.0", "--rate", "30",
-            "--max-batch", "4", "--train-steps", "1", "--oracle-every", "0",
-            "--watchdog", "warn", "--flightrec", str(tmp_path / "fr"),
-            "--admin-port", "0", "--trace", str(trace_file),
-            "--faults", faults, "--no-runstore", "-o", str(out_file),
-        ])
+        from distributed_sddmm_tpu.resilience import faults as res_faults
+
+        try:
+            rc = cli.main([
+                "serve", "--app", "als", "--log-m", "6", "--edge-factor",
+                "6", "--R", "8", "--duration", "2.0", "--rate", "30",
+                "--max-batch", "4", "--train-steps", "1", "--oracle-every",
+                "0", "--watchdog", "warn", "--flightrec",
+                str(tmp_path / "fr"), "--admin-port", "0", "--trace",
+                str(trace_file), "--faults", faults, "--no-runstore", "-o",
+                str(out_file),
+            ])
+        finally:
+            # --faults installs the plan process-wide; later tests in
+            # this process must not inherit it.
+            res_faults.clear()
         assert rc == 0
         record = json.loads(out_file.read_text().splitlines()[-1])
         assert record["admin_port"] > 0

@@ -17,15 +17,43 @@ def _not_capturing():
     profiler._capturing = False
 
 
-class TestAnnotate:
-    def test_nullcontext_when_not_capturing(self):
-        ctx = profiler.annotate("fusedSpMM")
-        assert isinstance(ctx, contextlib.nullcontext)
+class TestSpansInCapture:
+    def test_annotate_is_gone(self):
+        """``obs.trace.span`` is the one span API."""
+        assert not hasattr(profiler, "annotate")
 
-    def test_real_annotation_while_capturing(self, monkeypatch):
-        monkeypatch.setattr(profiler, "_capturing", True)
-        with profiler.annotate("fusedSpMM"):
-            pass  # constructing + entering a TraceAnnotation must work
+    def test_spans_land_nested_on_a_host_line(self, tmp_path):
+        """A short CPU capture read back with ``ProfileData``: the spans
+        of one timed call, JSONL off, nested on the host line of the
+        thread that opened them, on the trace's clock."""
+        import glob
+
+        import jax.numpy as jnp
+        from jax.profiler import ProfileData
+
+        from distributed_sddmm_tpu.obs import trace
+
+        trace.disable()
+        x = jnp.ones((8, 8))
+        (x @ x).block_until_ready()
+        with profiler.capture(str(tmp_path)):
+            with trace.span("op"):
+                with trace.span("op:dispatch"):
+                    y = x @ x
+                with trace.span("op:fetch"):
+                    y.block_until_ready()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        lines = [
+            {ev.name: (ev.start_ns, ev.start_ns + ev.duration_ns)
+             for ev in line.events}
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:CPU") for line in plane.lines]
+        (spans,) = [ln for ln in lines if "op" in ln]
+        op, dispatch, fetch = (spans[k] for k in
+                               ("op", "op:dispatch", "op:fetch"))
+        assert op[0] <= dispatch[0] <= dispatch[1] <= fetch[0]
+        assert fetch[1] <= op[1]
 
 
 class TestCaptureAvailable:

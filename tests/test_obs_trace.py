@@ -6,7 +6,9 @@ Covers the observability layer's load-bearing promises:
   written first; ``parent`` ids reconstruct the tree),
 * every emitted line round-trips through the reader/validator
   (``tools/tracereport``),
-* a disabled tracer is a true no-op (shared sentinel object, no file),
+* with JSONL off a span is its profiler annotation alone (no file, no
+  lock, no clock read, a pinned cost); on or off it enters a
+  ``jax.profiler.TraceAnnotation``,
 * resilience events (fault fired, retry) land in the trace,
 * counted comm volume on a real DenseShift15D run equals the analytic
   cost-model prediction — the measured-vs-modeled agreement the paper's
@@ -132,15 +134,94 @@ class TestSchemaRoundTrip:
         assert len(loose["errors"]) == 1
 
 
+def _forbid_jsonl_work(monkeypatch):
+    """Make every JSONL-side cost raise: a clock read, a tracer lock, a
+    file opened by the tracer module."""
+    def boom(*_a, **_k):
+        raise AssertionError("JSONL-off span touched the JSONL machinery")
+
+    monkeypatch.setattr(trace.clock, "now", boom)
+    monkeypatch.setattr(trace.Tracer, "next_id", boom)
+    monkeypatch.setattr(trace.Tracer, "emit", boom)
+    monkeypatch.setattr(trace, "open", boom, raising=False)
+
+
+class _RecordingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``; logs enters and
+    exits in order."""
+
+    log: list = []
+
+    def __init__(self, name, **kw):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax.profiler
+
+    _RecordingAnnotation.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _RecordingAnnotation)
+    return _RecordingAnnotation.log
+
+
 class TestDisabledTracer:
-    def test_span_is_shared_noop(self, tmp_path):
+    def test_span_is_shared_noop(self, tmp_path, monkeypatch):
+        """With JSONL off a span writes nothing: no file, no tracer lock,
+        no clock read; only its profiler annotation remains."""
         trace.disable()
         assert not trace.enabled()
-        assert trace.span("anything", a=1) is trace.NOOP_SPAN
-        with trace.span("x") as sp:
+        _forbid_jsonl_work(monkeypatch)
+        with trace.span("x", a=1) as sp:
             sp.set(k=2)  # must not raise
         trace.event("y", a=1)  # must not raise, must not create a file
         assert trace.run_id() is None and trace.trace_path() is None
+        assert list(tmp_path.iterdir()) == []
+
+    def test_span_enters_annotation_with_jsonl_off(self, annotations):
+        trace.disable()
+        with trace.span("outer"):
+            with trace.span("outer:fetch", n=3):
+                pass
+        assert annotations == [("enter", "outer"), ("enter", "outer:fetch"),
+                               ("exit", "outer:fetch"), ("exit", "outer")]
+
+    def test_span_enters_annotation_with_jsonl_on(self, tracer, annotations):
+        with trace.span("op", R=4):
+            pass
+        trace.disable()
+        assert annotations == [("enter", "op"), ("exit", "op")]
+        assert [r["name"] for r in _records(tracer)
+                if r["type"] == "span"] == ["op"]
+
+    def test_span_cost_with_jsonl_off(self, monkeypatch):
+        """Three spans a dispatch: the JSONL-off span, annotation
+        included, stays under 5 us (median of batches over 10k spans)."""
+        import statistics
+        import time
+
+        trace.disable()
+        _forbid_jsonl_work(monkeypatch)
+        per_span = []
+        for _ in range(10):
+            t0 = time.perf_counter()
+            for _ in range(1000):
+                with trace.span("cgStep:fetch"):
+                    pass
+            per_span.append((time.perf_counter() - t0) / 1000)
+        assert statistics.median(per_span) < 5e-6, per_span
+
+    def test_leaf_names(self):
+        assert trace.is_leaf("cgStep:dispatch") and trace.is_leaf("x:fetch")
+        assert not trace.is_leaf("cgStep") and not trace.is_leaf("als:step")
 
     def test_env_activation(self, tmp_path, monkeypatch):
         trace.disable()
