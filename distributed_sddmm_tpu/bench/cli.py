@@ -1553,7 +1553,12 @@ def _serve_until_signal(eng, slo, tenants) -> dict:
           file=sys.stderr)
     t0 = clock.now()
     try:
-        done.wait()
+        # Poll rather than block: a process-directed SIGTERM may land on
+        # an admin-server thread (as it tends to right after the SIGCONT
+        # that ends a wedge), and Python runs the handler only once the main
+        # thread is back in the interpreter.
+        while not done.wait(0.1):
+            pass
     finally:
         signal.signal(signal.SIGTERM, old_term)
         signal.signal(signal.SIGINT, old_int)

@@ -90,7 +90,7 @@ def _bench_one(S: HostCOO, R: int, kernel_name: str, trials: int) -> dict:
         kern = PallasKernel(precision=precision)
         meta = build_blocked(
             1, np.zeros(S.nnz, np.int64), S.rows, S.cols, S.M, S.N,
-            group=DEFAULT_GROUP,
+            group=DEFAULT_GROUP, R=R,
         )
         blk = BlockedTile(
             lr=jnp.array(meta.lr[0]), lc=jnp.array(meta.lc[0]),

@@ -248,7 +248,7 @@ def build_banded(
     rows_pad = pad_frame(max(tile_rows, 1), max(bms))
     fixed = [pick_block(tile_cols, s.block_cols) for s in specs if s.block_cols]
     bn_floor = max(fixed) if fixed else pick_block(
-        tile_cols, blocked_mod.DEFAULT_BLOCK_COLS
+        tile_cols, blocked_mod.DEFAULT_BLOCK_COLS or blocked_mod.BASE_BLOCK
     )
     cols_pad = pad_frame(max(tile_cols, 1), bn_floor)
     gcb_full = cols_pad // bn_floor

@@ -40,10 +40,13 @@ from distributed_sddmm_tpu.utils.buckets import pow2_bucket
 VARIANT_VERSION = 1
 
 #: R-regime tile geometry: (heavy-band block_rows, block_cols, group).
-#: ``rs``/``rm`` keep the measured headline blocks (KERNELS_TPU.jsonl:
-#: (512, 512) wins at R=128); ``rl`` halves both so the [R, bm] f32
-#: accumulator and dense windows stay VMEM-resident at R >= 1024
-#: (512x1024 f32 = 2 MiB per operand before double buffering).
+#: ``rs``/``rm`` keep the blocks measured best at logM 16, nnz/row 32,
+#: R=128 (KERNELS_TPU.jsonl), where a 512x512 block holds 128 nonzeros,
+#: one full chunk; sparser matrices fill them less (the generic
+#: encoding sizes its blocks to the density: ops/blocked.choose_blocks).
+#: ``rl`` halves both so the [R, bm] f32 accumulator and dense windows
+#: stay VMEM-resident at R >= 1024 (512x1024 f32 = 2 MiB per operand
+#: before double buffering).
 _REGIMES = {
     "rs": (512, 512, 4),
     "rm": (512, 512, 4),
@@ -224,8 +227,8 @@ def estimated_pad_frac(problem, banked: bool) -> float:
     rounding per row block."""
     from distributed_sddmm_tpu.ops import blocked
 
-    bm = blocked.DEFAULT_BLOCK_ROWS
-    bn = blocked.DEFAULT_BLOCK_COLS
+    bm = blocked.DEFAULT_BLOCK_ROWS or blocked.BASE_BLOCK
+    bn = blocked.DEFAULT_BLOCK_COLS or blocked.BASE_BLOCK
     grb = max(-(-problem.M // bm), 1)
     gcb = max(-(-problem.N // bn), 1)
     nnz = max(problem.nnz, 1)

@@ -42,12 +42,17 @@ CHUNK = int(os.environ.get("DSDDMM_CHUNK", "128"))
 # settings without code edits.
 DEFAULT_GROUP = int(os.environ.get("DSDDMM_CHUNK_GROUP", "4"))
 
-# Preferred dense block sizes for the one-hot kernels' (row, col) windows.
-# Env-overridable for the same reason as DEFAULT_GROUP: bench.py applies the
-# best (blocks, group, scatter form) combination measured in
-# KERNELS_TPU.jsonl without code edits.
-DEFAULT_BLOCK_ROWS = int(os.environ.get("DSDDMM_BLOCK_ROWS", "512"))
-DEFAULT_BLOCK_COLS = int(os.environ.get("DSDDMM_BLOCK_COLS", "512"))
+# Dense block sizes for the one-hot kernels' (row, col) windows. None (the
+# default) lets build_blocked size them to the tile set's density
+# (choose_blocks); DSDDMM_BLOCK_ROWS/COLS, or an autotune plan rebinding
+# these attributes, pin them — bench.py applies the best (blocks, group,
+# scatter form) combination measured in KERNELS_TPU.jsonl that way. A
+# pinned axis with the other unset takes BASE_BLOCK on the other.
+BASE_BLOCK = 512
+DEFAULT_BLOCK_ROWS = (int(os.environ["DSDDMM_BLOCK_ROWS"])
+                      if os.environ.get("DSDDMM_BLOCK_ROWS") else None)
+DEFAULT_BLOCK_COLS = (int(os.environ["DSDDMM_BLOCK_COLS"])
+                      if os.environ.get("DSDDMM_BLOCK_COLS") else None)
 
 # Scatter contraction form ("bt"/"nt") and step batching for the Pallas
 # kernels (consumed by ops/pallas_kernels.PallasKernel.__init__); defined
@@ -60,10 +65,11 @@ def knob_env_defaults() -> dict:
     """The effective kernel-knob values as the env-var strings bench.py
     passes to its workers — the single source of truth for its
     tuned-vs-first-rung dedup. Values reflect this process's environment
-    (each knob is env-overridable), falling back to the literals above."""
+    (each knob is env-overridable), falling back to the literals above;
+    unpinned block sizes read "auto" (sized by :func:`choose_blocks`)."""
     return {
-        "DSDDMM_BLOCK_ROWS": str(DEFAULT_BLOCK_ROWS),
-        "DSDDMM_BLOCK_COLS": str(DEFAULT_BLOCK_COLS),
+        "DSDDMM_BLOCK_ROWS": str(DEFAULT_BLOCK_ROWS or "auto"),
+        "DSDDMM_BLOCK_COLS": str(DEFAULT_BLOCK_COLS or "auto"),
         "DSDDMM_CHUNK_GROUP": str(DEFAULT_GROUP),
         "DSDDMM_SCATTER_FORM": DEFAULT_SCATTER_FORM,
         "DSDDMM_CHUNK": str(CHUNK),
@@ -88,6 +94,139 @@ def pick_block(frame: int, preferred: int = 512) -> int:
 
 def pad_frame(frame: int, block: int) -> int:
     return -(-frame // block) * block
+
+
+#: Scoped VMEM Mosaic gives a kernel by default on a v5e.
+DEFAULT_SCOPED_VMEM = 16 << 20
+#: Scoped VMEM the tile kernels may ask for: ``pallas_kernels._tile_call``
+#: raises the limit to this when a kernel's windows outgrow the default,
+#: and the density rule admits only geometries whose footprint fits it.
+TILE_VMEM_LIMIT = 32 << 20
+
+
+def tile_vmem_bytes(bm: int, bn: int, R: int, group: int,
+                    itemsize: int = 2) -> int:
+    """VMEM a fused tile kernel holds at one geometry: the double-buffered
+    ``at`` window and ``group`` ``bt`` windows (MXU operands of
+    ``itemsize`` bytes), the double-buffered float32 output window, its
+    accumulator, and the gather one-hots with their int32 compares. The
+    windows are what Mosaic allocates on the kernel's stack (a v5e
+    compile of fused 2048x2048 at R=256, group 4, asks for 16.02 MiB);
+    SDDMM and SpMM hold a subset."""
+    windows = 2 * R * bm * itemsize + 2 * group * R * bn * itemsize
+    out = 2 * R * bm * 4 + R * bm * 4
+    one_hots = (bm + bn) * CHUNK * (itemsize + 4)
+    return windows + out + one_hots
+
+
+# The density rule. A chunk costs the same whatever its fill: three
+# one-hot matmuls of fixed extent ([R,bm]@[bm,128], [R,bn]@[bn,128],
+# [R,128]@[128,bm]) and a bt-window DMA. Blocks sized to the density
+# therefore trade dearer chunks for fewer, fuller ones. The cost below is
+# the fused kernel's (ALS and the pair run it; SDDMM and SpMM run a
+# subset of its work): t = t0 + k * R * (2*bm + bn), fitted to
+# scripts/tune_blocks.py on a v5e at group 4 (logM 18, nnz/row 32, R=128
+# at seven geometries from 512x512 to 2048x2048 and R=256 at 512x512,
+# within 4% of each; PERF.md section 5).
+#: Block sizes the rule weighs on each axis (pick_block clamps them).
+RULE_BLOCKS = (256, 512, 1024, 2048, 4096)
+_CHUNK_FIXED_NS = 269.1    # t0: per chunk, whatever the geometry
+_CHUNK_MXU_NS = 1.432e-3   # k: per unit of R * (2*bm + bn)
+_HBM_BYTES_PER_NS = 819.0  # v5e HBM bandwidth, for the bt-window DMA
+#: A geometry replaces BASE_BLOCK only where it models at least this much
+#: cheaper: the fit misses single geometries by up to 4%, and 256-row
+#: blocks run 15% slower than it says.
+_RULE_MARGIN = 0.9
+#: Largest (bucket, row block, col block) grid at the smallest candidate
+#: the rule counts; a larger tile set keeps BASE_BLOCK.
+_RULE_MAX_CELLS = 1 << 25
+
+
+def chunk_time_ns(bm: int, bn: int, R: int) -> float:
+    """Modeled time of one fused-kernel chunk: the larger of its one-hot
+    MXU work and its bt-window DMA (bfloat16 operands)."""
+    mxu = _CHUNK_FIXED_NS + _CHUNK_MXU_NS * R * (2 * bm + bn)
+    return max(mxu, R * bn * 2 / _HBM_BYTES_PER_NS)
+
+
+def _grid_chunks(counts: np.ndarray, group: int) -> int:
+    """Chunks a bucket of ``build_blocked`` at a [buckets, gr, gc] grid of
+    pair counts: each pair's ceil(count / CHUNK), one pad chunk for an
+    empty row block, each row block rounded up to ``group``, the most of
+    any bucket."""
+    per_gr = (-(-counts // CHUNK)).sum(axis=2)
+    per_gr = np.maximum(per_gr, 1)
+    per_gr += (-per_gr) % group
+    C = max(int(per_gr.sum(axis=1).max(initial=0)), 1)
+    return -(-C // group) * group
+
+
+def block_chunk_counts(n_buckets, bucket, local_r, local_c, tile_rows,
+                       tile_cols, group=1) -> dict:
+    """Exact chunk count a bucket (``BlockedMeta.n_chunks``) that
+    :func:`build_blocked` gives at each geometry ``(bm, bn)`` the sizes in
+    ``RULE_BLOCKS`` clamp to, within the packed-meta block limit. One
+    ``bincount`` over the smallest geometry's pair grid; a coarser
+    geometry's pair counts are sums of that grid (every size is a power of
+    two). Empty when that grid exceeds ``_RULE_MAX_CELLS``."""
+    geoms = sorted({
+        (pick_block(tile_rows, a), pick_block(tile_cols, b))
+        for a in RULE_BLOCKS for b in RULE_BLOCKS
+    })
+    geoms = [(bm, bn) for bm, bn in geoms
+             if -(-tile_rows // bm) <= MAX_BLOCKS
+             and -(-tile_cols // bn) <= MAX_BLOCKS]
+    if not geoms:
+        return {}
+    fm = min(bm for bm, _ in geoms)
+    fn = min(bn for _, bn in geoms)
+    big_m = max(bm for bm, _ in geoms) // fm
+    big_n = max(bn for _, bn in geoms) // fn
+    grf = -(-max(-(-tile_rows // fm), 1) // big_m) * big_m
+    gcf = -(-max(-(-tile_cols // fn), 1) // big_n) * big_n
+    cells = n_buckets * grf * gcf
+    if cells > _RULE_MAX_CELLS:
+        return {}
+    key = np.asarray(bucket, dtype=np.int64) * grf
+    key += np.asarray(local_r) // fm
+    key *= gcf
+    key += np.asarray(local_c) // fn
+    fine = np.bincount(key, minlength=cells).reshape(n_buckets, grf, gcf)
+    out = {}
+    for bm, bn in geoms:
+        sr, sc = bm // fm, bn // fn
+        grb = max(-(-tile_rows // bm), 1)
+        gcb = max(-(-tile_cols // bn), 1)
+        counts = fine[:, :grb * sr, :gcb * sc].reshape(
+            n_buckets, grb, sr, gcb, sc).sum(axis=(2, 4))
+        out[(bm, bn)] = _grid_chunks(counts, group)
+    return out
+
+
+def choose_blocks(n_buckets, bucket, local_r, local_c, tile_rows, tile_cols,
+                  R: int, group: int = 1) -> tuple:
+    """``(bm, bn)`` for a tile set: the geometry of least modeled kernel
+    time (exact chunk count × :func:`chunk_time_ns`) among those whose
+    :func:`tile_vmem_bytes` at ``R`` fits :data:`TILE_VMEM_LIMIT`, ties
+    to the smaller footprint. BASE_BLOCK square stays where the best is
+    not ``_RULE_MARGIN`` of its time, or where there is nothing to count."""
+    base = (pick_block(tile_rows, BASE_BLOCK), pick_block(tile_cols, BASE_BLOCK))
+    chunks = block_chunk_counts(n_buckets, bucket, local_r, local_c,
+                                tile_rows, tile_cols, group)
+    fits = {
+        g: c for g, c in chunks.items()
+        if tile_vmem_bytes(*g, R, group) <= TILE_VMEM_LIMIT
+    }
+    if not fits:
+        return base
+
+    def cost(g):
+        return fits[g] * chunk_time_ns(*g, R)
+
+    best = min(fits, key=lambda g: (cost(g), tile_vmem_bytes(*g, R, group)))
+    if base in fits and cost(best) > _RULE_MARGIN * cost(base):
+        return base
+    return best
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,8 +305,14 @@ def build_blocked(
     block_rows: int | None = None,
     block_cols: int | None = None,
     group: int = 1,
+    R: int | None = None,
 ) -> BlockedMeta:
     """Build the chunk-list encoding.
+
+    The block geometry is ``block_rows x block_cols`` where given, else
+    ``DEFAULT_BLOCK_ROWS/COLS`` where pinned; with neither, and the
+    kernels' inner dimension ``R`` known, :func:`choose_blocks` sizes it
+    to these nonzeros, else it is ``BASE_BLOCK`` square.
 
     Guarantees the kernels rely on:
 
@@ -202,8 +347,13 @@ def build_blocked(
         block_rows = DEFAULT_BLOCK_ROWS
     if block_cols is None:
         block_cols = DEFAULT_BLOCK_COLS
-    bm = pick_block(tile_rows, block_rows)
-    bn = pick_block(tile_cols, block_cols)
+    if block_rows is None and block_cols is None and R is not None:
+        block_rows, block_cols = choose_blocks(
+            n_buckets, bucket, local_r, local_c, tile_rows, tile_cols,
+            R, group,
+        )
+    bm = pick_block(tile_rows, block_rows or BASE_BLOCK)
+    bn = pick_block(tile_cols, block_cols or BASE_BLOCK)
     gr_blocks = max(-(-tile_rows // bm), 1)
     gc_blocks = max(-(-tile_cols // bn), 1)
     if gr_blocks > MAX_BLOCKS or gc_blocks > MAX_BLOCKS:

@@ -640,6 +640,11 @@ def _tile_call(
     else:
         raise ValueError(op)
 
+    params = {"dimension_semantics": ("arbitrary",)}
+    vmem = blocked.tile_vmem_bytes(bm, bn, R, G, bt.dtype.itemsize)
+    if vmem > blocked.DEFAULT_SCOPED_VMEM:
+        params["vmem_limit_bytes"] = max(blocked.TILE_VMEM_LIMIT, vmem)
+
     def launch(m, s0, n):
         """Steps ``[s0, s0 + n)`` with ``m`` = their metadata."""
         chunk_in = pl.BlockSpec((1, G, CHUNK), lambda t, m: (t + s0, 0, 0))
@@ -661,9 +666,7 @@ def _tile_call(
             body,
             grid_spec=grid_spec,
             out_shape=out_shapes,
-            compiler_params=compat.pallas_tpu_compiler_params(
-                dimension_semantics=("arbitrary",)
-            ),
+            compiler_params=compat.pallas_tpu_compiler_params(**params),
             interpret=interpret,
             name=kernel_name(op),
         )(m, lr3, lc3, sv3, *dense)

@@ -522,18 +522,25 @@ class DistributedSparse(abc.ABC):
         )
 
     def _note_tile_metrics(self) -> None:
-        """Record the counted padded-lane fraction of each tile set as a
-        per-op metric gauge (scraped via ``/metrics`` and landed in
-        bench records) — the waste the codegen banked variants exist to
-        shrink. Called by strategy constructors once tiles exist."""
+        """Record each tile set's counted padded-lane fraction, its block
+        geometry (``block_rows`` x ``block_cols``, in the encoding's own
+        orientation) and its chunks a (device, tile) bucket as per-op
+        metric gauges (scraped via ``/metrics`` and landed in bench
+        records): the waste the density rule and the codegen banked
+        variants exist to shrink, and what they chose. Called by strategy
+        constructors once tiles exist."""
         a_side = ("sddmmA", "spmmA", "fusedSpMM", "cgStep", "gatLayer")
         b_side = ("sddmmB", "spmmB")
         for tiles, ops in ((self.S_tiles, a_side), (self.ST_tiles, b_side)):
             frac = getattr(tiles, "blk_pad_frac", None)
             if frac is None:
                 continue
+            bm, bn = tiles.blk_geom[:2]
             for op in ops:
-                self.metrics.note(op, padded_lane_frac=round(frac, 6))
+                self.metrics.note(
+                    op, padded_lane_frac=round(frac, 6), block_rows=bm,
+                    block_cols=bn, n_chunks=int(tiles.blk_meta.shape[-1]),
+                )
 
     def _sddmm_args(self, tiles, vals) -> tuple:
         """Tile operands following the dense args for sddmm programs."""

@@ -114,12 +114,12 @@ class CannonDense25D(DistributedSparse):
         self.S_tiles = build_tiles(
             S, grid, BlockCyclic25D(self.M_pad, self.N_pad, sqrtpc, c),
             tile_rows=self.localArows * c, tile_cols=self.localBrows, dtype=dtype,
-            block=block, block_swap=True, variant=variant,
+            block=block, block_swap=True, variant=variant, R=R,
         )
         self.ST_tiles = build_tiles(
             S.transpose(), grid, BlockCyclic25D(self.N_pad, self.M_pad, sqrtpc, c),
             tile_rows=self.localBrows * c, tile_cols=self.localArows, dtype=dtype,
-            block=block, block_swap=True, variant=variant,
+            block=block, block_swap=True, variant=variant, R=R,
         )
         self._note_tile_metrics()
 

@@ -113,12 +113,12 @@ class CannonSparse25D(DistributedSparse):
         self.S_tiles = build_replicated_tiles(
             S, grid, Floor2D(self.M_pad, self.N_pad, sqrtpc),
             tile_rows=self.localArows, tile_cols=self.localBrows, dtype=dtype,
-            block=block, variant=variant,
+            block=block, variant=variant, R=R,
         )
         self.ST_tiles = build_replicated_tiles(
             S.transpose(), grid, Floor2D(self.N_pad, self.M_pad, sqrtpc),
             tile_rows=self.localBrows, tile_cols=self.localArows, dtype=dtype,
-            block=block, variant=variant,
+            block=block, variant=variant, R=R,
         )
         self._note_tile_metrics()
 

@@ -112,12 +112,12 @@ class DenseShift15D(DistributedSparse):
         self.S_tiles = build_tiles(
             S, grid, layout_s,
             tile_rows=self.localArows * c, tile_cols=self.localBrows, dtype=dtype,
-            block=block, variant=variant,
+            block=block, variant=variant, R=R,
         )
         self.ST_tiles = build_tiles(
             S.transpose(), grid, layout_st,
             tile_rows=self.localBrows * c, tile_cols=self.localArows, dtype=dtype,
-            block=block, variant=variant,
+            block=block, variant=variant, R=R,
         )
         self._note_tile_metrics()
 

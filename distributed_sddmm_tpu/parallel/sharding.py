@@ -222,6 +222,7 @@ def build_replicated_tiles(
     dtype=jnp.float32,
     block: bool = False,
     variant=None,
+    R: int | None = None,
 ) -> ReplicatedTiles:
     """Bucket nonzeros onto the 2-D grid floor, replicate structure across
     layers, shard values 1/c per layer (contiguous equal slices).
@@ -252,7 +253,7 @@ def build_replicated_tiles(
             obs_metrics.GLOBAL.add("codegen_generic_fallbacks")
             variant = None
         blocked, blk_variant = _try_build_blocked(
-            n_buckets, dev, res, tile_rows, tile_cols, variant=variant
+            n_buckets, dev, res, tile_rows, tile_cols, variant=variant, R=R,
         )
         if blocked is not None:
             from distributed_sddmm_tpu.ops.blocked import CHUNK, pad_chunk_count
@@ -362,6 +363,7 @@ def build_tiles(
     block: bool = False,
     block_swap: bool = False,
     variant=None,
+    R: int | None = None,
 ) -> TileSet:
     """Bucket ``S``'s nonzeros by (device, tile) and pad to a static shape.
 
@@ -389,6 +391,10 @@ def build_tiles(
     list presented through the same ``blk_*`` fields plus ``blk_bands``.
     When banking is impossible (degenerate block grids) the build falls
     back to the generic encoding and counts ``codegen_generic_fallbacks``.
+
+    ``R``, the kernels' inner dimension, lets the generic encoding size
+    its blocks to the tile set's density (``ops.blocked.choose_blocks``)
+    where no geometry is pinned; without it the blocks are 512 square.
     """
     nr, nc, nh = grid.nr, grid.nc, grid.nh
     T = layout.n_tiles
@@ -410,7 +416,7 @@ def build_tiles(
     if block:
         blocked, blk_variant = _try_build_blocked(
             n_buckets, bucket, res, tile_rows, tile_cols, swap=block_swap,
-            variant=variant,
+            variant=variant, R=R,
         )
         # Banded encodings consume their rungs per band inside
         # build_banded; the generic encoding takes one rung on its total
@@ -515,17 +521,23 @@ _BLOCK_PAIR_LIMIT = 200_000_000
 
 
 def _try_build_blocked(n_buckets, bucket, res, tile_rows, tile_cols,
-                       swap=False, variant=None):
+                       swap=False, variant=None, R=None):
     """Returns ``(blocked_meta_or_None, realized_variant_id)``: the
     second element is the variant id ONLY when the variant actually
     shaped the encoding — a guard fallback returns None there, so
     records/keys never claim a specialization that did not build
     (``kernel_variant`` is a gate config axis; a mislabeled generic run
-    would pool into the variant baseline)."""
+    would pool into the variant baseline). Inside a dynamic-capacity
+    scope the generic encoding keeps BASE_BLOCK: a rebind must land on
+    the geometry its programs were traced for, whatever the new
+    pattern's density."""
     from distributed_sddmm_tpu.ops.blocked import (
-        DEFAULT_BLOCK_COLS, DEFAULT_BLOCK_ROWS, DEFAULT_GROUP,
+        BASE_BLOCK, DEFAULT_BLOCK_COLS, DEFAULT_BLOCK_ROWS, DEFAULT_GROUP,
         build_blocked, pick_block,
     )
+
+    if buckets.dyn_capacity_state() is not None:
+        R = None
 
     local_r, local_c = res.local_r, res.local_c
     if swap:
@@ -570,7 +582,8 @@ def _try_build_blocked(n_buckets, bucket, res, tile_rows, tile_cols,
 
             obs_metrics.GLOBAL.add("codegen_generic_fallbacks")
             variant = None
-    if _est_pairs(DEFAULT_BLOCK_ROWS, DEFAULT_BLOCK_COLS, 1) > _BLOCK_PAIR_LIMIT:
+    if _est_pairs(DEFAULT_BLOCK_ROWS or BASE_BLOCK,
+                  DEFAULT_BLOCK_COLS or BASE_BLOCK, 1) > _BLOCK_PAIR_LIMIT:
         return None, None
     if variant is not None and getattr(variant, "banked", False):
         from distributed_sddmm_tpu.codegen.banded import build_banded
@@ -596,6 +609,5 @@ def _try_build_blocked(n_buckets, bucket, res, tile_rows, tile_cols,
         ), variant.variant_id
     return build_blocked(
         n_buckets, bucket, local_r, local_c, tile_rows, tile_cols,
-        block_rows=DEFAULT_BLOCK_ROWS, block_cols=DEFAULT_BLOCK_COLS,
-        group=DEFAULT_GROUP,
+        group=DEFAULT_GROUP, R=R,
     ), None

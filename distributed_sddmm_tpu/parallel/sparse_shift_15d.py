@@ -107,12 +107,12 @@ class SparseShift15D(DistributedSparse):
         self.S_tiles = build_tiles(
             S, grid, ShardedBlockRow(self.M_pad, self.N_pad, p, c),
             tile_rows=self.blockAwidth, tile_cols=self.N_pad, dtype=dtype,
-            block=block, variant=variant,
+            block=block, variant=variant, R=R,
         )
         self.ST_tiles = build_tiles(
             S.transpose(), grid, ShardedBlockRow(self.N_pad, self.M_pad, p, c),
             tile_rows=self.blockBwidth, tile_cols=self.M_pad, dtype=dtype,
-            block=block, variant=variant,
+            block=block, variant=variant, R=R,
         )
         self._note_tile_metrics()
 

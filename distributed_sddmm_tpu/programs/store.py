@@ -603,13 +603,14 @@ def strategy_config_tag(alg) -> str:
     (algorithm, c, kernel) configurations — a heatmap sweep benchmarks
     every algorithm at every cell, and a re-measured plan can change its
     algorithm under an unchanged fingerprint — so the key must carry
-    the configuration or entries would alias across them. Tile geometry
-    and block shapes are already covered by the aval signature; this tag
-    covers what avals cannot see: the strategy class, replication
-    factor, the ring-build knobs (overlap fusion, rolled loops — same
-    avals, different traced program), and the kernel knobs that reshape
-    the traced program without changing argument shapes (precision,
-    gather chunking, scatter form, batch step).
+    the configuration or entries would alias across them. Tile shapes
+    are covered by the aval signature; this tag covers what avals cannot
+    see: the strategy class, replication factor, the ring-build knobs
+    (overlap fusion, rolled loops — same avals, different traced
+    program), the kernel knobs that reshape the traced program without
+    changing argument shapes (precision, gather chunking, scatter form,
+    batch step), and each blocked tile set's block geometry (the density
+    rule sizes it per matrix, and two geometries can share a chunk count).
     """
     kern = alg.kernel
     cls = type(kern).__name__
@@ -636,6 +637,10 @@ def strategy_config_tag(alg) -> str:
         v = getattr(kern, attr, None)
         if v is not None:
             bits.append(f"{attr[:4]}{v}")
+    for tiles in (getattr(alg, "S_tiles", None), getattr(alg, "ST_tiles", None)):
+        geom = getattr(tiles, "blk_geom", None)
+        if geom is not None:
+            bits.append(f"b{geom[0]}x{geom[1]}")
     return "-".join(bits)
 
 

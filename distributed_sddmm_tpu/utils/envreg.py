@@ -57,10 +57,12 @@ KNOBS: dict[str, Knob] = {k.name: k for k in [
     _K("DSDDMM_BATCH_STEP", "flag", "0",
        "batch grid steps in the blocked Pallas kernels (README: step "
        "batching)"),
-    _K("DSDDMM_BLOCK_COLS", "int", "512",
-       "blocked-kernel column tile size"),
-    _K("DSDDMM_BLOCK_ROWS", "int", "512",
-       "blocked-kernel row tile size"),
+    _K("DSDDMM_BLOCK_COLS", "int", "auto",
+       "blocked-kernel column tile size; unset, sized to the tile set's "
+       "density (ops/blocked.py)"),
+    _K("DSDDMM_BLOCK_ROWS", "int", "auto",
+       "blocked-kernel row tile size; unset, sized to the tile set's "
+       "density (ops/blocked.py)"),
     _K("DSDDMM_CHAOS", "spec", "off",
        "`bench fleet` chaos schedule when --chaos is unset: "
        "kind[:target]@frac[/dur][:param];... (resilience/chaos.py)"),

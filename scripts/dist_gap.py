@@ -75,7 +75,7 @@ def build_tile_setup(kern, log_m: int, npr: int, R: int):
     meta = build_blocked(
         1, np.zeros(S.nnz, np.int64), S.rows, S.cols, S.M, S.N,
         block_rows=DEFAULT_BLOCK_ROWS, block_cols=DEFAULT_BLOCK_COLS,
-        group=DEFAULT_GROUP,
+        group=DEFAULT_GROUP, R=R,
     )
     blk = BlockedTile(
         lr=jnp.array(meta.lr[0]), lc=jnp.array(meta.lc[0]),
@@ -208,7 +208,8 @@ def main() -> int:
     kern = PallasKernel()
     cfg = {
         "logM": log_m, "npr": npr, "R": R,
-        "blocks": f"{DEFAULT_BLOCK_ROWS}x{DEFAULT_BLOCK_COLS}",
+        "blocks": f"{DEFAULT_BLOCK_ROWS or 'auto'}x"
+                  f"{DEFAULT_BLOCK_COLS or 'auto'}",
         "group": DEFAULT_GROUP, "scatter_form": kern.scatter_form,
         "chunk": CHUNK, "batch_step": kern.batch_step,
         "backend": jax.default_backend(),

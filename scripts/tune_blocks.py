@@ -213,6 +213,8 @@ def main():
                "fused_pair_ms": t_f * 1e3,
                "sddmm_ms": t_s and t_s * 1e3, "spmm_ms": t_m and t_m * 1e3,
                "fused_ns_per_chunk": t_f / meta.n_chunks * 1e9,
+               "sddmm_ns_per_chunk": t_s and t_s / meta.n_chunks * 1e9,
+               "spmm_ns_per_chunk": t_m and t_m / meta.n_chunks * 1e9,
                "fused_pair_gflops": 2 * flops / t_f / 1e9,
                "sddmm_gflops": t_s and flops / t_s / 1e9,
                "spmm_gflops": t_m and flops / t_m / 1e9}

@@ -60,7 +60,11 @@ def main() -> int:
     server = AdminServer(
         snapshot_fn=snapshot, submit_fn=submit, port=args.admin_port,
     ).start()
-    stop.wait()
+    # Poll rather than block: a process-directed SIGTERM may land on a
+    # server thread (as it tends to right after a SIGCONT), and Python runs
+    # the handler only once the main thread is back in the interpreter.
+    while not stop.wait(0.1):
+        pass
     server.stop()
     record = {
         "app": "fleet-worker-stub", "name": args.name, "role": args.role,

@@ -88,12 +88,23 @@ def _check_compiled(compiled):
     assert need < V5E_HBM_BYTES, need
 
 
-def test_fused_pair_compiles_for_v5e(smoke_alg):
+def test_fused_pair_compiles_for_v5e(smoke_alg, monkeypatch):
+    """The pair at the geometry the density rule sizes for it, its chunk
+    list cut into several SMEM segments as a longer list would be."""
+    from distributed_sddmm_tpu.ops import pallas_kernels
+
     _, alg, cpu = smoke_alg
-    assert alg.S_tiles.blk_lr.shape[-2] > 2**17  # several SMEM segments
-    prog, operands = alg.fused_program(alg.like_s_values(1.0))
-    args = _sds(alg, (cpu["A"], cpu["B"], *operands))
-    _check_compiled(prog.lower(*args).compile())
+    C = alg.S_tiles.blk_lr.shape[-2]
+    assert alg.S_tiles.blk_geom[:2] != (512, 512)
+    monkeypatch.setattr(pallas_kernels, "MAX_PREFETCH_CHUNKS", C // 3)
+    pallas_kernels._tile_call.clear_cache()
+    alg._programs.clear()
+    try:
+        prog, operands = alg.fused_program(alg.like_s_values(1.0))
+        args = _sds(alg, (cpu["A"], cpu["B"], *operands))
+        _check_compiled(prog.lower(*args).compile())
+    finally:
+        pallas_kernels._tile_call.clear_cache()
 
 
 def test_als_cg_step_compiles_for_v5e(smoke_alg):

@@ -31,12 +31,15 @@ def spmm_oracle(rows, cols, vals, B, out_rows):
     return oracle.spmm_a(S, B.astype(np.float64))
 
 
-def _tile_setup(Mr=700, Nc=500, nnz=3000, seed=0, group=1):
+def _tile_setup(Mr=700, Nc=500, nnz=3000, seed=0, group=1, blocks=None):
     rng = np.random.default_rng(seed)
     rows = rng.integers(0, Mr, nnz).astype(np.int64)
     cols = rng.integers(0, Nc, nnz).astype(np.int64)
     bucket = np.zeros(nnz, dtype=np.int64)
-    meta = build_blocked(1, bucket, rows, cols, Mr, Nc, group=group)
+    bm, bn = blocks or (None, None)
+    meta = build_blocked(1, bucket, rows, cols, Mr, Nc, group=group,
+                         block_rows=bm, block_cols=bn)
+    assert blocks is None or (meta.bm, meta.bn) == blocks
     blk = BlockedTile(
         lr=jnp.array(meta.lr[0]),
         lc=jnp.array(meta.lc[0]),
@@ -139,32 +142,162 @@ class TestBlockedMeta:
         assert last.sum() == meta.gr_blocks
 
 
+def _uniform_tiles(per_block, frame=1 << 14, seed=0):
+    """One tile of a uniform random pattern whose 512x512 blocks hold
+    ``per_block`` nonzeros on average."""
+    rng = np.random.default_rng(seed)
+    nnz = per_block * (frame // 512) ** 2
+    rows = rng.integers(0, frame, nnz).astype(np.int64)
+    cols = rng.integers(0, frame, nnz).astype(np.int64)
+    return np.zeros(nnz, np.int64), rows, cols, frame
+
+
+class TestDensityRule:
+    """``choose_blocks``: block geometry from the tile set's own
+    nonzeros when none is pinned."""
+
+    @pytest.mark.parametrize("group", [1, 4])
+    def test_chunk_counts_are_the_builds(self, group):
+        from distributed_sddmm_tpu.ops.blocked import block_chunk_counts
+
+        rng = np.random.default_rng(5)
+        nnz = 20000
+        bucket = rng.integers(0, 3, nnz)
+        rows = rng.integers(0, 3000, nnz)
+        cols = rng.integers(0, 5000, nnz)
+        counts = block_chunk_counts(3, bucket, rows, cols, 3000, 5000, group)
+        assert len(counts) == 25
+        for (bm, bn), c in counts.items():
+            meta = build_blocked(3, bucket, rows, cols, 3000, 5000,
+                                 block_rows=bm, block_cols=bn, group=group)
+            assert (meta.bm, meta.bn, meta.n_chunks) == (bm, bn, c)
+
+    def test_full_blocks_keep_base(self):
+        """Where a 512x512 block already averages a full chunk (round 5's
+        logM 16 at nnz/row 32), the rule keeps 512x512."""
+        from distributed_sddmm_tpu.ops.blocked import choose_blocks
+
+        bucket, rows, cols, n = _uniform_tiles(CHUNK)
+        assert choose_blocks(1, bucket, rows, cols, n, n, R=128,
+                             group=4) == (512, 512)
+
+    @pytest.mark.parametrize("per_block", [32, 8])
+    def test_sparse_blocks_grow(self, per_block):
+        """At 32 and 8 nonzeros a 512x512 block, a larger geometry models
+        strictly cheaper than 512x512, and the rule takes it."""
+        from distributed_sddmm_tpu.ops.blocked import (
+            block_chunk_counts, choose_blocks, chunk_time_ns,
+        )
+
+        bucket, rows, cols, n = _uniform_tiles(per_block)
+        bm, bn = choose_blocks(1, bucket, rows, cols, n, n, R=128, group=4)
+        assert bm * bn > 512 * 512
+        counts = block_chunk_counts(1, bucket, rows, cols, n, n, 4)
+        assert (counts[bm, bn] * chunk_time_ns(bm, bn, 128)
+                < counts[512, 512] * chunk_time_ns(512, 512, 128))
+        meta = build_blocked(1, bucket, rows, cols, n, n, group=4, R=128)
+        assert (meta.bm, meta.bn, meta.n_chunks) == (bm, bn, counts[bm, bn])
+
+    @pytest.mark.parametrize("R", [256, 1024])
+    def test_vmem_bound_holds(self, R):
+        from distributed_sddmm_tpu.ops.blocked import (
+            TILE_VMEM_LIMIT, choose_blocks, tile_vmem_bytes,
+        )
+
+        # At 2 nonzeros a 512x512 block the largest geometry would model
+        # cheapest; at these R its footprint is over the limit.
+        bucket, rows, cols, n = _uniform_tiles(2)
+        assert tile_vmem_bytes(4096, 4096, R, 4) > TILE_VMEM_LIMIT
+        bm, bn = choose_blocks(1, bucket, rows, cols, n, n, R=R, group=4)
+        assert tile_vmem_bytes(bm, bn, R, 4) <= TILE_VMEM_LIMIT
+
+    def test_explicit_geometry_wins(self, monkeypatch):
+        """A caller's blocks, pinned module defaults (the env knobs, an
+        autotune plan) and an unknown R all bypass the rule; pinned at
+        512x512 the layout is byte-identical to the unsized build."""
+        from distributed_sddmm_tpu.autotune.candidates import Candidate
+        from distributed_sddmm_tpu.autotune.measure import block_knobs
+        from distributed_sddmm_tpu.ops import blocked
+
+        bucket, rows, cols, n = _uniform_tiles(8)
+
+        def build(**kw):
+            return build_blocked(1, bucket, rows, cols, n, n, group=4, **kw)
+
+        auto = build(R=128)
+        assert (auto.bm, auto.bn) != (512, 512)
+        assert (build(R=128, block_rows=256, block_cols=1024).bm,
+                build(R=128, block_rows=256, block_cols=1024).bn) == (256, 1024)
+        base = build()
+        assert (base.bm, base.bn) == (512, 512)
+        with block_knobs(Candidate("15d_fusion2", 1, kernel="pallas",
+                                   block=(1024, 512))):
+            tuned = build(R=128)
+        assert (tuned.bm, tuned.bn) == (1024, 512)
+        monkeypatch.setattr(blocked, "DEFAULT_BLOCK_ROWS", 512)
+        monkeypatch.setattr(blocked, "DEFAULT_BLOCK_COLS", 512)
+        pinned = build(R=128)
+        for field in ("lr", "lc", "meta", "host_to_chunk", "pad_lane"):
+            np.testing.assert_array_equal(getattr(pinned, field),
+                                          getattr(base, field))
+
+    def test_strategy_notes_its_geometry(self):
+        """A blocked strategy builds with its R, and its metrics carry each
+        tile set's geometry and chunk count beside the padded fraction."""
+        S = HostCOO.erdos_renyi(260, 220, 5, seed=3, values="normal")
+        alg = DenseShift15D(S, R=16, kernel=PallasKernel(precision="f32",
+                                                         interpret=True))
+        gauges = alg.metrics.gauges()
+        for op, tiles in (("fusedSpMM", alg.S_tiles), ("spmmB", alg.ST_tiles)):
+            bm, bn = tiles.blk_geom[:2]
+            assert gauges[op]["block_rows"] == bm
+            assert gauges[op]["block_cols"] == bn
+            assert gauges[op]["n_chunks"] == tiles.blk_meta.shape[-1]
+            assert 0.0 <= gauges[op]["padded_lane_frac"] < 1.0
+
+
+def _oracle_case(precision, tol, group, form, batch, blocks=None,
+                 slow=False):
+    """One row of the oracle test, its id the five kernel knobs (and the
+    geometry where one is pinned)."""
+    case_id = "-".join(map(str, (precision, tol, group, form, batch)))
+    if blocks:
+        case_id += f"-{blocks[0]}x{blocks[1]}"
+    return pytest.param(precision, tol, group, form, batch, blocks,
+                        id=case_id,
+                        marks=[pytest.mark.slow] if slow else [])
+
+
 class TestPallasTileKernels:
     # Slow-marked rows are single-axis redundancies: every axis keeps a
     # fast representative — grouping×form×batch interactions stay via
     # (4,bt,True)/(4,nt,True)/(8,bt,True), ungrouped bases via
     # (1,bt,False)/(1,nt,False), bf16 via both its rows.
+    # The rectangular geometries are ones the density rule
+    # (ops/blocked.choose_blocks) picks for sparse tile sets; their frame
+    # is wide enough that pick_block keeps them.
     @pytest.mark.parametrize(
-        "precision,tol,group,form,batch",
+        "precision,tol,group,form,batch,blocks",
         [
-            ("f32", 1e-5, 1, "bt", False),
-            ("bf16", 3e-2, 1, "bt", False),
-            pytest.param("f32", 1e-5, 4, "bt", False,
-                         marks=pytest.mark.slow),
-            ("f32", 1e-5, 1, "nt", False),
-            pytest.param("f32", 1e-5, 4, "nt", False,
-                         marks=pytest.mark.slow),
-            pytest.param("f32", 1e-5, 1, "bt", True,
-                         marks=pytest.mark.slow),
-            ("f32", 1e-5, 4, "bt", True),
-            ("f32", 1e-5, 4, "nt", True),
-            ("f32", 1e-5, 8, "bt", True),
-            ("bf16", 3e-2, 4, "bt", True),
+            _oracle_case("f32", 1e-5, 1, "bt", False),
+            _oracle_case("bf16", 3e-2, 1, "bt", False),
+            _oracle_case("f32", 1e-5, 4, "bt", False, slow=True),
+            _oracle_case("f32", 1e-5, 1, "nt", False),
+            _oracle_case("f32", 1e-5, 4, "nt", False, slow=True),
+            _oracle_case("f32", 1e-5, 1, "bt", True, slow=True),
+            _oracle_case("f32", 1e-5, 4, "bt", True),
+            _oracle_case("f32", 1e-5, 4, "nt", True),
+            _oracle_case("f32", 1e-5, 8, "bt", True),
+            _oracle_case("bf16", 3e-2, 4, "bt", True),
+            _oracle_case("f32", 1e-5, 4, "bt", False, (512, 1024)),
+            _oracle_case("bf16", 3e-2, 4, "bt", False, (1024, 2048)),
         ],
     )
-    def test_against_oracle(self, precision, tol, group, form, batch):
-        rows, cols, meta, blk, vals, rng = _tile_setup(group=group)
-        Mr, Nc, R = 700, 500, 32
+    def test_against_oracle(self, precision, tol, group, form, batch,
+                            blocks):
+        Mr, Nc, R = (700, 500, 32) if blocks is None else (1100, 2100, 32)
+        rows, cols, meta, blk, vals, rng = _tile_setup(
+            Mr=Mr, Nc=Nc, group=group, blocks=blocks)
         A = rng.standard_normal((Mr, R)).astype(np.float32)
         B = rng.standard_normal((Nc, R)).astype(np.float32)
         k = PallasKernel(precision=precision, interpret=True,
