@@ -24,6 +24,10 @@ from benchmark import program, sparse_ref
 from benchmark.apps import als_ref
 
 
+def tiny(cfg: dict) -> dict:
+    return dict(cfg, R=16, cg_iters=3)
+
+
 def work(cfg: dict, nnz: int, M: int, N: int) -> dict:
     """Per alternating step: the model FLOPs and, per sparse op, its FLOPs
     and least bytes (S's rows, cols and values, each dense operand read

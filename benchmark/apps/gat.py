@@ -21,6 +21,10 @@ from benchmark import program, sparse_ref
 from benchmark.apps import gat_ref
 
 
+def tiny(cfg: dict) -> dict:
+    return dict(cfg, input_features=16, features_per_head=16, heads=[2, 2, 3])
+
+
 def work(cfg: dict, nnz: int, M: int, N: int) -> dict:
     """Per forward: model FLOPs (projections and sparse ops) and each
     sparse op's FLOPs and least bytes (4 bytes an element)."""

@@ -21,6 +21,10 @@ import jax.numpy as jnp
 from benchmark import program, sparse_ref
 
 
+def tiny(cfg: dict) -> dict:
+    return dict(cfg, R=16)
+
+
 def work(cfg: dict, nnz: int, M: int, N: int) -> dict:
     R = cfg["R"]
     fused = (4 * nnz * R, 12 * nnz + 4 * (M + N) * R + 4 * M * R + 4 * nnz)

@@ -51,7 +51,7 @@ def main(argv=None, root: str = harness.ROOT) -> int:
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
         rng, key, _ = harness.seeds(seed)
-        mat = harness.make_matrix(cell.cfg["matrix"], rng)
+        mat = harness.make_matrix(cell.cfg["matrix"], rng, root)
         r = cell.app.Run(cell.cfg, mat, key, used, cell.cfg["check"])
         for _ in range(args.units):
             r.unit()

@@ -7,7 +7,11 @@ a traffic mix or a per-layer metric as new files:
 - ``BENCHMARK.json`` (checkout root): the cells and the metrics;
 - ``configs/<config>.json``: the sizes; its ``app`` names
   ``apps/<app>.py``, which builds the program, drives one unit of work,
-  counts the work and holds the plain reference (``apps/<app>_ref.py``);
+  counts the work and holds the plain reference (``apps/<app>_ref.py``),
+  and its ``matrix.generator`` names ``matrices/<generator>.py``;
+- ``matrices/<generator>.py``: ``make(spec, rng)`` draws the matrix a
+  configuration's ``matrix`` describes, as ``{"rows", "cols", "vals",
+  "M", "N"}``, from the seed's matrix stream;
 - ``traffic/<traffic>.json``: the parameters of a mix, which the one
   generator (``generator.py``) reads to drive the window;
 - ``metrics/<metric>.py``: a reader ``read(ctx)`` of one metric: of the
@@ -22,6 +26,11 @@ returns when the device has finished it, ``keep()`` keeps the last
 unit's answer for the check, ``release()`` drops the program,
 ``outputs()`` gives the kept answers and ``reference(quant)`` the plain
 reference's, as ``{name: (array, ...)}``.
+
+Both an app module and a generator module also define ``tiny``: the
+configuration (``tiny(cfg)``) or its ``matrix`` (``tiny(spec)``) with the
+keys that module reads cut to a size the CPU tests run. The benchmark's
+runs never call it.
 """
 
 from __future__ import annotations
@@ -174,15 +183,22 @@ def enable_compile_cache(work: str) -> str:
     return path
 
 
-def make_matrix(spec: dict, rng) -> dict:
-    from benchmark import rmat
+def generator_module(name: str, root: str = ROOT):
+    """``benchmark/matrices/<name>.py`` under ``root``; an unknown name
+    exits with the list of the generators there."""
+    mdir = os.path.join(root, "benchmark", "matrices")
+    path = os.path.join(mdir, name + ".py")
+    if not os.path.isfile(path):
+        known = sorted(f[:-3] for f in os.listdir(mdir)
+                       if f.endswith(".py") and f != "__init__.py")
+        raise SystemExit(f"unknown matrix generator {name!r}; known: {known}")
+    return load_module(path, "benchmark.matrices." + name)
 
-    if spec["generator"] != "rmat":
-        raise SystemExit(f"unknown matrix generator {spec['generator']!r}")
-    mat = rmat.rmat(spec["log_m"], spec["edge_factor"], spec["initiator"], rng)
-    if spec.get("values", "ones") == "uniform":
-        mat["vals"] = rng.uniform(0.5, 1.5, mat["rows"].size)
-    return mat
+
+def make_matrix(spec: dict, rng, root: str = ROOT) -> dict:
+    """The configuration's matrix as COO arrays and its shape, by the
+    generator that ``spec["generator"]`` names."""
+    return generator_module(spec["generator"], root).make(spec, rng)
 
 
 def compare(got: dict, want: dict) -> dict:
@@ -249,7 +265,7 @@ def run(args, root: str = ROOT, require_tpu: bool = True) -> int:
 
     rng, key, sample = seeds(args.seed)
     t0 = time.perf_counter()
-    mat = make_matrix(cell.cfg["matrix"], rng)
+    mat = make_matrix(cell.cfg["matrix"], rng, root)
     nnz, M, N = mat["rows"].size, mat["M"], mat["N"]
     t_matrix = time.perf_counter() - t0
     check = cell.cfg["check"]

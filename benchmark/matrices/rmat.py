@@ -1,5 +1,10 @@
 """The benchmark's own R-mat generator, seeded by ``--seed``.
 
+A configuration's ``matrix`` with ``"generator": "rmat"`` gives
+``log_m``, ``edge_factor``, ``initiator`` and ``values`` (``"ones"`` or
+``"uniform"``, the latter in [0.5, 1.5) from the same stream after the
+pattern).
+
 A copy of the program's vectorised numpy R-mat path
 (``distributed_sddmm_tpu/native.py::rmat_edges`` fallback, with
 ``HostCOO.rmat``'s dedup and Graph500 vertex permutation), kept here so
@@ -66,3 +71,16 @@ def rmat(log_m: int, edge_factor: int, initiator, rng) -> dict:
     rows, cols = perm[rows[keep]], perm[cols[keep]]
     return {"rows": rows, "cols": cols, "vals": np.ones(rows.size),
             "M": M, "N": M}
+
+
+def make(spec: dict, rng) -> dict:
+    """The matrix ``spec`` describes, drawn from ``rng``."""
+    mat = rmat(spec["log_m"], spec["edge_factor"], spec["initiator"], rng)
+    if spec.get("values", "ones") == "uniform":
+        mat["vals"] = rng.uniform(0.5, 1.5, mat["rows"].size)
+    return mat
+
+
+def tiny(spec: dict) -> dict:
+    """``spec`` cut to a size the CPU tests run: 256 rows, 8 a row."""
+    return dict(spec, log_m=8, edge_factor=8)

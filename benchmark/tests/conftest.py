@@ -19,17 +19,10 @@ os.environ["XLA_FLAGS"] = " ".join(
 
 import pytest  # noqa: E402
 
-#: Tiny stand-ins for every configuration: same apps, small sizes.
-TINY = {
-    "matrix": {"log_m": 8, "edge_factor": 8},
-    "R": 16, "cg_iters": 3,
-    "input_features": 16, "features_per_head": 16, "heads": [2, 2, 3],
-}
 
-
-def tiny_root(dst: str) -> str:
-    """A checkout with the benchmark's files and every configuration cut
-    to a tiny size: what a run needs, runnable on the CPU."""
+def copy_root(dst: str) -> str:
+    """A checkout with the benchmark's files, at full size, and a CPU
+    stand-in in the table of peaks."""
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), dst)
     shutil.copytree(os.path.join(ROOT, "benchmark"),
                     os.path.join(dst, "benchmark"),
@@ -41,17 +34,33 @@ def tiny_root(dst: str) -> str:
     table["cpu"] = dict(table["TPU v5 lite"], source="test stand-in")
     with open(peaks, "w") as f:
         json.dump(table, f)
-    cdir = os.path.join(dst, "benchmark", "configs")
+    return dst
+
+
+def cut_configs(root: str) -> None:
+    """Every configuration under ``root`` cut to its tiny stand-in: its
+    matrix by its generator's ``tiny``, the rest by its app's."""
+    from benchmark import harness
+
+    cdir = os.path.join(root, "benchmark", "configs")
     for name in os.listdir(cdir):
         path = os.path.join(cdir, name)
         with open(path) as f:
             cfg = json.load(f)
-        cfg["matrix"].update(TINY["matrix"])
-        for k, v in TINY.items():
-            if k in cfg and k != "matrix":
-                cfg[k] = v
+        gen = harness.generator_module(cfg["matrix"]["generator"], root)
+        app = harness.load_module(
+            os.path.join(root, "benchmark", "apps", cfg["app"] + ".py"),
+            "benchmark.apps." + cfg["app"])
+        cfg = app.tiny(dict(cfg, matrix=gen.tiny(cfg["matrix"])))
         with open(path, "w") as f:
             json.dump(cfg, f)
+
+
+def tiny_root(dst: str) -> str:
+    """A checkout with the benchmark's files and every configuration cut
+    to a tiny size: what a run needs, runnable on the CPU."""
+    copy_root(dst)
+    cut_configs(dst)
     return dst
 
 

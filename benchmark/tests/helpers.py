@@ -1,8 +1,10 @@
 """Running one cell in-process at a tiny size, without the chip."""
 
 import json
+import os
 
 from benchmark import harness
+from benchmark.tests.conftest import ROOT
 
 
 def run_cell(root, capsys, workload, seed=5, seconds=0.5, trace=0):
@@ -16,4 +18,17 @@ def run_cell(root, capsys, workload, seed=5, seconds=0.5, trace=0):
     return json.loads(out[-1])
 
 
-CELLS = ("als-rmat18", "gat-rmat18", "pair-rmat20-4chip")
+def _apps_and_chips() -> dict:
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    out = {}
+    for w in bench["workloads"]:
+        with open(os.path.join(ROOT, files[w["config"]])) as f:
+            out[w["name"]] = (json.load(f)["app"], w["chips"])
+    return out
+
+
+#: Every cell of ``BENCHMARK.json``: its app and the chips it asks for.
+APPS_AND_CHIPS = _apps_and_chips()
+CELLS = tuple(APPS_AND_CHIPS)

@@ -1,18 +1,19 @@
 """A run with the timed path broken underneath reports ``correct``
-false: once for each fault a cell can have."""
+false: once for each fault a cell can have. One chip has no exchange
+between chips to leave out."""
 
 import pytest
 
 from benchmark import faults
-from benchmark.tests.helpers import run_cell
+from benchmark.tests.helpers import APPS_AND_CHIPS, run_cell
 
-APP = {"als-rmat18": "als", "gat-rmat18": "gat", "pair-rmat20-4chip": "pair"}
-CASES = [(cell, f) for cell, app in APP.items() for f in faults.APPLIES[app]]
+CASES = [(cell, f) for cell, (app, chips) in APPS_AND_CHIPS.items()
+         for f in faults.APPLIES[app] if f != "no_exchange" or chips > 1]
 
 
 @pytest.mark.parametrize("cell,fault", CASES)
 def test_fault_is_caught(tiny, capsys, cell, fault):
-    with faults.plant(fault, APP[cell]):
+    with faults.plant(fault, APPS_AND_CHIPS[cell][0]):
         res = run_cell(tiny, capsys, cell)
     assert res["correct"] is False, (fault, res["checks"])
     # The comparison said why: some number is over its limit.

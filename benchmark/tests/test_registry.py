@@ -62,3 +62,71 @@ def test_new_parts_are_found_by_name(tiny, capsys, mix):
     res = run_cell(tiny, capsys, "throwaway-cell", trace=1)
     assert res["metrics"]["throwaway_metric"] == {"value": 42.0,
                                                   "unit": "count"}
+
+
+BANDED = '''"""A band of ``width`` diagonals from the main one, wrapping."""
+import numpy as np
+
+
+def make(spec, rng):
+    n, w = spec["n"], spec["width"]
+    rows = np.repeat(np.arange(n), w)
+    cols = (rows + np.tile(np.arange(w), n)) % n
+    return {"rows": rows, "cols": cols,
+            "vals": rng.uniform(0.5, 1.5, rows.size), "M": n, "N": n}
+
+
+def tiny(spec):
+    return dict(spec, n=128, width=4)
+'''
+
+THROWAWAY_APP = '''"""The fused pair under a name of its own, with its own tiny size."""
+from benchmark.apps.pair import Run, work  # noqa: F401
+
+
+def tiny(cfg):
+    return dict(cfg, R=8)
+'''
+
+
+def test_new_generator_and_app_are_found_by_name(tmp_path, capsys):
+    """A configuration whose matrix is not an R-mat and whose app is new
+    comes in as new files: its generator and its app cut it to a tiny
+    size, and a run finds both by name."""
+    from benchmark.tests.conftest import copy_root, cut_configs
+
+    root = copy_root(str(tmp_path))
+    bdir = os.path.join(root, "benchmark")
+    with open(os.path.join(bdir, "matrices", "banded.py"), "w") as f:
+        f.write(BANDED)
+    with open(os.path.join(bdir, "apps", "throwaway_app.py"), "w") as f:
+        f.write(THROWAWAY_APP)
+    with open(os.path.join(bdir, "configs", "pair-rmat18-r128.json")) as f:
+        cfg = json.load(f)
+    cfg.update(name="band-cfg", app="throwaway_app",
+               matrix={"generator": "banded", "n": 1 << 18, "width": 32})
+    with open(os.path.join(bdir, "configs", "band-cfg.json"), "w") as f:
+        json.dump(cfg, f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["configs"].append({"name": "band-cfg", "source": "test",
+                             "file": "benchmark/configs/band-cfg.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "band-cell", "config": "band-cfg",
+                               "traffic": "back_to_back", "chips": 1,
+                               "why": "test"})
+    for m in bench["end_to_end"]:
+        if m["name"] == "pair_gflops":
+            m["workloads"].append("band-cell")
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+    cut_configs(root)
+    with open(os.path.join(bdir, "configs", "band-cfg.json")) as f:
+        cut = json.load(f)
+    assert cut["matrix"] == {"generator": "banded", "n": 128, "width": 4}
+    assert cut["R"] == 8
+    res = run_cell(root, capsys, "band-cell")
+    assert res["correct"], res
+    assert set(res["metrics"]) == {"pair_gflops", "setup_s"}
+    assert res["metrics"]["pair_gflops"]["value"] > 0

@@ -66,3 +66,19 @@ def test_gat_work_by_hand():
     sddmm = (2 * nnz * 256, 12 * nnz + 2 * 4 * M * 256 + 4 * nnz)
     spmm = (2 * nnz * 256, 12 * nnz + 2 * 4 * M * 256)
     assert w["sparse_ops"] == [sddmm, spmm] * 14
+
+
+def test_one_chip_pair_readers_by_hand():
+    """The one-chip pair's rate is the pair's rate; its median unit time
+    leaves a stalled unit out."""
+    def reader(name):
+        return load_module(os.path.join(ROOT, "benchmark", "metrics",
+                                        name + ".py"), name).read
+
+    w = _app("pair").work(_cfg("pair-rmat18-r128"), 8_388_064, 1 << 18,
+                          1 << 18)
+    ctx = {"window": {"ends_s": [0.08, 0.16, 0.36], "window_s": 0.36,
+                      "latency_s": [0.08, 0.08, 0.2]},
+           "chips": 1, "work": w}
+    assert reader("pair_gflops_1chip")(ctx) == reader("pair_gflops")(ctx)
+    assert reader("unit_p50_ms.pair_1chip")(ctx) == pytest.approx(80.0)
