@@ -20,7 +20,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from benchmark import program, sparse_ref
+from benchmark import faults, program, sparse_ref
 from benchmark.apps import als_ref
 
 
@@ -31,19 +31,45 @@ def tiny(cfg: dict) -> dict:
 def work(cfg: dict, nnz: int, M: int, N: int) -> dict:
     """Per alternating step: the model FLOPs and, per sparse op, its FLOPs
     and least bytes (S's rows, cols and values, each dense operand read
-    once, each output written once; 4 bytes an element)."""
+    once, each output written once; 4 bytes an element) and the kernel
+    that runs it."""
     R, it = cfg["R"], cfg["cg_iters"]
-    ops, flops = [], 0
+    ops, kernels, flops = [], [], 0
     for rows, cols in ((M, N), (N, M)):  # the A, then the B half-step
         spmm = (2 * nnz * R, 12 * nnz + 4 * (cols + rows) * R)
         fused = (4 * nnz * R,
                  12 * nnz + 4 * (rows + cols) * R + 4 * rows * R + 4 * nnz)
         ops += [spmm] + [fused] * (it + 1)
+        kernels += ["spmm"] + ["fused"] * (it + 1)
         # A CG iteration's vector algebra: the ridge term, two row dots
         # and three row axpys, 2 FLOPs an element each.
         flops += it * 12 * rows * R
     return {"model_flops": flops + sum(f for f, _ in ops),
-            "sparse_ops": ops}
+            "sparse_ops": ops, "sparse_kernels": kernels}
+
+
+def _unchanged():
+    """Each step leaves the factors as they were."""
+    from distributed_sddmm_tpu.models.als import DistributedALS
+
+    return faults.patched(DistributedALS, "run_cg",
+                          lambda self, *a, **k: None)
+
+
+def _altered():
+    """Each half-step's solve moves one element of ``A``."""
+    from distributed_sddmm_tpu.models.als import DistributedALS
+
+    solve = DistributedALS.cg_optimizer
+
+    def altered(self, mode, *a, **k):
+        solve(self, mode, *a, **k)
+        self.A = faults.alter(self.A)
+    return faults.patched(DistributedALS, "cg_optimizer", altered)
+
+
+#: The faults of ``faults.py`` that this app can have, with its patches.
+FAULTS = {"unchanged": _unchanged, "half": None, "altered": _altered}
 
 
 def _factors(key, R, shape_a, shape_b, sh_a, sh_b):

@@ -17,7 +17,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from benchmark import program, sparse_ref
+from benchmark import faults, program, sparse_ref
 from benchmark.apps import gat_ref
 
 
@@ -27,14 +27,30 @@ def tiny(cfg: dict) -> dict:
 
 def work(cfg: dict, nnz: int, M: int, N: int) -> dict:
     """Per forward: model FLOPs (projections and sparse ops) and each
-    sparse op's FLOPs and least bytes (4 bytes an element)."""
-    ops, flops = [], 0
+    sparse op's FLOPs and least bytes (4 bytes an element) and the
+    kernel that runs it."""
+    ops, kernels, flops = [], [], 0
     for fin, f, heads in gat_ref.layer_dims(cfg):
         flops += heads * 2 * M * fin * f
         sddmm = (2 * nnz * f, 12 * nnz + 4 * (M + N) * f + 4 * nnz)
         spmm = (2 * nnz * f, 12 * nnz + 4 * N * f + 4 * M * f)
         ops += [sddmm, spmm] * heads
-    return {"model_flops": flops + sum(f for f, _ in ops), "sparse_ops": ops}
+        kernels += ["sddmm", "spmm"] * heads
+    return {"model_flops": flops + sum(f for f, _ in ops), "sparse_ops": ops,
+            "sparse_kernels": kernels}
+
+
+def _altered():
+    """Each forward's output has one element moved."""
+    from distributed_sddmm_tpu.models.gat import GAT
+
+    fwd = GAT.forward
+    return faults.patched(GAT, "forward",
+                          lambda self, X=None: faults.alter(fwd(self, X)))
+
+
+#: The faults of ``faults.py`` that this app can have, with its patches.
+FAULTS = {"half": None, "altered": _altered}
 
 
 def _inputs(key, cfg, shape_x, sharding):

@@ -18,7 +18,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from benchmark import program, sparse_ref
+from benchmark import faults, program, sparse_ref
 
 
 def tiny(cfg: dict) -> dict:
@@ -28,7 +28,33 @@ def tiny(cfg: dict) -> dict:
 def work(cfg: dict, nnz: int, M: int, N: int) -> dict:
     R = cfg["R"]
     fused = (4 * nnz * R, 12 * nnz + 4 * (M + N) * R + 4 * M * R + 4 * nnz)
-    return {"model_flops": fused[0], "sparse_ops": [fused]}
+    return {"model_flops": fused[0], "sparse_ops": [fused],
+            "sparse_kernels": ["fused"]}
+
+
+def _unchanged():
+    """Each call returns its inputs: ``A`` as ``out``, ``s`` as ``mid``."""
+    from distributed_sddmm_tpu.parallel.dense_shift_15d import DenseShift15D
+
+    return faults.patched(DenseShift15D, "fused_spmm",
+                          lambda self, A, B, s, mode=None: (A, s))
+
+
+def _altered():
+    """Each call's ``out`` has one element moved."""
+    from distributed_sddmm_tpu.parallel.dense_shift_15d import DenseShift15D
+
+    fused = DenseShift15D.fused_spmm
+
+    def altered(self, *a, **k):
+        out, mid = fused(self, *a, **k)
+        return faults.alter(out), mid
+    return faults.patched(DenseShift15D, "fused_spmm", altered)
+
+
+#: The faults of ``faults.py`` that this app can have, with its patches.
+FAULTS = {"unchanged": _unchanged, "half": None, "no_exchange": None,
+          "altered": _altered}
 
 
 def _dense(key, shape_a, shape_b, sh_a, sh_b):

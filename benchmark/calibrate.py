@@ -8,10 +8,11 @@ the window's own call (the answer of the last one kept), then every
 number the check compares, for the program (against the plain
 reference) and, with ``--control``, for the control: the reference in
 the precision below the configuration's, in the program's place. With
-``--faults``, the faults of ``faults.py`` planted in the reference put
-in the program's place: ``half`` (half of the nonzeros left out) and
-``altered`` (one answer of each output moved by ten times the largest).
-One JSON line per seed. The benchmark's own runs never run these.
+``--faults``, faults planted in the reference put in the program's
+place: ``fault.half`` (half of the nonzeros left out), ``fault.altered``
+(one answer of each output moved by ten times the largest), and a
+``fault.<name>`` for each of the app's ``REFERENCE_FAULTS``. One JSON
+line per seed. The benchmark's own runs never run these.
 """
 
 import argparse
@@ -80,6 +81,9 @@ def main(argv=None, root: str = harness.ROOT) -> int:
             del half
             line["fault.altered"] = harness.compare(
                 {k: tuple(alter(a) for a in v) for k, v in ref.items()}, ref)
+            for name in getattr(cell.app, "REFERENCE_FAULTS", ()):
+                line["fault." + name] = harness.compare(
+                    r.reference(harness.quant("reference"), fault=name), ref)
         line["calibrate_s"] = time.perf_counter() - t0
         print(json.dumps(line), flush=True)
         del r, ref, mat

@@ -1,15 +1,21 @@
 """Faults planted under the timed path, to show that the check catches
 them: the tests plant them at a tiny size on the CPU, ``calibrate.py
---fault`` at a cell's own size on the chip. Each is a context manager
-that patches the program and undoes the patch on exit.
+--faults`` in the reference at a cell's own size on the chip. Each is a
+context manager that patches the program and undoes the patch on exit.
 
-- ``unchanged``: the unit returns its state (ALS) or its inputs (the
-  pair) unchanged;
+- ``unchanged``: the unit returns its state or its inputs unchanged;
 - ``half``: half of the matrix's nonzeros are left out of every sparse
   op (their values read as zero);
 - ``no_exchange``: every collective between chips is left out (the
   program's own ``"local"`` ablation of ``parallel/loops.py``);
 - ``altered``: one answer is altered where it is produced.
+
+Which of them an app can have is the app's to say: its module's
+``FAULTS`` maps each that applies to the app's own patch, a callable
+with no arguments that returns the context manager, or to ``None`` where
+the generic patch here (``half``, ``no_exchange``) serves. Only the app
+knows where its state or its answers are made, so ``unchanged`` and
+``altered`` always come with the app's patch.
 """
 
 from __future__ import annotations
@@ -18,16 +24,10 @@ import contextlib
 
 import jax.numpy as jnp
 
-#: Which faults each app can have.
-APPLIES = {
-    "als": ("unchanged", "half", "altered"),
-    "gat": ("half", "altered"),
-    "pair": ("unchanged", "half", "no_exchange", "altered"),
-}
-
 
 @contextlib.contextmanager
-def _patched(obj, name, value):
+def patched(obj, name, value):
+    """``obj.name`` is ``value`` inside the context, as before outside."""
     old = getattr(obj, name)
     setattr(obj, name, value)
     try:
@@ -51,48 +51,34 @@ def _half(fn):
     return wrapped
 
 
-def plant(name: str, app: str):
-    """The context in which fault ``name`` is planted for ``app``."""
-    from distributed_sddmm_tpu.models.als import DistributedALS
-    from distributed_sddmm_tpu.models.gat import GAT
-    from distributed_sddmm_tpu.parallel import loops
-    from distributed_sddmm_tpu.parallel.dense_shift_15d import DenseShift15D
+@contextlib.contextmanager
+def half():
+    """Every tile set's values with the second half read as zero."""
     from distributed_sddmm_tpu.parallel.sharding import TileSet
 
-    if name not in APPLIES[app]:
-        raise ValueError(f"fault {name!r} does not apply to {app!r}")
-    stack = contextlib.ExitStack()
-    if name == "unchanged" and app == "als":
-        stack.enter_context(_patched(DistributedALS, "run_cg",
-                                     lambda self, *a, **k: None))
-    elif name == "unchanged":
-        stack.enter_context(_patched(
-            DenseShift15D, "fused_spmm",
-            lambda self, A, B, s, mode=None: (A, s)))
-    elif name == "half":
-        stack.enter_context(_patched(TileSet, "like_values",
-                                     _half(TileSet.like_values)))
-        stack.enter_context(_patched(TileSet, "scatter_values",
-                                     _half(TileSet.scatter_values)))
-    elif name == "no_exchange":
-        stack.enter_context(_patched(loops, "_ABLATION", "local"))
-    elif app == "als":
-        solve = DistributedALS.cg_optimizer
+    with patched(TileSet, "like_values", _half(TileSet.like_values)), \
+            patched(TileSet, "scatter_values", _half(TileSet.scatter_values)):
+        yield
 
-        def altered(self, mode, *a, **k):
-            solve(self, mode, *a, **k)
-            self.A = alter(self.A)
-        stack.enter_context(_patched(DistributedALS, "cg_optimizer",
-                                     altered))
-    elif app == "gat":
-        fwd = GAT.forward
-        stack.enter_context(_patched(
-            GAT, "forward", lambda self, X=None: alter(fwd(self, X))))
-    else:
-        fused = DenseShift15D.fused_spmm
 
-        def altered(self, *a, **k):
-            out, mid = fused(self, *a, **k)
-            return alter(out), mid
-        stack.enter_context(_patched(DenseShift15D, "fused_spmm", altered))
-    return stack
+def no_exchange():
+    """The program's loops with every collective left out."""
+    from distributed_sddmm_tpu.parallel import loops
+
+    return patched(loops, "_ABLATION", "local")
+
+
+#: The faults whose patch is the same for every app.
+GENERIC = {"half": half, "no_exchange": no_exchange}
+
+
+def plant(name: str, app):
+    """The context in which fault ``name`` is planted for the app module
+    ``app``, by the patch its ``FAULTS`` gives or the generic one."""
+    if name not in app.FAULTS:
+        raise ValueError(f"fault {name!r} does not apply to {app.__name__!r}")
+    patch = app.FAULTS[name] or GENERIC.get(name)
+    if patch is None:
+        raise ValueError(f"fault {name!r} has no generic patch: "
+                         f"{app.__name__!r} has to give its own")
+    return patch()

@@ -20,12 +20,25 @@ a traffic mix or a per-layer metric as new files:
   the harness's own;
 - ``peaks.json``: the chip's peaks, keyed by JAX's ``device_kind``.
 
-An app module defines ``work(cfg, nnz, M, N)`` and ``Run(cfg, mat, key,
-devices, check)``, whose ``unit()`` runs one unit of work and
-returns when the device has finished it, ``keep()`` keeps the last
-unit's answer for the check, ``release()`` drops the program,
-``outputs()`` gives the kept answers and ``reference(quant)`` the plain
-reference's, as ``{name: (array, ...)}``.
+An app module defines:
+
+- ``work(cfg, nnz, M, N)``: one unit's ``model_flops``, its
+  ``sparse_ops`` as ``(FLOPs, least bytes)`` pairs, and beside them
+  ``sparse_kernels``, the name prefix of the Pallas kernel that runs each
+  (``fused``, ``sddmm``, ``spmm``: ``ops/pallas_kernels.py`` names a
+  launch ``<prefix>_tiles.<n>``);
+- ``Run(cfg, mat, key, devices, check)``, whose ``unit()`` runs one unit
+  of work and returns when the device has finished it, ``keep()`` keeps
+  the last unit's answer for the check, ``release()`` drops the program,
+  ``outputs()`` gives the kept answers and ``reference(quant, keep=None)``
+  the plain reference's, as ``{name: (array, ...)}``; ``keep`` (0/1 a
+  nonzero) leaves nonzeros out;
+- ``FAULTS``: the faults of ``faults.py`` that the app can have, each
+  with the app's own patch or ``None`` for the generic one;
+- optionally ``REFERENCE_FAULTS``: faults of the app's own mathematics
+  (a term left out, an operand swapped) that ``reference(quant,
+  fault=<name>)`` plants in the plain reference, for ``calibrate.py
+  --faults``.
 
 Both an app module and a generator module also define ``tiny``: the
 configuration (``tiny(cfg)``) or its ``matrix`` (``tiny(spec)``) with the
@@ -94,9 +107,7 @@ class Cell:
         with open(os.path.join(bdir, "traffic",
                                self.cell["traffic"] + ".json")) as f:
             self.traffic = json.load(f)
-        self.app = load_module(os.path.join(bdir, "apps",
-                                            self.cfg["app"] + ".py"),
-                               f"benchmark.apps.{self.cfg['app']}")
+        self.app = app_module(self.cfg["app"], root)
         self.chips = int(self.cell["chips"])
         self.end_to_end = [m for m in self.bench["end_to_end"]
                            if name in m.get("workloads", [name])]
@@ -181,6 +192,12 @@ def enable_compile_cache(work: str) -> str:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
+
+
+def app_module(name: str, root: str = ROOT):
+    """``benchmark/apps/<name>.py`` under ``root``."""
+    return load_module(os.path.join(root, "benchmark", "apps", name + ".py"),
+                       "benchmark.apps." + name)
 
 
 def generator_module(name: str, root: str = ROOT):

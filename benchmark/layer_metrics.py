@@ -4,8 +4,9 @@
 - ``trace``: ``trace.Trace``; ``lo``, ``hi``: the traced window (ns);
 - ``devices``: the traced devices the cell uses;
 - ``units``: units of work completed in the window; ``chips``;
-- ``work``: the app's work function for one unit (``model_flops`` and
-  ``sparse_ops``, a list of ``(flops, least bytes)``);
+- ``work``: the app's work function for one unit (``model_flops``,
+  ``sparse_ops``, a list of ``(flops, least bytes)``, and
+  ``sparse_kernels``, the kernel that runs each);
 - ``peak``: the device's row of ``peaks.json``.
 
 A reader that finds nothing to read returns ``None``.
@@ -13,6 +14,7 @@ A reader that finds nothing to read returns ``None``.
 
 from __future__ import annotations
 
+from benchmark import span_metrics
 from benchmark import trace as tr
 
 
@@ -39,13 +41,18 @@ def idle_share(ctx):
     return 100.0 * (1.0 - busy_s(ctx) * 1e9 / window_ns(ctx))
 
 
-def roofline_s(ctx) -> float:
+def roofline_s(ctx, kind: str | None = None) -> float:
     """Least time of the window's sparse work on ``chips`` chips: per op,
-    the larger of FLOPs over peak and least bytes over bandwidth."""
+    the larger of FLOPs over peak and least bytes over bandwidth. With
+    ``kind``, only the ops that kernel runs (``sparse_kernels``)."""
     peak, chips = ctx["peak"], ctx["chips"]
+    ops = ctx["work"]["sparse_ops"]
+    if kind is not None:
+        ops = [op for op, k in zip(ops, ctx["work"].get("sparse_kernels", ()))
+               if k == kind]
     one = sum(max(f / (peak["flops_bf16"] * chips),
                   b / (peak["hbm_bytes_per_s"] * chips))
-              for f, b in ctx["work"]["sparse_ops"])
+              for f, b in ops)
     return one * ctx["units"]
 
 
@@ -58,6 +65,18 @@ def sparse_roofline(ctx):
     if not kernel:
         return None
     return 100.0 * roofline_s(ctx) / (kernel / 1e9)
+
+
+def kernel_roofline(ctx, kind: str):
+    """% of its roofline of the kernel ``kind`` (``fused``, ``sddmm``,
+    ``spmm``): the least time of the window's ops that it runs over the
+    device time of the operations named ``kind*`` (their union in the
+    window, mean over the devices, as ``span_metrics.kernel_s``)."""
+    least = roofline_s(ctx, kind)
+    kernel = span_metrics.kernel_s(ctx, kind)
+    if not least or not kernel:
+        return None
+    return 100.0 * least / (kernel * ctx["units"])
 
 
 def mfu(ctx):

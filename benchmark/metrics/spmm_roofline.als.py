@@ -1,0 +1,7 @@
+"""spmm_roofline.als: % of its roofline of the SpMM kernel: the least time of the window's ops tagged `spmm` in the app's `work()` (FLOPs over bf16 peak or least bytes over HBM bandwidth, the larger) over the device time of the operations named `spmm*` (`spmm_tiles.<n>`), union in the window, mean over devices."""
+
+from benchmark.layer_metrics import kernel_roofline
+
+
+def read(ctx):
+    return kernel_roofline(ctx, "spmm")

@@ -48,9 +48,7 @@ def cut_configs(root: str) -> None:
         with open(path) as f:
             cfg = json.load(f)
         gen = harness.generator_module(cfg["matrix"]["generator"], root)
-        app = harness.load_module(
-            os.path.join(root, "benchmark", "apps", cfg["app"] + ".py"),
-            "benchmark.apps." + cfg["app"])
+        app = harness.app_module(cfg["app"], root)
         cfg = app.tiny(dict(cfg, matrix=gen.tiny(cfg["matrix"])))
         with open(path, "w") as f:
             json.dump(cfg, f)

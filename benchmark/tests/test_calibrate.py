@@ -1,27 +1,15 @@
 """``calibrate.py`` at a tiny size: the program reads at float32 rounding
-against the reference, the control and the planted faults read far
+against the reference, the control and every planted fault (the generic
+``half`` and ``altered``, and the app's ``REFERENCE_FAULTS``) read far
 above it."""
-
-import json
 
 import pytest
 
-from benchmark import calibrate
-from benchmark.tests.helpers import CELLS
+from benchmark.tests.helpers import CELLS, calibrate_readings
 
 UNITS = {"als-rmat18": 0}
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_calibrate_readings(tiny, capsys, cell):
-    capsys.readouterr()
-    assert calibrate.main(["--workload", cell, "--seeds", "3,4", "--units",
-                           str(UNITS.get(cell, 1)), "--control", "--faults",
-                           "--cpu"], root=tiny) == 0
-    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
-    assert [x["seed"] for x in lines] == [3, 4]
-    for x in lines:
-        prog = max(x["program"].values())
-        assert prog < 1e-5
-        for kind in ("control", "fault.half", "fault.altered"):
-            assert max(x[kind].values()) > 100 * prog, (kind, x)
+    calibrate_readings(tiny, capsys, cell, UNITS.get(cell, 1))

@@ -1,21 +1,30 @@
 """A run with the timed path broken underneath reports ``correct``
-false: once for each fault a cell can have. One chip has no exchange
-between chips to leave out."""
+false: once for each fault that the ``FAULTS`` of a cell's app declares.
+One chip has no exchange between chips to leave out."""
+
+import types
 
 import pytest
 
 from benchmark import faults
-from benchmark.tests.helpers import APPS_AND_CHIPS, run_cell
+from benchmark.tests.helpers import assert_fault_caught, fault_cases
 
-CASES = [(cell, f) for cell, (app, chips) in APPS_AND_CHIPS.items()
-         for f in faults.APPLIES[app] if f != "no_exchange" or chips > 1]
+CASES = fault_cases()
 
 
 @pytest.mark.parametrize("cell,fault", CASES)
 def test_fault_is_caught(tiny, capsys, cell, fault):
-    with faults.plant(fault, APPS_AND_CHIPS[cell][0]):
-        res = run_cell(tiny, capsys, cell)
-    assert res["correct"] is False, (fault, res["checks"])
-    # The comparison said why: some number is over its limit.
-    assert any(c["value"] is None or c["value"] > c["limit"]
-               for c in res["checks"].values())
+    assert_fault_caught(tiny, capsys, cell, fault)
+
+
+def test_faults_have_no_table_of_apps():
+    """``faults.py`` keeps only the generic patches: the rest come from
+    the app, and a fault an app does not declare is refused."""
+    assert set(faults.GENERIC) == {"half", "no_exchange"}
+
+    app = types.SimpleNamespace(__name__="some_app",
+                                FAULTS={"half": None, "unchanged": None})
+    with pytest.raises(ValueError, match="does not apply to 'some_app'"):
+        faults.plant("altered", app)
+    with pytest.raises(ValueError, match="has to give its own"):
+        faults.plant("unchanged", app)
