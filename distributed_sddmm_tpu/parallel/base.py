@@ -760,8 +760,8 @@ class DistributedSparse(abc.ABC):
                 with obs_trace.span(name + ":dispatch"):
                     out = fn(*args)
                 # Execution barrier over every output leaf
-                # (utils.platform.force_fetch); one scalar per leaf is
-                # negligible next to any timed op.
+                # (utils.platform.force_fetch): one wait for the whole
+                # output, with no device program and no host copy.
                 with obs_trace.span(name + ":fetch"):
                     force_fetch(out)
                 kernel_s = time.perf_counter() - t0

@@ -58,30 +58,25 @@ def force_cpu_platform(n_devices: int | None = None, replace: bool = False) -> N
         )
 
 
-def force_fetch(tree) -> float:
-    """Execution barrier over a pytree: copies one scalar of every numeric
-    leaf to the host and returns their sum.
+def force_fetch(tree) -> None:
+    """Execution barrier over a pytree: returns once the computation of
+    every array leaf has finished, and raises a device error here.
 
-    A host copy cannot finish before the computation that produced its
-    leaf has, so a timed region that ends with ``force_fetch(out)`` times
-    the device work for every output, however many leaves ``out`` has.
-    Under a trace there is nothing to fetch and tracers are skipped.
+    One wait over all the leaves' buffers (``jax.block_until_ready``):
+    it runs no device program and copies nothing to the host, so a timed
+    region that ends with ``force_fetch(out)`` times the device work for
+    every output at the cost of one wait, however many leaves ``out``
+    has. Tracers (under a trace there is nothing to wait for), empty
+    arrays and non-array leaves are skipped.
     """
     import jax
-    import jax.numpy as jnp
 
-    total = 0.0
-    for leaf in jax.tree.leaves(tree):
-        if (
-            hasattr(leaf, "dtype")
-            and jnp.issubdtype(leaf.dtype, jnp.number)
-            and getattr(leaf, "size", 0)
-            # Under a trace (e.g. differentiating through a public op)
-            # there is nothing to fetch.
-            and not isinstance(leaf, jax.core.Tracer)
-        ):
-            total += float(jnp.asarray(leaf).reshape(-1)[0])
-    return total
+    jax.block_until_ready([
+        leaf for leaf in jax.tree.leaves(tree)
+        if isinstance(leaf, jax.Array)
+        and not isinstance(leaf, jax.core.Tracer)
+        and leaf.size
+    ])
 
 
 def _checkout_root() -> str:

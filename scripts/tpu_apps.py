@@ -7,7 +7,7 @@ appends records to APPS_TPU.jsonl:
 * ALS-CG and GAT apps (`benchmark_dist.cpp:88-100`),
 * the R-sweep heatmap (`bench_heatmap.cpp:33-35`) for both kernels.
 
-Timed loops end in host fetches (utils.platform.force_fetch). Each
+Timed loops end in an execution barrier (utils.platform.force_fetch). Each
 invocation skips configs already recorded, so an interrupted run resumes
 where it stopped.
 """

@@ -81,3 +81,66 @@ def test_als_requires_ground_truth_vals():
     als.initialize_embeddings()
     with pytest.raises(ValueError, match="transposed ground-truth"):
         als.cg_optimizer(MatMode.B, 1)
+
+
+def _executions(fn, logdir) -> int:
+    """Device programs that ``fn()`` runs on the CPU backend: the
+    runtime's ``PjRtCpuExecutable::Execute`` events in a profiler
+    capture (host tracer at its most detailed, Python tracer off)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 3
+    jax.profiler.start_trace(str(logdir), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = logdir.glob("**/*.xplane.pb")
+    return sum(
+        ev.name == "PjRtCpuExecutable::Execute"
+        for plane in ProfileData.from_file(str(path)).planes
+        for line in plane.lines
+        for ev in line.events
+    )
+
+
+def test_als_step_runs_only_its_own_programs(tmp_path, monkeypatch):
+    """One alternating step at the benchmark ALS app's tiny size (R-mat
+    logM 8, 8 a row, R 16, 3 CG iterations, one device) runs the same
+    device programs whether the barrier after each dispatch waits or
+    not: the barrier adds no program of its own, however many output
+    leaves it waits on. It is still one barrier a dispatch: an SpMM, the
+    initial fused pair and one ``cgStep`` an iteration, each half-step."""
+    import jax
+
+    from distributed_sddmm_tpu.bench.harness import make_algorithm
+    from distributed_sddmm_tpu.ops.kernels import get_kernel
+    from distributed_sddmm_tpu.utils import platform
+
+    cg_iters = 3
+    S = HostCOO.rmat(log_m=8, edge_factor=8, seed=0)
+    alg = make_algorithm("15d_fusion2", S, 16, 1, kernel=get_kernel("auto"),
+                         devices=jax.devices()[:1])
+    als = DistributedALS(alg, seed=0)
+    als.initialize_embeddings()
+
+    def step():
+        als.run_cg(1, cg_iters=cg_iters)
+        jax.block_until_ready((als.A, als.B))
+
+    step()  # compiles every program the step runs
+    barrier, waits = platform.force_fetch, []
+
+    def counted(out):
+        waits.append(out)
+        barrier(out)
+
+    monkeypatch.setattr(platform, "force_fetch", counted)
+    with_barrier = _executions(step, tmp_path / "barrier")
+    monkeypatch.setattr(platform, "force_fetch", lambda out: None)
+    without = _executions(step, tmp_path / "none")
+    assert len(waits) == 2 * (2 + cg_iters)
+    assert with_barrier == without > 0

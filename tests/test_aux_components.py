@@ -204,24 +204,66 @@ class TestCompileCache:
         assert jax.config.values["jax_compilation_cache_dir"] == before
 
 
+def _barrier_tree(kind: str):
+    """An output pytree of a shape the timed paths wait on: a tuple of
+    leaves, a dict beside a non-array leaf, or a leaf sharded over every
+    device. Freshly dispatched, so the barrier has work to wait for."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    square = jax.jit(lambda x: (x @ x, x.sum(0).astype(jnp.int32)))
+    x = jnp.full((256, 256), 0.5)
+    if kind == "tuple":
+        return square(x)
+    if kind == "dict":
+        return {"out": square(x)[0], "mid": x * 2.0, "label": "pair"}
+    mesh = Mesh(jax.devices(), ("d",))
+    y = jax.device_put(jnp.arange(8 * len(jax.devices()), dtype=jnp.float32),
+                       NamedSharding(mesh, PartitionSpec("d")))
+    return (jax.jit(lambda y: y * 3.0)(y),)
+
+
 class TestForceFetch:
     """utils.platform.force_fetch — the execution barrier every timed
-    region relies on."""
+    region relies on: one wait over every array leaf."""
 
-    def test_scalar_per_leaf(self):
-        import jax.numpy as jnp
+    @pytest.mark.parametrize("tree", ["tuple", "dict", "sharded"])
+    def test_every_leaf_ready(self, tree):
+        import jax
 
         from distributed_sddmm_tpu.utils.platform import force_fetch
 
-        out = force_fetch((jnp.full((3, 2), 2.0), jnp.ones((4,), jnp.int32)))
-        assert out == 3.0  # first element of each leaf
+        out = _barrier_tree(tree)
+        assert force_fetch(out) is None
+        leaves = [x for x in jax.tree.leaves(out) if isinstance(x, jax.Array)]
+        assert leaves and all(x.is_ready() for x in leaves)
+
+    @pytest.mark.parametrize("guard", ["transfer_guard_device_to_host",
+                                       "transfer_guard"])
+    @pytest.mark.parametrize("tree", ["tuple", "dict", "sharded"])
+    def test_no_transfer(self, tree, guard):
+        """No copy to the host and no transfer at all: the barrier only
+        waits. (On the CPU a device-to-host copy aliases host memory and
+        escapes its own guard; a per-leaf fetch still trips the guard on
+        every direction, for the index it sends to the device.)"""
+        import jax
+
+        from distributed_sddmm_tpu.utils.platform import force_fetch
+
+        out = _barrier_tree(tree)
+        with getattr(jax, guard)("disallow"):
+            force_fetch(out)
 
     def test_empty_and_non_array_leaves(self):
+        import jax
         import jax.numpy as jnp
 
         from distributed_sddmm_tpu.utils.platform import force_fetch
 
-        assert force_fetch((jnp.zeros((0, 5)), "label", None, 7)) == 0.0
+        tree = (jnp.zeros((0, 5)), "label", None, 7)
+        with jax.transfer_guard("disallow"):
+            assert force_fetch(tree) is None
 
     def test_tracer_safe(self):
         import jax
