@@ -2,9 +2,9 @@
 
 `run.py` censuses the XLA-kernel shard_map programs AOT-compiled for a real
 v5e 2x4 topology; this companion does the same for the production kernel
-path — the blocked chunk-list Pallas programs each strategy builds when its
-kernel `is_blocked` (including their `check_vma=False` shard_map wrapping,
-`dense_shift_15d.py`). The round-3 verdict flagged that the collective-
+path — each strategy's programs over the blocked form of the tile seam
+(`ops/pallas_kernels.BlockedTiles`, with its `check_vma=False` shard_map
+wrapping), which they take when the kernel `is_blocked`. The round-3 verdict flagged that the collective-
 parity and async-permute claims only covered the flat XLA programs; this
 closes that gap: same collectives table, now for the code path that would
 actually run on a pod.
@@ -55,11 +55,11 @@ PLANS = {
     "15d_fusion2": (
         "fused", lambda alg, A, B, v: (A, B, *alg._tile_args(alg.S_tiles, v))),
     "15d_sparse": (
-        "spmm", lambda alg, A, B, v: (B, *alg._spmm_args(alg.S_tiles, v))),
+        "spmm", lambda alg, A, B, v: (B, *alg._tile_args(alg.S_tiles, v))),
     "25d_dense_replicate": (
-        "sddmm", lambda alg, A, B, v: (B, A, *alg._sddmm_args(alg.S_tiles, v))),
+        "sddmm", lambda alg, A, B, v: (B, A, *alg._tile_args(alg.S_tiles, v, mask=True))),
     "25d_sparse_replicate": (
-        "spmm", lambda alg, A, B, v: (A, B, *alg._spmm_args(alg.S_tiles, v))),
+        "spmm", lambda alg, A, B, v: (A, B, *alg._tile_args(alg.S_tiles, v))),
 }
 
 

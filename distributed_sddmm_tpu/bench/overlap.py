@@ -280,7 +280,7 @@ def fusion_overlap_hlo_report(
     vals = alg.like_s_values(1.0)
     if algorithm == "15d_sparse":
         op, args = "spmm", (alg.dummy_initialize(MatMode.B),
-                            *alg._spmm_args(alg.S_tiles, vals))
+                            *alg._tile_args(alg.S_tiles, vals))
     else:
         op = "fused" if alg.fusion_approach == 2 else "fused_twopass"
         args = (alg.dummy_initialize(MatMode.A),

@@ -12,10 +12,11 @@ half of the codegen story — structure as *data* bound at runtime, not
   ``utils.buckets.dyn_capacity`` scope: every structure-sizing decision
   (flat max_nnz, chunk counts, per-band chunk ranges) pads up to a
   pow2 capacity rung, and the declared row count reserves a growth
-  rung. Structure arrays are already program *inputs* (``_sddmm_args``
-  passes rows/cols/mask and the ``blk_*`` chunk lists per call), so any
-  pattern landing in the same rungs presents byte-identical avals and
-  static metadata to jax — zero retraces by construction.
+  rung. Structure arrays are already program *inputs* (``_tile_args``
+  passes the tile seam's rows/cols or ``blk_*`` chunk lists, and the
+  mask, per call), so any pattern landing in the same rungs presents
+  byte-identical avals and static metadata to jax — zero retraces by
+  construction.
 * :func:`rebind` re-derives chunk lists and band assignments for a
   mutated pattern on the host and binds them into the *existing*
   strategy (and hence its existing compiled programs) when they fit the

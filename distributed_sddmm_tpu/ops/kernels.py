@@ -13,6 +13,11 @@ and any implementation can be swapped in. Implementations:
 * ``PallasKernel`` (``ops/pallas_kernels.py``) — blocked kernels for peak TPU
   throughput on row-sorted tiles.
 
+The ring programs call either through one tile seam: :class:`FlatTiles`
+here (``(rows, cols)`` tiles, the flat protocol) or ``BlockedTiles``
+beside ``PallasKernel`` (chunk-list tiles, the tile-level kernels). The
+strategy owns the ring; the seam's form owns the local tile work.
+
 Tile convention: a tile is a struct-of-arrays ``(rows, cols, vals)`` of static
 length ``max_nnz``, padded with inert entries ``row = col = 0, val = 0``.
 Zero-valued padding is harmless in both ops: SDDMM multiplies dots by the
@@ -236,6 +241,98 @@ class XlaKernel:
         device max/denominator merge)."""
         m, d = self.attn_stats(rows, gate, logits, out_rows)
         return self.attn_normalize(rows, gate, logits, m, d)
+
+
+class FlatTiles:
+    """The flat form of the tile seam the ring programs call: a tile is
+    its ``(rows, cols)`` index vectors and the local ops are the
+    kernel's flat protocol. Its blocked twin is
+    :class:`~distributed_sddmm_tpu.ops.pallas_kernels.BlockedTiles`;
+    ``DistributedSparse._tile_ops`` picks one per tile set.
+
+    The ops read the tile in the orientation of the accumulating output
+    (``out_rows`` of it): ``x_r`` is the dense operand its rows gather
+    from, ``x_c`` the one its columns gather from. ``swap`` puts the
+    output on the tile's columns (the 2.5D dense-replicating ring)."""
+
+    # The flat ops carry their varying-mesh-axes types, so shard_map
+    # checks them.
+    check_vma = True
+
+    def __init__(self, kernel, tiles, swap: bool = False):
+        self.kernel = kernel
+        self.tiles = tiles
+        self.swap = swap
+        self.out_rows = tiles.tile_cols if swap else tiles.tile_rows
+
+    def fields(self) -> tuple:
+        """The tile set's structure arrays, in program-argument order."""
+        return (self.tiles.rows, self.tiles.cols)
+
+    @staticmethod
+    def specs(tile_spec) -> tuple:
+        return (tile_spec, tile_spec)
+
+    @staticmethod
+    def squeeze(fields, lead=()) -> tuple:
+        """Per-device field blocks -> ``lead + [max_nnz]``."""
+        return tuple(f.reshape(*lead, f.shape[-1]) for f in fields)
+
+    @staticmethod
+    def softmax_fields(fields) -> tuple:
+        """The fields :meth:`softmax` reads: the row indices."""
+        return tuple(fields[:1])
+
+    @staticmethod
+    def tile(rows, cols):
+        return (rows, cols)
+
+    @staticmethod
+    def rows(X):
+        return X
+
+    @staticmethod
+    def cols(X):
+        return X
+
+    def sddmm(self, tile, vals, x_r, x_c):
+        rows, cols = tile
+        if self.swap:
+            return self.kernel.sddmm(rows, cols, vals, x_c, x_r)
+        return self.kernel.sddmm(rows, cols, vals, x_r, x_c)
+
+    def spmm(self, tile, vals, x_c):
+        """SpMM partial ``[out_rows, R]``."""
+        rows, cols = tile
+        if self.swap:
+            rows, cols = cols, rows
+        return self.kernel.spmm(rows, cols, vals, x_c, self.out_rows)
+
+    def fused(self, tile, vals, x_r, x_c, acc, put):
+        """SDDMM then SpMM on one tile: ``(acc + partial, put(mid))``."""
+        mid = self.sddmm(tile, vals, x_r, x_c)
+        stored = put(mid)
+        return acc + self.spmm(tile, mid, x_c), stored
+
+    def zeros(self, like):
+        return jnp.zeros((self.out_rows, like.shape[-1]), like.dtype)
+
+    @staticmethod
+    def finish(acc, like):
+        return acc
+
+    def softmax(self, fields, gate, logits, merge):
+        """Row-wise masked softmax over ``[T, max_nnz]`` tile-layout
+        values: segment stats over all tiles, ``merge(m, d)`` across
+        devices, normalize."""
+        shape = gate.shape
+        rows_f = fields[0].reshape(-1)
+        gate_f = gate.reshape(-1)
+        z_f = logits.reshape(-1)
+        m, d = self.kernel.attn_stats(rows_f, gate_f, z_f, self.out_rows)
+        m, d = merge(m, d)
+        return self.kernel.attn_normalize(
+            rows_f, gate_f, z_f, m, d).reshape(shape)
 
 
 def attn_merge_stats(stats):

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from distributed_sddmm_tpu import compat
 
@@ -49,7 +50,9 @@ from distributed_sddmm_tpu.ops import blocked
 from distributed_sddmm_tpu.ops.blocked import (
     CHUNK, _GC_SHIFT, _GR_SHIFT, MAX_BLOCKS, unpack_meta,
 )
-from distributed_sddmm_tpu.ops.kernels import ATTN_NEG, XlaKernel
+from distributed_sddmm_tpu.ops.kernels import (
+    ATTN_NEG, XlaKernel, attn_merge_stats,
+)
 
 
 @jax.tree_util.register_dataclass
@@ -834,8 +837,9 @@ class PallasKernel:
     """TPU-native local kernel (one-hot MXU formulation).
 
     Its entry points are the blocked tile-level ones (``sddmm_tile`` /
-    ``spmm_tile`` / ``fused_tile``) that the distributed algorithms call
-    on tile sets carrying blocked metadata. The flat
+    ``spmm_tile`` / ``fused_tile`` and their feature-major ``*_t``
+    forms); the distributed algorithms call the ``*_t`` forms through
+    :class:`BlockedTiles` on tile sets carrying blocked metadata. The flat
     :class:`~distributed_sddmm_tpu.ops.kernels.LocalKernel` protocol runs
     XLA formulas, and only in interpreter mode (CPU test meshes): on the
     TPU a tile set without blocked metadata raises instead of running XLA
@@ -1029,3 +1033,110 @@ class PallasKernel:
 
     def attn_softmax(self, rows, gate, logits, out_rows: int):
         return self._flat().attn_softmax(rows, gate, logits, out_rows)
+
+
+class BlockedTiles:
+    """The blocked form of the tile seam the ring programs call: a tile
+    is its chunk list ``(lr, lc, meta)`` and the local ops are the
+    feature-major Pallas tile kernels. Same operations and meanings as
+    :class:`~distributed_sddmm_tpu.ops.kernels.FlatTiles`; what differs
+    is the data: dense operands enter through :meth:`rows` / :meth:`cols`
+    (padded feature-major ``[R, pad]`` in the MXU dtype), and an SpMM
+    accumulator stays feature-major ``[R, rows_pad]`` f32 until
+    :meth:`finish`. A swapped encoding (``block_swap``) is already in
+    the output's orientation, so ``swap`` only picks ``out_rows``."""
+
+    # pallas_call out_shapes carry no varying-mesh-axes annotation, which
+    # the strict checker rejects inside shard_map.
+    check_vma = False
+
+    def __init__(self, kernel, tiles, swap: bool = False):
+        self.kernel = kernel
+        self.tiles = tiles
+        self.out_rows = tiles.tile_cols if swap else tiles.tile_rows
+        bm, bn, grb, gcb, grp = tiles.blk_geom
+        self.rows_pad, self.cols_pad = grb * bm, gcb * bn
+        bands = getattr(tiles, "blk_bands", None)
+        if bands:
+            # The codegen banked encoding: one launch per row band.
+            from distributed_sddmm_tpu.codegen.kernel import BankedTile
+
+            self._make = functools.partial(
+                BankedTile, bands=bands,
+                rows_pad=self.rows_pad, cols_pad=self.cols_pad,
+            )
+        else:
+            self._make = functools.partial(
+                BlockedTile, bm=bm, bn=bn, gr_blocks=grb, gc_blocks=gcb,
+                group=grp,
+            )
+
+    def fields(self) -> tuple:
+        """The tile set's chunk arrays, in program-argument order."""
+        return (self.tiles.blk_lr, self.tiles.blk_lc, self.tiles.blk_meta)
+
+    @staticmethod
+    def specs(tile_spec) -> tuple:
+        chunk_spec = P(*tile_spec, None)
+        return (chunk_spec, chunk_spec, tile_spec)
+
+    @staticmethod
+    def squeeze(fields, lead=()) -> tuple:
+        """Per-device chunk blocks -> ``lead + [C, CHUNK]`` (meta
+        ``lead + [C]``)."""
+        lr, lc, meta = fields
+        C = lr.shape[-2]
+        return (
+            lr.reshape(*lead, C, CHUNK),
+            lc.reshape(*lead, C, CHUNK),
+            meta.reshape(*lead, C),
+        )
+
+    @staticmethod
+    def softmax_fields(fields) -> tuple:
+        return tuple(fields)
+
+    def tile(self, lr, lc, meta):
+        return self._make(lr, lc, meta)
+
+    def rows(self, X):
+        return self.kernel.prep(X, self.rows_pad)
+
+    def cols(self, X):
+        return self.kernel.prep(X, self.cols_pad)
+
+    def sddmm(self, tile, vals, x_r, x_c):
+        return self.kernel.sddmm_tile_t(tile, vals, x_r, x_c, vals.dtype)
+
+    def spmm(self, tile, vals, x_c):
+        """SpMM partial, feature-major ``[R, rows_pad]`` f32."""
+        return self.kernel.spmm_tile_t(tile, vals, x_c)
+
+    def fused(self, tile, vals, x_r, x_c, acc, put):
+        partial, mid = self.kernel.fused_tile_t(
+            tile, vals, x_r, x_c, vals.dtype)
+        return acc + partial, put(mid)
+
+    def zeros(self, like):
+        return jnp.zeros((like.shape[-1], self.rows_pad), jnp.float32)
+
+    def finish(self, acc, like):
+        return acc.T[:self.out_rows].astype(like.dtype)
+
+    def softmax(self, fields, gate, logits, merge):
+        """Per-tile Pallas reduce launches, a tile merge, ``merge(m, d)``
+        across devices, then per-tile normalize launches (one launch
+        pair per tile: the tile loop is static)."""
+        T = gate.shape[0]
+        tiles = [self.tile(*(f[s] for f in fields)) for s in range(T)]
+        stats = [
+            self.kernel.attn_stats_tile_t(tiles[s], gate[s], logits[s])
+            for s in range(T)
+        ]
+        m, d = merge(*attn_merge_stats(stats))
+        probs = [
+            self.kernel.attn_norm_tile_t(
+                tiles[s], gate[s], logits[s], m, d, gate.dtype)
+            for s in range(T)
+        ]
+        return jnp.stack(probs)

@@ -32,11 +32,12 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_sddmm_tpu.common import KernelMode, MatMode
+from distributed_sddmm_tpu.compat import shard_map
 from distributed_sddmm_tpu.obs import log as obs_log
 from distributed_sddmm_tpu.obs import metrics as obs_metrics
 from distributed_sddmm_tpu.obs import trace as obs_trace
 from distributed_sddmm_tpu.obs import watchdog as obs_watchdog
-from distributed_sddmm_tpu.ops.kernels import LocalKernel, XlaKernel
+from distributed_sddmm_tpu.ops.kernels import FlatTiles, LocalKernel, XlaKernel
 from distributed_sddmm_tpu.parallel.mesh import GridSpec
 from distributed_sddmm_tpu.parallel.sharding import TileSet
 
@@ -462,7 +463,8 @@ class DistributedSparse(abc.ABC):
         self.R = R
 
     # ------------------------------------------------------------------ #
-    # Blocked (Pallas) kernel dispatch, shared by every strategy
+    # The tile seam: every strategy's ring program calls the local tile
+    # work through one of its two forms, chosen here per tile set.
     # ------------------------------------------------------------------ #
 
     def _use_blocked(self, tiles) -> bool:
@@ -470,40 +472,34 @@ class DistributedSparse(abc.ABC):
         set carries it (``ops/blocked.py``)."""
         return getattr(self.kernel, "is_blocked", False) and tiles.has_blocked
 
-    def _blk_tile_factory(self, tiles):
-        """Constructor for the kernel's per-tile chunk-list view:
-        ``f(lr [C, CHUNK], lc [C, CHUNK], meta [C]) -> tile view``.
+    def _tile_ops(self, tiles, swap: bool = False):
+        """The tile seam for one tile set: ``BlockedTiles`` (chunk lists,
+        Pallas tile kernels) when :meth:`_use_blocked`, else
+        ``FlatTiles`` (``(rows, cols)``, the kernel's flat protocol).
+        ``swap``: the ring's output rides the tile's column frame."""
+        if self._use_blocked(tiles):
+            from distributed_sddmm_tpu.ops.pallas_kernels import BlockedTiles
 
-        Returns a :class:`~distributed_sddmm_tpu.codegen.kernel.
-        BankedTile` builder when the tile set carries the banked
-        encoding (``blk_bands``), else the generic ``BlockedTile``
-        builder — the one place every strategy's blocked program binds
-        the kernel to the tile geometry."""
-        bands = getattr(tiles, "blk_bands", None)
-        if bands:
-            from distributed_sddmm_tpu.codegen.kernel import BankedTile
+            return BlockedTiles(self.kernel, tiles, swap)
+        return FlatTiles(self.kernel, tiles, swap)
 
-            bm, bn, grb, gcb, _ = tiles.blk_geom
-            rows_pad, cols_pad = grb * bm, gcb * bn
+    def _tile_args(self, tiles, vals, mask: bool = False) -> tuple:
+        """The tile operands following a program's dense arguments: the
+        seam's structure fields, ``tiles.mask`` when the program takes
+        it (the SDDMMs of the tile-rotating and 2.5D rings), and
+        ``vals``."""
+        fields = self._tile_ops(tiles).fields()
+        return (*fields, tiles.mask, vals) if mask else (*fields, vals)
 
-            def make(lr, lc, meta):
-                return BankedTile(
-                    lr, lc, meta, bands=bands,
-                    rows_pad=rows_pad, cols_pad=cols_pad,
-                )
-
-            return make
-        from distributed_sddmm_tpu.ops.pallas_kernels import BlockedTile
-
-        bm, bn, grb, gcb, grp = tiles.blk_geom
-
-        def make(lr, lc, meta):
-            return BlockedTile(
-                lr, lc, meta, bm=bm, bn=bn, gr_blocks=grb,
-                gc_blocks=gcb, group=grp,
-            )
-
-        return make
+    def _shard_program(self, key, form, prog, in_specs, out_specs):
+        """Cache ``prog`` as the jitted shard_map program for ``key``,
+        through the program-store binder."""
+        fn = self._finalize_program(key, jax.jit(shard_map(
+            prog, mesh=self.grid.mesh, in_specs=in_specs,
+            out_specs=out_specs, check_vma=form.check_vma,
+        )))
+        self._programs[key] = fn
+        return fn
 
     @property
     def kernel_variant_realized(self):
@@ -541,17 +537,6 @@ class DistributedSparse(abc.ABC):
                     op, padded_lane_frac=round(frac, 6), block_rows=bm,
                     block_cols=bn, n_chunks=int(tiles.blk_meta.shape[-1]),
                 )
-
-    def _sddmm_args(self, tiles, vals) -> tuple:
-        """Tile operands following the dense args for sddmm programs."""
-        if self._use_blocked(tiles):
-            return (tiles.blk_lr, tiles.blk_lc, tiles.blk_meta, tiles.mask, vals)
-        return (tiles.rows, tiles.cols, tiles.mask, vals)
-
-    def _spmm_args(self, tiles, vals) -> tuple:
-        if self._use_blocked(tiles):
-            return (tiles.blk_lr, tiles.blk_lc, tiles.blk_meta, vals)
-        return (tiles.rows, tiles.cols, vals)
 
     def initial_shift(self, A, B, mode: KernelMode):
         """Pre-skew dense operands if the strategy needs it (no-op default;

@@ -204,181 +204,28 @@ class CannonDense25D(DistributedSparse):
     # Cannon main loop
     # ------------------------------------------------------------------ #
 
-    def _build_blocked_program(self, op: str, use_st: bool):
-        """Blocked (Pallas) variants over the SWAPPED chunk encoding: the
-        accumulator dimension is the tile's column frame (the rotating
-        output), and SDDMM flips its dense operands (it is role-symmetric).
-        Tile chunk metadata and traveling values rotate around the ``cols``
-        ring exactly like the flat struct-of-arrays."""
-        from distributed_sddmm_tpu.ops.blocked import CHUNK
-
-        tiles = self.ST_tiles if use_st else self.S_tiles
-        n, c = self.sqrtpc, self.c
-        max_nnz = tiles.max_nnz
-        out_rows = tiles.tile_cols  # moving-output block height (cols side)
-        kern = self.kernel
-        unroll = self.unroll
-        perm = ring_perm(n)
-        # Swapped geometry: gr blocks tile the COLS frame, gc the ROWS frame.
-        bm, bn, grb, gcb, grp = tiles.blk_geom
-        mov_pad, stat_pad = grb * bm, gcb * bn
-        C = max_nnz // CHUNK
-        # Wire roles: read-only ring payloads (the SDDMM moving input,
-        # tile mask/values; int chunk indices never cast) vs the two
-        # in-flight accumulators — the traveling SDDMM dots and SpMM's
-        # rotating OUTPUT — which hop at ring_accum (f32 under the
-        # default bf16 policy).
-        w_ring = self.wire.dtype_for("ring")
-        w_ring_accum = self.wire.dtype_for("ring_accum")
-        w_gather = self.wire.dtype_for("gather")
-
-        def shift_dense(x, wire=w_ring):
-            return x if n == 1 else abl_ppermute(x, "rows", perm, wire=wire)
-
-        def shift_sparse(tree, wire=w_ring):
-            if n == 1:
-                return tree
-            return jax.tree.map(
-                lambda t: abl_ppermute(t, "cols", perm, wire=wire), tree
-            )
-
-        def replicate(stat):
-            if c == 1:
-                return stat
-            return abl_all_gather(stat, "layers", axis=0, tiled=True, size=c,
-                                  wire=w_gather)
-
-        def dvary(x):
-            return vary(x, ("rows", "cols", "layers"))
-
-        def squeeze_blk(blr, blc, bmeta):
-            return (
-                blr.reshape(C, CHUNK),
-                blc.reshape(C, CHUNK),
-                bmeta.reshape(C),
-            )
-
-        make_tile = self._blk_tile_factory(tiles)
-
-        def blk_of(fields):
-            blr, blc, bmeta = fields
-            return make_tile(blr, blc, bmeta)
-
-        BLK6 = P("rows", "cols", "layers", None, None, None)
-        mesh = self.grid.mesh
-
-        if op == "sddmm":
-
-            def prog(stat, mov, blr, blc, bmeta, t_mask, t_vals):
-                bt = kern.prep(replicate(stat), stat_pad)  # gathered via lc=rows
-                init = (
-                    squeeze_blk(blr, blc, bmeta),
-                    t_mask.reshape(max_nnz),
-                    dvary(jnp.zeros((max_nnz,), t_mask.dtype)),
-                    mov,
-                )
-
-                def body(s, state):
-                    fields, mask, acc, mov = state
-                    at = kern.prep(mov, mov_pad)  # gathered via lr=cols
-                    acc = acc + kern.sddmm_tile_t(
-                        blk_of(fields), mask, at, bt, mask.dtype
-                    )
-                    return (fields, mask, acc, mov)
-
-                def shift_all(state):
-                    fields, mask, acc, mov = state
-                    fields, mask = shift_sparse((fields, mask))
-                    acc = shift_sparse(acc, wire=w_ring_accum)
-                    return (fields, mask, acc, shift_dense(mov))
-
-                def shift_acc_home(state):
-                    fields, mask, acc, mov = state
-                    return (fields, mask,
-                            shift_sparse(acc, wire=w_ring_accum), mov)
-
-                state = ring_loop(
-                    n, body, init, shift_all, shift_final=shift_acc_home,
-                    unroll=unroll,
-                )
-                acc = state[2]
-                return (t_vals.reshape(max_nnz) * acc).reshape(1, 1, 1, 1, max_nnz)
-
-            in_specs = (
-                _DENSE_SPEC, _DENSE_SPEC, BLK6, BLK6,
-                _TILE_SPEC, _TILE_SPEC, _TILE_SPEC,
-            )
-            out_specs = _TILE_SPEC
-
-        elif op == "spmm":
-
-            def prog(stat, mov, blr, blc, bmeta, t_vals):
-                bt = kern.prep(replicate(stat), stat_pad)
-                init = (
-                    squeeze_blk(blr, blc, bmeta),
-                    t_vals.reshape(max_nnz),
-                    mov,
-                )
-
-                def body(s, state):
-                    fields, vals, mov = state
-                    partial = kern.spmm_tile_t(blk_of(fields), vals, bt)
-                    mov = mov + partial.T[:out_rows].astype(mov.dtype)
-                    return (fields, vals, mov)
-
-                def shift_all(state):
-                    fields, vals, mov = state
-                    fields, vals = shift_sparse((fields, vals))
-                    # mov IS the accumulating output here (rotating
-                    # bBuf): ring_accum, not ring.
-                    return (fields, vals,
-                            shift_dense(mov, wire=w_ring_accum))
-
-                def shift_out_home(state):
-                    fields, vals, mov = state
-                    return fields, vals, shift_dense(mov, wire=w_ring_accum)
-
-                state = ring_loop(
-                    n, body, init, shift_all, shift_final=shift_out_home,
-                    unroll=unroll,
-                )
-                return state[2]
-
-            in_specs = (_DENSE_SPEC, _DENSE_SPEC, BLK6, BLK6, _TILE_SPEC, _TILE_SPEC)
-            out_specs = _DENSE_SPEC
-
-        else:
-            raise ValueError(op)
-
-        return jax.jit(
-            shard_map(
-                prog, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False,
-            )
-        )
-
     def _program(self, op: str, use_st: bool):
+        """Build (and cache) the jitted shard_map program for one op
+        (``op`` in {"sddmm", "spmm"}). The tile and its traveling values
+        rotate around the ``cols`` ring, the moving dense operand around
+        the ``rows`` ring. The accumulating output rides the tile's
+        column frame, so the tile seam reads the tile swapped (the
+        blocked encoding is built swapped for the same reason)."""
         key = self._program_cache_key(op, use_st)
         if key in self._programs:
             return self._programs[key]
-        if self._use_blocked(self.ST_tiles if use_st else self.S_tiles):
-            fn = self._finalize_program(
-                key, self._build_blocked_program(op, use_st)
-            )
-            self._programs[key] = fn
-            return fn
 
         tiles = self.ST_tiles if use_st else self.S_tiles
+        form = self._tile_ops(tiles, swap=True)
         n, c = self.sqrtpc, self.c
         max_nnz = tiles.max_nnz
-        stat_frame = tiles.tile_rows  # stationary frame height (rows side)
-        out_rows = tiles.tile_cols  # moving-output block height (cols side)
-        kern = self.kernel
         unroll = self.unroll
         perm = ring_perm(n)
-        # Same wire-role split as the blocked builder: read-only ring
-        # payloads vs the two in-flight accumulators (traveling SDDMM
-        # dots, SpMM's rotating output) at ring_accum.
+        # Wire roles: read-only ring payloads (the SDDMM moving input,
+        # tile mask/values; int indices never cast) vs the two in-flight
+        # accumulators — the traveling SDDMM dots and SpMM's rotating
+        # OUTPUT — which hop at ring_accum (f32 under the default bf16
+        # policy).
         w_ring = self.wire.dtype_for("ring")
         w_ring_accum = self.wire.dtype_for("ring_accum")
         w_gather = self.wire.dtype_for("gather")
@@ -410,45 +257,49 @@ class CannonDense25D(DistributedSparse):
         def squeeze(t):
             return t.reshape(max_nnz)
 
-        mesh = self.grid.mesh
-
         if op == "sddmm":
             # Partial R-slice dots travel with the tile around the cols ring
             # while the moving dense rotates around the rows ring. The
             # traveling accumulator must complete its round trip home.
 
-            def prog(stat, mov, t_rows, t_cols, t_mask, t_vals):
-                stat_rep = replicate(stat)
+            def prog(stat, mov, *targs):
+                *fields, mask, vals = targs
+                x_c = form.cols(replicate(stat))
                 init = (
-                    squeeze(t_rows), squeeze(t_cols), squeeze(t_mask),
-                    dvary(jnp.zeros((max_nnz,), t_mask.dtype)),
+                    form.squeeze(fields), squeeze(mask),
+                    dvary(jnp.zeros((max_nnz,), mask.dtype)),
                     mov,
                 )
 
                 def body(s, state):
-                    rows, cols, mask, acc, mov = state
-                    acc = acc + kern.sddmm(rows, cols, mask, stat_rep, mov)
-                    return (rows, cols, mask, acc, mov)
+                    fields, mask, acc, mov = state
+                    acc = acc + form.sddmm(
+                        form.tile(*fields), mask, form.rows(mov), x_c
+                    )
+                    return (fields, mask, acc, mov)
 
                 def shift_all(state):
-                    rows, cols, mask, acc, mov = state
-                    rows, cols, mask = shift_sparse((rows, cols, mask))
+                    fields, mask, acc, mov = state
+                    fields, mask = shift_sparse((fields, mask))
                     acc = shift_sparse(acc, wire=w_ring_accum)
-                    return (rows, cols, mask, acc, shift_dense(mov))
+                    return (fields, mask, acc, shift_dense(mov))
 
                 def shift_acc_home(state):
-                    rows, cols, mask, acc, mov = state
-                    return (rows, cols, mask,
+                    fields, mask, acc, mov = state
+                    return (fields, mask,
                             shift_sparse(acc, wire=w_ring_accum), mov)
 
                 state = ring_loop(
                     n, body, init, shift_all, shift_final=shift_acc_home,
                     unroll=unroll,
                 )
-                acc = state[3]
-                return (squeeze(t_vals) * acc).reshape(1, 1, 1, 1, max_nnz)
+                acc = state[2]
+                return (squeeze(vals) * acc).reshape(1, 1, 1, 1, max_nnz)
 
-            in_specs = (_DENSE_SPEC, _DENSE_SPEC) + (_TILE_SPEC,) * 4
+            in_specs = (
+                _DENSE_SPEC, _DENSE_SPEC, *form.specs(_TILE_SPEC),
+                _TILE_SPEC, _TILE_SPEC,
+            )
             out_specs = _TILE_SPEC
 
         elif op == "spmm":
@@ -456,26 +307,27 @@ class CannonDense25D(DistributedSparse):
             # moving operand, accumulating as it rotates (the reference's
             # rotating bBuf output, 25D_cannon_dense.hpp:271-305).
 
-            def prog(stat, mov, t_rows, t_cols, t_vals):
-                stat_rep = replicate(stat)
-                init = (squeeze(t_rows), squeeze(t_cols), squeeze(t_vals), mov)
+            def prog(stat, mov, *targs):
+                *fields, vals = targs
+                x_c = form.cols(replicate(stat))
+                init = (form.squeeze(fields), squeeze(vals), mov)
 
                 def body(s, state):
-                    rows, cols, vals, mov = state
-                    mov = mov + kern.spmm(cols, rows, vals, stat_rep, out_rows)
-                    return (rows, cols, vals, mov)
+                    fields, vals, mov = state
+                    partial = form.spmm(form.tile(*fields), vals, x_c)
+                    return (fields, vals, mov + form.finish(partial, mov))
 
                 def shift_all(state):
-                    rows, cols, vals, mov = state
-                    rows, cols, vals = shift_sparse((rows, cols, vals))
+                    fields, vals, mov = state
+                    fields, vals = shift_sparse((fields, vals))
                     # mov IS the accumulating output (rotating bBuf):
                     # ring_accum, not ring.
-                    return (rows, cols, vals,
+                    return (fields, vals,
                             shift_dense(mov, wire=w_ring_accum))
 
                 def shift_out_home(state):
-                    rows, cols, vals, mov = state
-                    return rows, cols, vals, shift_dense(mov, wire=w_ring_accum)
+                    fields, vals, mov = state
+                    return fields, vals, shift_dense(mov, wire=w_ring_accum)
 
                 # The rotating OUTPUT must complete the ring back to its
                 # skewed home; the spent tile needn't.
@@ -483,21 +335,17 @@ class CannonDense25D(DistributedSparse):
                     n, body, init, shift_all, shift_final=shift_out_home,
                     unroll=unroll,
                 )
-                return state[3]
+                return state[2]
 
-            in_specs = (_DENSE_SPEC, _DENSE_SPEC) + (_TILE_SPEC,) * 3
+            in_specs = (
+                _DENSE_SPEC, _DENSE_SPEC, *form.specs(_TILE_SPEC), _TILE_SPEC,
+            )
             out_specs = _DENSE_SPEC
 
         else:
             raise ValueError(op)
 
-        fn = self._finalize_program(
-            key,
-            jax.jit(shard_map(prog, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)),
-        )
-        self._programs[key] = fn
-        return fn
+        return self._shard_program(key, form, prog, in_specs, out_specs)
 
     # ------------------------------------------------------------------ #
     # Public ops (moving operand must be pre-skewed via initial_shift)
@@ -506,23 +354,27 @@ class CannonDense25D(DistributedSparse):
     def sddmm_a(self, A, B, s_vals):
         t = self.ST_tiles
         prog = self._program("sddmm", use_st=True)
-        return self._timed("sddmmA", prog, B, A, *self._sddmm_args(t, s_vals))
+        return self._timed(
+            "sddmmA", prog, B, A, *self._tile_args(t, s_vals, mask=True)
+        )
 
     def sddmm_b(self, A, B, st_vals):
         t = self.S_tiles
         prog = self._program("sddmm", use_st=False)
-        return self._timed("sddmmB", prog, A, B, *self._sddmm_args(t, st_vals))
+        return self._timed(
+            "sddmmB", prog, A, B, *self._tile_args(t, st_vals, mask=True)
+        )
 
     def spmm_a(self, A, B, s_vals):
         """A = S @ B; A must be pre-skewed zeros (or accumulate base)."""
         t = self.ST_tiles
         prog = self._program("spmm", use_st=True)
-        return self._timed("spmmA", prog, B, A, *self._spmm_args(t, s_vals))
+        return self._timed("spmmA", prog, B, A, *self._tile_args(t, s_vals))
 
     def spmm_b(self, A, B, st_vals):
         t = self.S_tiles
         prog = self._program("spmm", use_st=False)
-        return self._timed("spmmB", prog, A, B, *self._spmm_args(t, st_vals))
+        return self._timed("spmmB", prog, A, B, *self._tile_args(t, st_vals))
 
     def fused_spmm(self, A, B, s_vals, mode: MatMode = MatMode.A):
         """SDDMM -> SpMM with the moving operand pre-skewed once for both."""

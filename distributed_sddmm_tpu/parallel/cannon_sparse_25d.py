@@ -281,162 +281,29 @@ class CannonSparse25D(DistributedSparse):
     # Cannon main loop (sparse stationary, both dense operands rotate)
     # ------------------------------------------------------------------ #
 
-    def _build_blocked_program(self, op: str, use_st: bool):
-        """Blocked (Pallas) variants: the sparse chunk lists stay put (they
-        are replicated up the fiber like the rest of the structure); both
-        dense operands rotate and are re-prepped feature-major per step.
-        The fiber value collectives (`25D_cannon_sparse.hpp:221-242,287-306`)
-        operate on the chunk-flat layout, whose length is padded to split
-        evenly into owned slices."""
-        from distributed_sddmm_tpu.ops.blocked import CHUNK
-        from distributed_sddmm_tpu.ops.pallas_kernels import BlockedTile
+    def _program(self, op: str, use_st: bool):
+        """Build (and cache) the jitted shard_map program for one op
+        (``op`` in {"sddmm", "spmm"}). The tile stays put (its structure
+        is replicated up the fiber); both dense operands rotate and enter
+        the tile seam each step. The fiber value collectives
+        (`25D_cannon_sparse.hpp:221-242,287-306`) work on the flat value
+        layout, which a chunk list shares (its length is padded to split
+        evenly into owned slices)."""
+        key = self._program_cache_key(op, use_st)
+        if key in self._programs:
+            return self._programs[key]
 
         tiles = self.ST_tiles if use_st else self.S_tiles
+        form = self._tile_ops(tiles)
         n, c = self.sqrtpc, self.c
         max_nnz, owned_len = tiles.max_nnz, tiles.owned_len
-        out_rows = tiles.tile_rows
-        kern = self.kernel
         unroll = self.unroll
         perm = ring_perm(n)
-        bm, bn, grb, gcb, grp = tiles.blk_geom
-        rows_pad, cols_pad = grb * bm, gcb * bn
-        C = max_nnz // CHUNK
         # Wire roles: both rotating dense operands are read-only in
         # SDDMM (ring); in SpMM the A-role is the accumulating OUTPUT
         # (ring_accum). The fiber value gather is input data (gather);
         # the fiber psum_scatter of SDDMM dots is a reduction (reduce —
         # f32 under the default bf16 policy).
-        w_ring = self.wire.dtype_for("ring")
-        w_ring_accum = self.wire.dtype_for("ring_accum")
-        w_gather = self.wire.dtype_for("gather")
-        w_reduce = self.wire.dtype_for("reduce")
-
-        def shift_a(x, wire=w_ring):
-            return x if n == 1 else abl_ppermute(x, "cols", perm, wire=wire)
-
-        def shift_b(x):
-            return x if n == 1 else abl_ppermute(x, "rows", perm, wire=w_ring)
-
-        def dvary(x):
-            return vary(x, ("rows", "cols", "layers"))
-
-        def blk_of(blr, blc, bmeta):
-            return BlockedTile(
-                blr.reshape(C, CHUNK), blc.reshape(C, CHUNK), bmeta.reshape(C),
-                bm=bm, bn=bn, gr_blocks=grb, gc_blocks=gcb, group=grp,
-            )
-
-        BLK_SPEC = P("rows", "cols", None, None)
-        META_SPEC = P("rows", "cols", None)
-        mesh = self.grid.mesh
-
-        if op == "sddmm":
-
-            def prog(a_role, b_role, blr, blc, bmeta, t_mask, vals_owned):
-                blk = blk_of(blr, blc, bmeta)
-                mask = t_mask.reshape(max_nnz)
-                init = (
-                    dvary(jnp.zeros((max_nnz,), mask.dtype)),
-                    a_role, b_role,
-                )
-
-                def body(s, state):
-                    acc, a, b = state
-                    at = kern.prep(a, rows_pad)
-                    bt = kern.prep(b, cols_pad)
-                    acc = acc + kern.sddmm_tile_t(blk, mask, at, bt, mask.dtype)
-                    return (acc, a, b)
-
-                def shift_ab(state):
-                    acc, a, b = state
-                    return (acc, shift_a(a), shift_b(b))
-
-                state = ring_loop(n, body, init, shift_ab, unroll=unroll)
-                acc = state[0]
-                if c > 1:
-                    owned = abl_psum_scatter(
-                        acc, "layers", scatter_dimension=0, tiled=True,
-                        size=c, wire=w_reduce,
-                    )
-                else:
-                    owned = acc
-                return (vals_owned.reshape(owned_len) * owned).reshape(
-                    1, 1, 1, owned_len
-                )
-
-            in_specs = (
-                _DENSE_SPEC, _DENSE_SPEC, BLK_SPEC, BLK_SPEC, META_SPEC,
-                _STRUCT_SPEC, _VALUES_SPEC,
-            )
-            out_specs = _VALUES_SPEC
-
-        elif op == "spmm":
-
-            def prog(a_role, b_role, blr, blc, bmeta, vals_owned):
-                blk = blk_of(blr, blc, bmeta)
-                v = vals_owned.reshape(owned_len)
-                if c > 1:
-                    vals = abl_all_gather(v, "layers", axis=0, tiled=True,
-                                          size=c, wire=w_gather)
-                else:
-                    vals = v
-                init = (a_role, b_role)
-
-                def body(s, state):
-                    a, b = state
-                    partial = kern.spmm_tile_t(blk, vals, kern.prep(b, cols_pad))
-                    return (a + partial.T[:out_rows].astype(a.dtype), b)
-
-                def shift_ab(state):
-                    # The A-role is the accumulating output: ring_accum.
-                    a, b = state
-                    return (shift_a(a, wire=w_ring_accum), shift_b(b))
-
-                def shift_out_home(state):
-                    a, b = state
-                    return (shift_a(a, wire=w_ring_accum), b)
-
-                state = ring_loop(
-                    n, body, init, shift_ab, shift_final=shift_out_home,
-                    unroll=unroll,
-                )
-                return state[0]
-
-            in_specs = (
-                _DENSE_SPEC, _DENSE_SPEC, BLK_SPEC, BLK_SPEC, META_SPEC,
-                _VALUES_SPEC,
-            )
-            out_specs = _DENSE_SPEC
-
-        else:
-            raise ValueError(op)
-
-        return jax.jit(
-            shard_map(
-                prog, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False,
-            )
-        )
-
-    def _program(self, op: str, use_st: bool):
-        key = self._program_cache_key(op, use_st)
-        if key in self._programs:
-            return self._programs[key]
-        if self._use_blocked(self.ST_tiles if use_st else self.S_tiles):
-            fn = self._finalize_program(
-                key, self._build_blocked_program(op, use_st)
-            )
-            self._programs[key] = fn
-            return fn
-
-        tiles = self.ST_tiles if use_st else self.S_tiles
-        n, c = self.sqrtpc, self.c
-        max_nnz, owned_len = tiles.max_nnz, tiles.owned_len
-        out_rows = tiles.tile_rows
-        kern = self.kernel
-        unroll = self.unroll
-        perm = ring_perm(n)
-        # Same wire-role split as the blocked builder (see there).
         w_ring = self.wire.dtype_for("ring")
         w_ring_accum = self.wire.dtype_for("ring_accum")
         w_gather = self.wire.dtype_for("gather")
@@ -451,14 +318,14 @@ class CannonSparse25D(DistributedSparse):
         def dvary(x):
             return vary(x, ("rows", "cols", "layers"))
 
-        mesh = self.grid.mesh
+        struct_specs = form.specs(_STRUCT_SPEC)
 
         if op == "sddmm":
 
-            def prog(a_role, b_role, t_rows, t_cols, t_mask, vals_owned):
-                rows = t_rows.reshape(max_nnz)
-                cols = t_cols.reshape(max_nnz)
-                mask = t_mask.reshape(max_nnz)
+            def prog(a_role, b_role, *targs):
+                *fields, mask, vals_owned = targs
+                tile = form.tile(*form.squeeze(fields))
+                mask = mask.reshape(max_nnz)
                 init = (
                     dvary(jnp.zeros((max_nnz,), mask.dtype)),
                     a_role, b_role,
@@ -466,7 +333,10 @@ class CannonSparse25D(DistributedSparse):
 
                 def body(s, state):
                     acc, a, b = state
-                    return (acc + kern.sddmm(rows, cols, mask, a, b), a, b)
+                    acc = acc + form.sddmm(
+                        tile, mask, form.rows(a), form.cols(b)
+                    )
+                    return (acc, a, b)
 
                 def shift_ab(state):
                     acc, a, b = state
@@ -488,8 +358,8 @@ class CannonSparse25D(DistributedSparse):
                 )
 
             in_specs = (
-                _DENSE_SPEC, _DENSE_SPEC,
-                _STRUCT_SPEC, _STRUCT_SPEC, _STRUCT_SPEC, _VALUES_SPEC,
+                _DENSE_SPEC, _DENSE_SPEC, *struct_specs,
+                _STRUCT_SPEC, _VALUES_SPEC,
             )
             out_specs = _VALUES_SPEC
 
@@ -497,9 +367,9 @@ class CannonSparse25D(DistributedSparse):
             # A-role is the rotating OUTPUT accumulating complete results;
             # values gathered up the fiber first.
 
-            def prog(a_role, b_role, t_rows, t_cols, vals_owned):
-                rows = t_rows.reshape(max_nnz)
-                cols = t_cols.reshape(max_nnz)
+            def prog(a_role, b_role, *targs):
+                *fields, vals_owned = targs
+                tile = form.tile(*form.squeeze(fields))
                 v = vals_owned.reshape(owned_len)
                 if c > 1:
                     vals = abl_all_gather(v, "layers", axis=0, tiled=True,
@@ -510,7 +380,8 @@ class CannonSparse25D(DistributedSparse):
 
                 def body(s, state):
                     a, b = state
-                    return (a + kern.spmm(rows, cols, vals, b, out_rows), b)
+                    partial = form.spmm(tile, vals, form.cols(b))
+                    return (a + form.finish(partial, a), b)
 
                 def shift_ab(state):
                     # The A-role is the accumulating output: ring_accum.
@@ -529,22 +400,13 @@ class CannonSparse25D(DistributedSparse):
                 )
                 return state[0]
 
-            in_specs = (
-                _DENSE_SPEC, _DENSE_SPEC,
-                _STRUCT_SPEC, _STRUCT_SPEC, _VALUES_SPEC,
-            )
+            in_specs = (_DENSE_SPEC, _DENSE_SPEC, *struct_specs, _VALUES_SPEC)
             out_specs = _DENSE_SPEC
 
         else:
             raise ValueError(op)
 
-        fn = self._finalize_program(
-            key,
-            jax.jit(shard_map(prog, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)),
-        )
-        self._programs[key] = fn
-        return fn
+        return self._shard_program(key, form, prog, in_specs, out_specs)
 
     # ------------------------------------------------------------------ #
     # Public ops (the moving operand must be transpose-shifted first)
@@ -553,22 +415,26 @@ class CannonSparse25D(DistributedSparse):
     def sddmm_a(self, A, B, s_vals):
         t = self.S_tiles
         prog = self._program("sddmm", use_st=False)
-        return self._timed("sddmmA", prog, A, B, *self._sddmm_args(t, s_vals))
+        return self._timed(
+            "sddmmA", prog, A, B, *self._tile_args(t, s_vals, mask=True)
+        )
 
     def sddmm_b(self, A, B, st_vals):
         t = self.ST_tiles
         prog = self._program("sddmm", use_st=True)
-        return self._timed("sddmmB", prog, B, A, *self._sddmm_args(t, st_vals))
+        return self._timed(
+            "sddmmB", prog, B, A, *self._tile_args(t, st_vals, mask=True)
+        )
 
     def spmm_a(self, A, B, s_vals):
         t = self.S_tiles
         prog = self._program("spmm", use_st=False)
-        return self._timed("spmmA", prog, A, B, *self._spmm_args(t, s_vals))
+        return self._timed("spmmA", prog, A, B, *self._tile_args(t, s_vals))
 
     def spmm_b(self, A, B, st_vals):
         t = self.ST_tiles
         prog = self._program("spmm", use_st=True)
-        return self._timed("spmmB", prog, B, A, *self._spmm_args(t, st_vals))
+        return self._timed("spmmB", prog, B, A, *self._tile_args(t, st_vals))
 
     def fused_spmm(self, A, B, s_vals, mode: MatMode = MatMode.A):
         if mode == MatMode.A:

@@ -29,13 +29,10 @@ TPU-native redesign of the reference's ``Sparse15D_Dense_Shift``
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from distributed_sddmm_tpu.compat import shard_map
 
 from distributed_sddmm_tpu.common import MatMode, divide_round_up
 from distributed_sddmm_tpu.parallel.base import DistributedSparse
@@ -214,31 +211,22 @@ class DenseShift15D(DistributedSparse):
     def _program(self, op: str, use_st: bool):
         """Build (and cache) the jitted shard_map program for one op.
 
-        ``op`` in {"sddmm", "spmm", "fused", "fused_twopass"}; ``use_st``
-        selects the transposed tile set (B-output variants). The moving
-        operand always rotates along the ``rows`` axis; the stationary
-        operand is replicated over the ``cols`` axis.
-
-        When the kernel is blocked-capable (Pallas) and the tiles carry
-        chunk-list metadata, the blocked program variants are built instead:
-        same ring/collective structure, but local compute runs feature-major
-        through the tile-level Pallas kernels.
+        ``op`` in {"sddmm", "spmm", "fused", "fused_twopass", "attn",
+        "attn_softmax"}; ``use_st`` selects the transposed tile set
+        (B-output variants). The moving operand always rotates along the
+        ``rows`` axis; the stationary operand is replicated over the
+        ``cols`` axis. The local tile work goes through the tile seam
+        (``_tile_ops``): flat XLA ops, or the feature-major Pallas tile
+        kernels when the tile set carries chunk lists.
         """
         key = self._program_cache_key(op, use_st)
         if key in self._programs:
             return self._programs[key]
-        if self._use_blocked(self.ST_tiles if use_st else self.S_tiles):
-            fn = self._finalize_program(
-                key, self._build_blocked_program(op, use_st)
-            )
-            self._programs[key] = fn
-            return fn
 
         tiles = self.ST_tiles if use_st else self.S_tiles
+        form = self._tile_ops(tiles)
         nr, c = self.nr, self.c
         T, max_nnz = tiles.n_tiles, tiles.max_nnz
-        stat_rows = tiles.tile_rows  # stationary/output frame height
-        kern = self.kernel
         perm = ring_perm(nr)
         unroll = self.unroll
         overlap = self.overlap
@@ -263,6 +251,9 @@ class DenseShift15D(DistributedSparse):
                 return arr[s]
             return lax.dynamic_index_in_dim(arr, s, axis=0, keepdims=False)
 
+        def tile(fields, s):
+            return form.tile(*(tile_at(f, s) for f in fields))
+
         def replicate(stat_blk):
             if c == 1:
                 return stat_blk
@@ -280,211 +271,150 @@ class DenseShift15D(DistributedSparse):
         def squeeze(t):
             return t.reshape(T, max_nnz)
 
+        def unpack(targs):
+            *fields, vals = targs
+            return form.squeeze(fields, (T,)), squeeze(vals)
+
         def dvary(x):
             return vary(x, ("rows", "cols"))
 
-        def sddmm_pass(stat_rep, mov, t_rows, t_cols, t_vals, out_vals,
-                       complete_rotation=False):
+        def ring(local, carry, mov, complete_rotation=False):
+            """``carry = local(s, carry, mov)`` at each of the ``nr``
+            steps; ``complete_rotation`` brings ``mov`` home."""
             if overlap:
-                def body(s, out_vals, mov):
-                    dots = kern.sddmm(
-                        tile_at(t_rows, s), tile_at(t_cols, s),
-                        tile_at(t_vals, s), stat_rep, mov,
-                    )
-                    return out_vals.at[s].set(dots)
-
                 return ring_loop_overlap(
-                    nr, body, out_vals, mov, shift_one,
+                    nr, local, carry, mov, shift_one,
                     final_shift=complete_rotation, unroll=unroll,
                 )
 
             def body(s, state):
-                out_vals, mov = state
-                dots = kern.sddmm(
-                    tile_at(t_rows, s), tile_at(t_cols, s), tile_at(t_vals, s),
-                    stat_rep, mov,
-                )
-                return out_vals.at[s].set(dots), mov
+                carry, mov = state
+                return local(s, carry, mov), mov
 
             return ring_loop(
-                nr, body, (out_vals, mov), shift_mov,
+                nr, body, (carry, mov), shift_mov,
                 shift_final=shift_mov if complete_rotation else None,
                 unroll=unroll,
             )
 
-        def spmm_pass(mov, t_rows, t_cols, vals_tiles, acc):
-            if overlap:
-                def body(s, acc, mov):
-                    return acc + kern.spmm(
-                        tile_at(t_rows, s), tile_at(t_cols, s),
-                        tile_at(vals_tiles, s), mov, stat_rows,
-                    )
+        def sddmm_pass(x_r, mov, fields, vals, complete_rotation=False):
+            def local(s, out_vals, mov):
+                return out_vals.at[s].set(form.sddmm(
+                    tile(fields, s), tile_at(vals, s), x_r, form.cols(mov)
+                ))
 
-                return ring_loop_overlap(
-                    nr, body, acc, mov, shift_one, unroll=unroll
+            out_vals = dvary(jnp.zeros((T, max_nnz), vals.dtype))
+            return ring(local, out_vals, mov, complete_rotation)
+
+        def spmm_pass(mov, fields, vals):
+            def local(s, acc, mov):
+                return acc + form.spmm(
+                    tile(fields, s), tile_at(vals, s), form.cols(mov)
                 )
 
-            def body(s, state):
-                acc, mov = state
-                acc = acc + kern.spmm(
-                    tile_at(t_rows, s), tile_at(t_cols, s), tile_at(vals_tiles, s),
-                    mov, stat_rows,
-                )
-                return acc, mov
+            acc, _ = ring(local, dvary(form.zeros(mov)), mov)
+            return reduce_out(form.finish(acc, mov))
 
-            return ring_loop(nr, body, (acc, mov), shift_mov, unroll=unroll)
-
-        dense_spec = _DENSE_SPEC
-        mesh = self.grid.mesh
+        # (stationary, moving, *tile fields, values)
+        two_dense_in = (
+            _DENSE_SPEC, _DENSE_SPEC, *form.specs(_TILE_SPEC), _TILE_SPEC,
+        )
+        pair_out = (_DENSE_SPEC, _TILE_SPEC)
 
         if op == "sddmm":
 
-            def prog(stat, mov, t_rows, t_cols, t_vals):
-                t_rows, t_cols, t_vals = squeeze(t_rows), squeeze(t_cols), squeeze(t_vals)
-                stat_rep = replicate(stat)
-                out_vals = dvary(jnp.zeros((T, max_nnz), t_vals.dtype))
-                out_vals, _ = sddmm_pass(stat_rep, mov, t_rows, t_cols, t_vals, out_vals)
+            def prog(stat, mov, *targs):
+                fields, vals = unpack(targs)
+                x_r = form.rows(replicate(stat))
+                out_vals, _ = sddmm_pass(x_r, mov, fields, vals)
                 return out_vals.reshape(1, 1, 1, T, max_nnz)
 
-            in_specs = (dense_spec, dense_spec, _TILE_SPEC, _TILE_SPEC, _TILE_SPEC)
-            out_specs = _TILE_SPEC
+            in_specs, out_specs = two_dense_in, _TILE_SPEC
 
         elif op == "spmm":
 
-            def prog(mov, t_rows, t_cols, t_vals):
-                t_rows, t_cols, t_vals = squeeze(t_rows), squeeze(t_cols), squeeze(t_vals)
-                acc = dvary(jnp.zeros((stat_rows, mov.shape[1]), mov.dtype))
-                acc, _ = spmm_pass(mov, t_rows, t_cols, t_vals, acc)
-                return reduce_out(acc)
+            def prog(mov, *targs):
+                fields, vals = unpack(targs)
+                return spmm_pass(mov, fields, vals)
 
-            in_specs = (dense_spec, _TILE_SPEC, _TILE_SPEC, _TILE_SPEC)
-            out_specs = dense_spec
+            in_specs = (_DENSE_SPEC, *form.specs(_TILE_SPEC), _TILE_SPEC)
+            out_specs = _DENSE_SPEC
 
         elif op == "fused":
             # fusion 2, "local kernel overlap": SDDMM + SpMM per tile inside
             # one ring traversal (`15D_dense_shift.hpp:199-227`).
 
             @jax.named_scope("fused")
-            def prog(stat, mov, t_rows, t_cols, t_vals):
-                t_rows, t_cols, t_vals = squeeze(t_rows), squeeze(t_cols), squeeze(t_vals)
-                stat_rep = replicate(stat)
+            def prog(stat, mov, *targs):
+                fields, vals = unpack(targs)
+                x_r = form.rows(replicate(stat))
                 init = (
-                    dvary(jnp.zeros((stat_rows, mov.shape[1]), mov.dtype)),
-                    dvary(jnp.zeros((T, max_nnz), t_vals.dtype)),
+                    dvary(form.zeros(mov)),
+                    dvary(jnp.zeros((T, max_nnz), vals.dtype)),
                 )
 
-                if overlap:
-                    def body(s, carry, mov):
-                        acc, out_vals = carry
-                        rs, cs = tile_at(t_rows, s), tile_at(t_cols, s)
-                        mid = kern.sddmm(
-                            rs, cs, tile_at(t_vals, s), stat_rep, mov
-                        )
-                        out_vals = out_vals.at[s].set(mid)
-                        return (
-                            acc + kern.spmm(rs, cs, mid, mov, stat_rows),
-                            out_vals,
-                        )
-
-                    (acc, out_vals), _ = ring_loop_overlap(
-                        nr, body, init, mov, shift_one, unroll=unroll
+                def local(s, carry, mov):
+                    acc, out_vals = carry
+                    return form.fused(
+                        tile(fields, s), tile_at(vals, s), x_r,
+                        form.cols(mov), acc,
+                        lambda mid: out_vals.at[s].set(mid),
                     )
-                else:
-                    def body(s, state):
-                        (acc, out_vals), mov = state
-                        rs, cs = tile_at(t_rows, s), tile_at(t_cols, s)
-                        mid = kern.sddmm(rs, cs, tile_at(t_vals, s), stat_rep, mov)
-                        out_vals = out_vals.at[s].set(mid)
-                        return (acc + kern.spmm(rs, cs, mid, mov, stat_rows), out_vals), mov
 
-                    (acc, out_vals), _ = ring_loop(
-                        nr, body, (init, mov), shift_mov, unroll=unroll
-                    )
-                return reduce_out(acc), out_vals.reshape(1, 1, 1, T, max_nnz)
+                (acc, out_vals), _ = ring(local, init, mov)
+                return (reduce_out(form.finish(acc, mov)),
+                        out_vals.reshape(1, 1, 1, T, max_nnz))
 
-            in_specs = (dense_spec, dense_spec, _TILE_SPEC, _TILE_SPEC, _TILE_SPEC)
-            out_specs = (dense_spec, _TILE_SPEC)
+            in_specs, out_specs = two_dense_in, pair_out
 
-        elif op == "fused_twopass":
+        elif op in ("fused_twopass", "attn"):
             # fusion 1, "replication reuse": one all_gather feeds two ring
             # passes (SDDMM then SpMM) in one compiled program — the
             # functional equivalent of `initial_replicate=false` on the
-            # second call (`distributed_sparse.h:296-312`).
+            # second call (`distributed_sparse.h:296-312`). Attention runs
+            # the same two passes with the masked-softmax epilogue between
+            # them: the SDDMM pass completes its rotation, so every logit
+            # of the device's rows lands before any weight is formed
+            # (segment stats + a [rows]-vector merge over the replication
+            # axis) — one compiled program, no dense logits materialized.
 
-            def prog(stat, mov, t_rows, t_cols, t_vals):
-                t_rows, t_cols, t_vals = squeeze(t_rows), squeeze(t_cols), squeeze(t_vals)
-                stat_rep = replicate(stat)
-                out_vals = dvary(jnp.zeros((T, max_nnz), t_vals.dtype))
+            def prog(stat, mov, *targs):
+                fields, vals = unpack(targs)
+                x_r = form.rows(replicate(stat))
                 out_vals, mov = sddmm_pass(
-                    stat_rep, mov, t_rows, t_cols, t_vals, out_vals,
-                    complete_rotation=True,
+                    x_r, mov, fields, vals, complete_rotation=True
                 )
-                acc = dvary(jnp.zeros((stat_rows, mov.shape[1]), mov.dtype))
-                acc, _ = spmm_pass(mov, t_rows, t_cols, out_vals, acc)
-                return reduce_out(acc), out_vals.reshape(1, 1, 1, T, max_nnz)
+                if op == "attn":
+                    out_vals = form.softmax(
+                        fields, vals, out_vals, self._merge_stats_cols
+                    )
+                return (spmm_pass(mov, fields, out_vals),
+                        out_vals.reshape(1, 1, 1, T, max_nnz))
 
-            in_specs = (dense_spec, dense_spec, _TILE_SPEC, _TILE_SPEC, _TILE_SPEC)
-            out_specs = (dense_spec, _TILE_SPEC)
-
-        elif op == "attn":
-            # Fused block-sparse attention: SDDMM ring pass (complete
-            # rotation — every logit of the device's rows lands before
-            # any weight is formed), masked-softmax epilogue (segment
-            # stats + a [rows]-vector merge over the replication axis),
-            # SpMM ring pass over the normalized weights — ONE compiled
-            # program, no dense logits materialized.
-
-            def prog(stat, mov, t_rows, t_cols, t_vals):
-                t_rows, t_cols, t_vals = squeeze(t_rows), squeeze(t_cols), squeeze(t_vals)
-                stat_rep = replicate(stat)
-                out_vals = dvary(jnp.zeros((T, max_nnz), t_vals.dtype))
-                logits, mov = sddmm_pass(
-                    stat_rep, mov, t_rows, t_cols, t_vals, out_vals,
-                    complete_rotation=True,
-                )
-                probs = self._softmax_flat(
-                    kern, t_rows, t_vals, logits, stat_rows
-                )
-                acc = dvary(jnp.zeros((stat_rows, mov.shape[1]), mov.dtype))
-                acc, _ = spmm_pass(mov, t_rows, t_cols, probs, acc)
-                return reduce_out(acc), probs.reshape(1, 1, 1, T, max_nnz)
-
-            in_specs = (dense_spec, dense_spec, _TILE_SPEC, _TILE_SPEC, _TILE_SPEC)
-            out_specs = (dense_spec, _TILE_SPEC)
+            in_specs, out_specs = two_dense_in, pair_out
 
         elif op == "attn_softmax":
             # Standalone masked softmax over tile-layout logits — the
             # middle stage of the UNFUSED baseline; shares the exact
-            # softmax closure with the fused program so the two paths
-            # stay bit-aligned.
+            # softmax with the fused program so the two paths stay
+            # bit-aligned.
 
-            def prog(t_rows, t_cols, t_vals, t_logits):
-                t_rows, t_vals = squeeze(t_rows), squeeze(t_vals)
-                probs = self._softmax_flat(
-                    kern, t_rows, t_vals, squeeze(t_logits), stat_rows
+            def prog(*targs):
+                *fields, vals, logits = targs
+                fields = form.squeeze(form.softmax_fields(fields), (T,))
+                probs = form.softmax(
+                    fields, squeeze(vals), squeeze(logits),
+                    self._merge_stats_cols,
                 )
                 return probs.reshape(1, 1, 1, T, max_nnz)
 
-            in_specs = (_TILE_SPEC, _TILE_SPEC, _TILE_SPEC, _TILE_SPEC)
+            in_specs = (*form.specs(_TILE_SPEC), _TILE_SPEC, _TILE_SPEC)
             out_specs = _TILE_SPEC
 
         else:
             raise ValueError(op)
 
-        fn = self._finalize_program(
-            key,
-            jax.jit(
-                shard_map(prog, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-            ),
-        )
-        self._programs[key] = fn
-        return fn
-
-    # ------------------------------------------------------------------ #
-    # Masked-softmax epilogue helpers (shared by the fused and the
-    # standalone-softmax programs of both kernel families).
-    # ------------------------------------------------------------------ #
+        return self._shard_program(key, form, prog, in_specs, out_specs)
 
     def _merge_stats_cols(self, m, d):
         """Cross-device online-softmax merge over the replication axis:
@@ -497,313 +427,6 @@ class DenseShift15D(DistributedSparse):
         mg = lax.pmax(m, "cols")
         dg = lax.psum(d * jnp.exp(m - mg), "cols")
         return mg, dg
-
-    def _softmax_flat(self, kern, t_rows, gate_t, logits_t, stat_rows):
-        """Row-wise masked softmax over flat tile-layout values: local
-        segment stats over ALL tiles (the SDDMM rotation completed, so
-        the device holds every logit it owns), cross-device merge,
-        normalize. Returns probs in tile layout [T, max_nnz]."""
-        shape = gate_t.shape
-        rows_f = t_rows.reshape(-1)
-        gate_f = gate_t.reshape(-1)
-        z_f = logits_t.reshape(-1)
-        m, d = kern.attn_stats(rows_f, gate_f, z_f, stat_rows)
-        m, d = self._merge_stats_cols(m, d)
-        return kern.attn_normalize(rows_f, gate_f, z_f, m, d).reshape(shape)
-
-    def _softmax_blk(self, kern, make_tile, fields, gate_t, logits_t):
-        """Blocked-path softmax: per-tile Pallas reduce launches riding
-        the chunk-list metadata, tile merge, cross-device merge, then
-        per-tile Pallas normalize launches. The tile loop is static
-        (one specialized launch pair per tile, exactly like the banked
-        per-band launches)."""
-        from distributed_sddmm_tpu.ops.kernels import attn_merge_stats
-
-        blr, blc, bmeta = fields
-        T = gate_t.shape[0]
-        tiles = [make_tile(blr[s], blc[s], bmeta[s]) for s in range(T)]
-        stats = [
-            kern.attn_stats_tile_t(tiles[s], gate_t[s], logits_t[s])
-            for s in range(T)
-        ]
-        m, d = attn_merge_stats(stats)
-        m, d = self._merge_stats_cols(m, d)
-        probs = [
-            kern.attn_norm_tile_t(
-                tiles[s], gate_t[s], logits_t[s], m, d, gate_t.dtype
-            )
-            for s in range(T)
-        ]
-        return jnp.stack(probs)
-
-    # ------------------------------------------------------------------ #
-    # Blocked (Pallas) shard_map programs — same ring/collective skeleton,
-    # local compute through the feature-major tile kernels.
-    # ------------------------------------------------------------------ #
-
-    def _build_blocked_program(self, op: str, use_st: bool):
-        from distributed_sddmm_tpu.ops.blocked import CHUNK
-
-        tiles = self.ST_tiles if use_st else self.S_tiles
-        nr, c = self.nr, self.c
-        T, max_nnz = tiles.n_tiles, tiles.max_nnz
-        stat_rows = tiles.tile_rows
-        kern = self.kernel
-        perm = ring_perm(nr)
-        unroll = self.unroll
-        overlap = self.overlap
-        bm, bn, grb, gcb, grp = tiles.blk_geom
-        rows_pad, cols_pad = grb * bm, gcb * bn
-        chunk_len = CHUNK
-        # Same per-role wire dtypes as the flat programs (the blocked
-        # ring/collective skeleton is identical — only local compute
-        # changes).
-        w_ring = self.wire.dtype_for("ring")
-        w_gather = self.wire.dtype_for("gather")
-        w_reduce = self.wire.dtype_for("reduce")
-
-        def shift_one(mov):
-            return abl_ppermute(mov, "rows", perm, wire=w_ring)
-
-        def shift_mov(state):
-            carry, mov = state
-            return carry, shift_one(mov)
-
-        def tile_at(arr, s):
-            if unroll:
-                return arr[s]
-            return lax.dynamic_index_in_dim(arr, s, axis=0, keepdims=False)
-
-        def replicate(stat_blk):
-            if c == 1:
-                return stat_blk
-            return abl_all_gather(stat_blk, "cols", axis=0, tiled=True,
-                                  size=c, wire=w_gather)
-
-        def reduce_out(acc):
-            if c == 1:
-                return acc
-            return abl_psum_scatter(
-                acc, "cols", scatter_dimension=0, tiled=True, size=c,
-                wire=w_reduce,
-            )
-
-        def dvary(x):
-            return vary(x, ("rows", "cols"))
-
-        def squeeze_blk(blr, blc, bmeta):
-            C = blr.shape[-2]
-            return (
-                blr.reshape(T, C, chunk_len),
-                blc.reshape(T, C, chunk_len),
-                bmeta.reshape(T, C),
-            )
-
-        make_tile = self._blk_tile_factory(tiles)
-
-        def blk_at(fields, s):
-            blr, blc, bmeta = fields
-            return make_tile(
-                tile_at(blr, s), tile_at(blc, s), tile_at(bmeta, s)
-            )
-
-        def sddmm_pass(at, mov, fields, t_vals, out_vals, complete_rotation=False):
-            if overlap:
-                def body(s, out_vals, mov):
-                    mid = kern.sddmm_tile_t(
-                        blk_at(fields, s), tile_at(t_vals, s),
-                        at, kern.prep(mov, cols_pad), t_vals.dtype,
-                    )
-                    return out_vals.at[s].set(mid)
-
-                return ring_loop_overlap(
-                    nr, body, out_vals, mov, shift_one,
-                    final_shift=complete_rotation, unroll=unroll,
-                )
-
-            def body(s, state):
-                out_vals, mov = state
-                mid = kern.sddmm_tile_t(
-                    blk_at(fields, s), tile_at(t_vals, s),
-                    at, kern.prep(mov, cols_pad), t_vals.dtype,
-                )
-                return out_vals.at[s].set(mid), mov
-
-            return ring_loop(
-                nr, body, (out_vals, mov), shift_mov,
-                shift_final=shift_mov if complete_rotation else None,
-                unroll=unroll,
-            )
-
-        def spmm_pass(mov, fields, vals_tiles, accT):
-            if overlap:
-                def body(s, accT, mov):
-                    return accT + kern.spmm_tile_t(
-                        blk_at(fields, s), tile_at(vals_tiles, s),
-                        kern.prep(mov, cols_pad),
-                    )
-
-                return ring_loop_overlap(
-                    nr, body, accT, mov, shift_one, unroll=unroll
-                )
-
-            def body(s, state):
-                accT, mov = state
-                accT = accT + kern.spmm_tile_t(
-                    blk_at(fields, s), tile_at(vals_tiles, s),
-                    kern.prep(mov, cols_pad),
-                )
-                return accT, mov
-
-            return ring_loop(nr, body, (accT, mov), shift_mov, unroll=unroll)
-
-        def finish(accT, like):
-            return reduce_out(accT.T[:stat_rows].astype(like.dtype))
-
-        dense_spec = _DENSE_SPEC
-        BLK6 = P("rows", "cols", None, None, None, None)
-        blk_specs = (BLK6, BLK6, _TILE_SPEC)
-        mesh = self.grid.mesh
-
-        if op == "sddmm":
-
-            def prog(stat, mov, blr, blc, bmeta, t_vals):
-                fields = squeeze_blk(blr, blc, bmeta)
-                t_vals = t_vals.reshape(T, max_nnz)
-                at = kern.prep(replicate(stat), rows_pad)
-                out_vals = dvary(jnp.zeros((T, max_nnz), t_vals.dtype))
-                out_vals, _ = sddmm_pass(at, mov, fields, t_vals, out_vals)
-                return out_vals.reshape(1, 1, 1, T, max_nnz)
-
-            in_specs = (dense_spec, dense_spec) + blk_specs + (_TILE_SPEC,)
-            out_specs = _TILE_SPEC
-
-        elif op == "spmm":
-
-            def prog(mov, blr, blc, bmeta, t_vals):
-                fields = squeeze_blk(blr, blc, bmeta)
-                t_vals = t_vals.reshape(T, max_nnz)
-                accT = dvary(jnp.zeros((mov.shape[-1], rows_pad), jnp.float32))
-                accT, _ = spmm_pass(mov, fields, t_vals, accT)
-                return finish(accT, mov)
-
-            in_specs = (dense_spec,) + blk_specs + (_TILE_SPEC,)
-            out_specs = dense_spec
-
-        elif op == "fused":
-
-            @jax.named_scope("fused")
-            def prog(stat, mov, blr, blc, bmeta, t_vals):
-                fields = squeeze_blk(blr, blc, bmeta)
-                t_vals = t_vals.reshape(T, max_nnz)
-                at = kern.prep(replicate(stat), rows_pad)
-                init = (
-                    dvary(jnp.zeros((mov.shape[-1], rows_pad), jnp.float32)),
-                    dvary(jnp.zeros((T, max_nnz), t_vals.dtype)),
-                )
-
-                if overlap:
-                    def body(s, carry, mov):
-                        accT, out_vals = carry
-                        pT, mid = kern.fused_tile_t(
-                            blk_at(fields, s), tile_at(t_vals, s),
-                            at, kern.prep(mov, cols_pad), t_vals.dtype,
-                        )
-                        return (accT + pT, out_vals.at[s].set(mid))
-
-                    (accT, out_vals), _ = ring_loop_overlap(
-                        nr, body, init, mov, shift_one, unroll=unroll
-                    )
-                else:
-                    def body(s, state):
-                        (accT, out_vals), mov = state
-                        pT, mid = kern.fused_tile_t(
-                            blk_at(fields, s), tile_at(t_vals, s),
-                            at, kern.prep(mov, cols_pad), t_vals.dtype,
-                        )
-                        return (accT + pT, out_vals.at[s].set(mid)), mov
-
-                    (accT, out_vals), _ = ring_loop(
-                        nr, body, (init, mov), shift_mov, unroll=unroll
-                    )
-                return finish(accT, mov), out_vals.reshape(1, 1, 1, T, max_nnz)
-
-            in_specs = (dense_spec, dense_spec) + blk_specs + (_TILE_SPEC,)
-            out_specs = (dense_spec, _TILE_SPEC)
-
-        elif op == "fused_twopass":
-
-            def prog(stat, mov, blr, blc, bmeta, t_vals):
-                fields = squeeze_blk(blr, blc, bmeta)
-                t_vals = t_vals.reshape(T, max_nnz)
-                at = kern.prep(replicate(stat), rows_pad)
-                out_vals = dvary(jnp.zeros((T, max_nnz), t_vals.dtype))
-                out_vals, mov = sddmm_pass(
-                    at, mov, fields, t_vals, out_vals, complete_rotation=True
-                )
-                accT = dvary(jnp.zeros((mov.shape[-1], rows_pad), jnp.float32))
-                accT, _ = spmm_pass(mov, fields, out_vals, accT)
-                return finish(accT, mov), out_vals.reshape(1, 1, 1, T, max_nnz)
-
-            in_specs = (dense_spec, dense_spec) + blk_specs + (_TILE_SPEC,)
-            out_specs = (dense_spec, _TILE_SPEC)
-
-        elif op == "attn":
-            # Fused block-sparse attention, blocked: the masked-softmax
-            # epilogue rides the SAME chunk-list metadata between the
-            # SDDMM and SpMM ring passes — per-tile Pallas reduce/
-            # normalize launches, a tile merge, and the cols-axis merge,
-            # all inside ONE compiled program.
-
-            def prog(stat, mov, blr, blc, bmeta, t_vals):
-                fields = squeeze_blk(blr, blc, bmeta)
-                t_vals = t_vals.reshape(T, max_nnz)
-                at = kern.prep(replicate(stat), rows_pad)
-                out_vals = dvary(jnp.zeros((T, max_nnz), t_vals.dtype))
-                logits, mov = sddmm_pass(
-                    at, mov, fields, t_vals, out_vals, complete_rotation=True
-                )
-                probs = self._softmax_blk(
-                    kern, make_tile, fields, t_vals, logits
-                )
-                accT = dvary(jnp.zeros((mov.shape[-1], rows_pad), jnp.float32))
-                accT, _ = spmm_pass(mov, fields, probs, accT)
-                return finish(accT, mov), probs.reshape(1, 1, 1, T, max_nnz)
-
-            in_specs = (dense_spec, dense_spec) + blk_specs + (_TILE_SPEC,)
-            out_specs = (dense_spec, _TILE_SPEC)
-
-        elif op == "attn_softmax":
-
-            def prog(blr, blc, bmeta, t_vals, t_logits):
-                fields = squeeze_blk(blr, blc, bmeta)
-                t_vals = t_vals.reshape(T, max_nnz)
-                probs = self._softmax_blk(
-                    kern, make_tile, fields, t_vals,
-                    t_logits.reshape(T, max_nnz),
-                )
-                return probs.reshape(1, 1, 1, T, max_nnz)
-
-            in_specs = blk_specs + (_TILE_SPEC, _TILE_SPEC)
-            out_specs = _TILE_SPEC
-
-        else:
-            raise ValueError(op)
-
-        # check_vma=False: pallas_call out_shapes carry no varying-mesh-axes
-        # annotation, which the strict checker rejects inside shard_map.
-        return jax.jit(
-            shard_map(
-                prog, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False,
-            )
-        )
-
-    def _tile_args(self, tiles, vals) -> tuple:
-        """The per-path tile operands following the dense args."""
-        if self._use_blocked(tiles):
-            return (tiles.blk_lr, tiles.blk_lc, tiles.blk_meta, vals)
-        return (tiles.rows, tiles.cols, vals)
 
     # ------------------------------------------------------------------ #
     # Public ops

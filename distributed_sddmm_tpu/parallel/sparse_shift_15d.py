@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from distributed_sddmm_tpu.compat import shard_map
 
 from distributed_sddmm_tpu.common import MatMode, divide_round_up
 from distributed_sddmm_tpu.parallel.base import DistributedSparse
@@ -137,171 +136,6 @@ class SparseShift15D(DistributedSparse):
     # shard_map programs
     # ------------------------------------------------------------------ #
 
-    def _build_blocked_program(self, op: str, use_st: bool):
-        """Blocked (Pallas) variants: the chunk-list tile metadata ring-shifts
-        WITH the tile (`shiftCSR` analog — the blocked encoding is just more
-        arrays in the traveling struct-of-arrays), local compute runs through
-        the feature-major tile kernels."""
-        from distributed_sddmm_tpu.ops.blocked import CHUNK
-
-        tiles = self.ST_tiles if use_st else self.S_tiles
-        nr, c = self.nr, self.c
-        max_nnz = tiles.max_nnz
-        out_bw = tiles.tile_rows
-        kern = self.kernel
-        perm = ring_perm(nr)
-        unroll = self.unroll
-        overlap = self.overlap
-        bm, bn, grb, gcb, grp = tiles.blk_geom
-        rows_pad, cols_pad = grb * bm, gcb * bn
-        C = max_nnz // CHUNK
-        # Wire roles: the tile's index/mask/value arrays are read-only
-        # ring payloads (indices are int — the boundary cast skips
-        # them); the SDDMM dots accumulate IN FLIGHT, so they hop at
-        # the ring_accum dtype (f32 under the default bf16 policy).
-        w_ring = self.wire.dtype_for("ring")
-        w_ring_accum = self.wire.dtype_for("ring_accum")
-        w_gather = self.wire.dtype_for("gather")
-
-        def shift(tree, wire=w_ring):
-            if nr == 1:
-                return tree
-            return jax.tree.map(
-                lambda x: abl_ppermute(x, "rows", perm, wire=wire), tree
-            )
-
-        def shift_accum(tree):
-            return shift(tree, wire=w_ring_accum)
-
-        def replicate_stationary(blk):
-            if c > 1:
-                blk = abl_all_gather(blk, "cols", axis=1, tiled=True, size=c,
-                                     wire=w_gather)
-            return blk.reshape(blk.shape[0] * blk.shape[1] * blk.shape[2], blk.shape[3])
-
-        def dvary(x):
-            return vary(x, ("rows", "cols"))
-
-        def my_stripe(step):
-            i_idx = lax.axis_index("rows")
-            return jax.numpy.mod(i_idx - step, nr)
-
-        def squeeze_blk(blr, blc, bmeta):
-            return (
-                blr.reshape(C, CHUNK),
-                blc.reshape(C, CHUNK),
-                bmeta.reshape(C),
-            )
-
-        make_tile = self._blk_tile_factory(tiles)
-
-        def blk_of(fields):
-            blr, blc, bmeta = fields
-            return make_tile(blr, blc, bmeta)
-
-        BLK6 = P("rows", "cols", None, None, None, None)
-        mesh = self.grid.mesh
-
-        if op == "sddmm":
-
-            def prog(a_role, b_role, blr, blc, bmeta, t_mask, t_vals):
-                bt = kern.prep(replicate_stationary(b_role), cols_pad)
-                mov0 = (squeeze_blk(blr, blc, bmeta), t_mask.reshape(max_nnz))
-                acc0 = dvary(jnp.zeros((max_nnz,), t_mask.dtype))
-
-                def local(s, fields, mask, acc):
-                    stripe = lax.dynamic_index_in_dim(
-                        a_role, my_stripe(s), axis=0, keepdims=False
-                    ).reshape(out_bw, a_role.shape[-1])
-                    at = kern.prep(stripe, rows_pad)
-                    return acc + kern.sddmm_tile_t(
-                        blk_of(fields), mask, at, bt, mask.dtype
-                    )
-
-                if overlap:
-                    def body(s, acc, mov):
-                        fields, mask = mov
-                        return local(s, fields, mask, acc)
-
-                    acc, _ = ring_loop_overlap(
-                        nr, body, acc0, mov0, shift,
-                        shift_carry=shift_accum,
-                        final_shift=True, unroll=unroll,
-                    )
-                else:
-                    def body(s, state):
-                        (fields, mask), acc = state
-                        return ((fields, mask), local(s, fields, mask, acc))
-
-                    def shift_state(state):
-                        mov, acc = state
-                        return (shift(mov), shift_accum(acc))
-
-                    state = ring_loop(
-                        nr, body, (mov0, acc0), shift_state,
-                        shift_final=shift_state, unroll=unroll,
-                    )
-                    acc = state[1]
-                return (t_vals.reshape(max_nnz) * acc).reshape(1, 1, 1, 1, max_nnz)
-
-            in_specs = (
-                _DENSE_SPEC, _DENSE_SPEC, BLK6, BLK6,
-                _TILE_SPEC, _TILE_SPEC, _TILE_SPEC,
-            )
-            out_specs = _TILE_SPEC
-
-        elif op == "spmm":
-
-            def prog(stat, blr, blc, bmeta, t_vals):
-                bt = kern.prep(replicate_stationary(stat), cols_pad)
-                mov0 = (squeeze_blk(blr, blc, bmeta), t_vals.reshape(max_nnz))
-                out0 = dvary(
-                    jnp.zeros((nr, 1, out_bw, stat.shape[-1]), stat.dtype)
-                )
-
-                def local(s, fields, vals, out):
-                    partial = kern.spmm_tile_t(blk_of(fields), vals, bt)
-                    stripe = partial.T[:out_bw].astype(out.dtype)
-                    return lax.dynamic_update_index_in_dim(
-                        out, stripe[None, :, :], my_stripe(s), axis=0
-                    )
-
-                if overlap:
-                    def body(s, out, mov):
-                        fields, vals = mov
-                        return local(s, fields, vals, out)
-
-                    out, _ = ring_loop_overlap(
-                        nr, body, out0, mov0, shift, unroll=unroll
-                    )
-                    return out
-
-                def body(s, state):
-                    (fields, vals), out = state
-                    return ((fields, vals), local(s, fields, vals, out))
-
-                def shift_tile_only(state):
-                    mov, out = state
-                    return (shift(mov), out)
-
-                state = ring_loop(
-                    nr, body, (mov0, out0), shift_tile_only, unroll=unroll
-                )
-                return state[1]
-
-            in_specs = (_DENSE_SPEC, BLK6, BLK6, _TILE_SPEC, _TILE_SPEC)
-            out_specs = _DENSE_SPEC
-
-        else:
-            raise ValueError(op)
-
-        return jax.jit(
-            shard_map(
-                prog, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False,
-            )
-        )
-
     def _program_cache_key(self, op: str, use_st: bool) -> tuple:
         """Base key + the fusion build (see DenseShift15D)."""
         return (
@@ -310,27 +144,27 @@ class SparseShift15D(DistributedSparse):
         )
 
     def _program(self, op: str, use_st: bool):
+        """Build (and cache) the jitted shard_map program for one op
+        (``op`` in {"sddmm", "spmm"}). The tile's structure arrays —
+        flat indices or chunk lists, whichever the tile seam
+        (``_tile_ops``) takes — ring-shift WITH its values (the
+        `shiftCSR` analog)."""
         key = self._program_cache_key(op, use_st)
         if key in self._programs:
             return self._programs[key]
-        if self._use_blocked(self.ST_tiles if use_st else self.S_tiles):
-            fn = self._finalize_program(
-                key, self._build_blocked_program(op, use_st)
-            )
-            self._programs[key] = fn
-            return fn
 
         tiles = self.ST_tiles if use_st else self.S_tiles
+        form = self._tile_ops(tiles)
         nr, c = self.nr, self.c
         max_nnz = tiles.max_nnz
         out_bw = tiles.tile_rows  # output stripe height (A-role block width)
-        kern = self.kernel
         perm = ring_perm(nr)
         unroll = self.unroll
         overlap = self.overlap
-        # Wire roles (see the blocked builder): read-only tile arrays
-        # ride at the ring dtype, the in-flight SDDMM dot accumulator
-        # at ring_accum (f32 under the default bf16 policy).
+        # Wire roles: the tile's index/mask/value arrays are read-only
+        # ring payloads (indices are int — the boundary cast skips
+        # them); the SDDMM dots accumulate IN FLIGHT, so they hop at
+        # the ring_accum dtype (f32 under the default bf16 policy).
         w_ring = self.wire.dtype_for("ring")
         w_ring_accum = self.wire.dtype_for("ring_accum")
         w_gather = self.wire.dtype_for("gather")
@@ -359,140 +193,107 @@ class SparseShift15D(DistributedSparse):
             i_idx = lax.axis_index("rows")
             return jax.numpy.mod(i_idx - step, nr)
 
-        def squeeze_tile(t):
+        def squeeze(t):
             return t.reshape(max_nnz)
 
-        mesh = self.grid.mesh
+        def ring(local, carry, tile0, shift_carry=None, home=False):
+            """``carry = local(s, carry, tile)`` at each of the ``nr``
+            steps while the tile travels. A ``shift_carry`` carry depends
+            on the body, so it hops after it; ``home`` completes the
+            round trip."""
+            if overlap:
+                return ring_loop_overlap(
+                    nr, local, carry, tile0, shift,
+                    shift_carry=shift_carry, final_shift=home,
+                    unroll=unroll,
+                )[0]
+
+            def body(s, state):
+                tile, carry = state
+                return tile, local(s, carry, tile)
+
+            def shift_state(state):
+                tile, carry = state
+                if shift_carry is None:
+                    return shift(tile), carry
+                return shift(tile), shift_carry(carry)
+
+            return ring_loop(
+                nr, body, (tile0, carry), shift_state,
+                shift_final=shift_state if home else None, unroll=unroll,
+            )[1]
 
         if op == "sddmm":
             # Partial dots accumulate onto the traveling tile; one full ring
             # trip returns them to the owner with the complete R sum.
 
-            def prog(a_role, b_role, t_rows, t_cols, t_mask, t_vals):
+            def prog(a_role, b_role, *targs):
                 # a_role supplies the per-step output-side stripe; b_role is
                 # replicated across layers (reference Arole/Brole split,
                 # `15D_sparse_shift.hpp:176-199`).
-                b_rep = replicate_stationary(b_role)  # (rows_pad, r_loc)
-                fields = (
-                    squeeze_tile(t_rows),
-                    squeeze_tile(t_cols),
-                    squeeze_tile(t_mask),
-                )
-                acc0 = dvary(jnp.zeros((max_nnz,), t_mask.dtype))
+                *fields, mask, vals = targs
+                x_c = form.cols(replicate_stationary(b_role))
+                tile0 = (form.squeeze(fields), squeeze(mask))
+                acc0 = dvary(jnp.zeros((max_nnz,), mask.dtype))
 
-                def stripe_at(s):
-                    return lax.dynamic_index_in_dim(
+                def local(s, acc, tile):
+                    fields, mask = tile
+                    stripe = lax.dynamic_index_in_dim(
                         a_role, my_stripe(s), axis=0, keepdims=False
                     ).reshape(out_bw, a_role.shape[-1])
-
-                if overlap:
-                    # Index/mask arrays are body-independent: they
-                    # double-buffer. The accumulating dots depend on the
-                    # body, so they hop after it (shift_carry) — the one
-                    # leg of this traveling tile that cannot overlap —
-                    # and at the ring_accum wire dtype (a changing
-                    # partial sum must not be re-rounded per hop).
-                    def body(s, acc, fields):
-                        rows, cols, mask = fields
-                        return acc + kern.sddmm(
-                            rows, cols, mask, stripe_at(s), b_rep
-                        )
-
-                    acc, _ = ring_loop_overlap(
-                        nr, body, acc0, fields, shift,
-                        shift_carry=shift_accum,
-                        final_shift=True, unroll=unroll,
+                    return acc + form.sddmm(
+                        form.tile(*fields), mask, form.rows(stripe), x_c
                     )
-                else:
-                    def body(s, state):
-                        rows, cols, mask, acc = state
-                        acc = acc + kern.sddmm(
-                            rows, cols, mask, stripe_at(s), b_rep
-                        )
-                        return (rows, cols, mask, acc)
 
-                    def shift_state(state):
-                        rows, cols, mask, acc = state
-                        rows, cols, mask = shift((rows, cols, mask))
-                        return (rows, cols, mask, shift_accum(acc))
-
-                    # The accumulating dots travel WITH the tile; the
-                    # final shift completes their round trip home.
-                    state = ring_loop(
-                        nr, body, (*fields, acc0), shift_state,
-                        shift_final=shift_state, unroll=unroll,
-                    )
-                    acc = state[3]
-                return (squeeze_tile(t_vals) * acc).reshape(1, 1, 1, 1, max_nnz)
+                # Index/mask arrays are body-independent: under overlap
+                # they double-buffer. The accumulating dots depend on the
+                # body, so they hop after it — the one leg of this
+                # traveling tile that cannot overlap — and at the
+                # ring_accum wire dtype (a changing partial sum must not
+                # be re-rounded per hop); the final hop completes their
+                # round trip home.
+                acc = ring(local, acc0, tile0, shift_carry=shift_accum,
+                           home=True)
+                return (squeeze(vals) * acc).reshape(1, 1, 1, 1, max_nnz)
 
             in_specs = (
-                _DENSE_SPEC, _DENSE_SPEC,
-                _TILE_SPEC, _TILE_SPEC, _TILE_SPEC, _TILE_SPEC,
+                _DENSE_SPEC, _DENSE_SPEC, *form.specs(_TILE_SPEC),
+                _TILE_SPEC, _TILE_SPEC,
             )
             out_specs = _TILE_SPEC
 
         elif op == "spmm":
             # The tile (with its values) rotates; each step computes the
-            # output stripe matching the tile currently held.
+            # output stripe matching the tile currently held. The output
+            # stays put, so under overlap every hop double-buffers.
 
-            def prog(stat, t_rows, t_cols, t_vals):
-                stat_rep = replicate_stationary(stat)
-                fields = (
-                    squeeze_tile(t_rows),
-                    squeeze_tile(t_cols),
-                    squeeze_tile(t_vals),
-                )
+            def prog(stat, *targs):
+                *fields, vals = targs
+                x_c = form.cols(replicate_stationary(stat))
+                tile0 = (form.squeeze(fields), squeeze(vals))
                 out0 = dvary(
                     jnp.zeros((nr, 1, out_bw, stat.shape[-1]), stat.dtype)
                 )
 
-                if overlap:
-                    # The whole traveling tile is body-independent here
-                    # (the output stays put): every hop double-buffers.
-                    def body(s, out, fields):
-                        rows, cols, vals = fields
-                        stripe = kern.spmm(rows, cols, vals, stat_rep, out_bw)
-                        return lax.dynamic_update_index_in_dim(
-                            out, stripe[None, :, :].astype(out.dtype),
-                            my_stripe(s), axis=0,
-                        )
-
-                    out, _ = ring_loop_overlap(
-                        nr, body, out0, fields, shift, unroll=unroll
+                def local(s, out, tile):
+                    fields, vals = tile
+                    stripe = form.finish(
+                        form.spmm(form.tile(*fields), vals, x_c), out
                     )
-                    return out
-
-                def body(s, state):
-                    rows, cols, vals, out = state
-                    stripe = kern.spmm(rows, cols, vals, stat_rep, out_bw)
-                    out = lax.dynamic_update_index_in_dim(
-                        out, stripe[None, :, :].astype(out.dtype), my_stripe(s), axis=0
+                    return lax.dynamic_update_index_in_dim(
+                        out, stripe[None, :, :].astype(out.dtype),
+                        my_stripe(s), axis=0,
                     )
-                    return (rows, cols, vals, out)
 
-                def shift_tile_only(state):
-                    rows, cols, vals, out = state
-                    rows, cols, vals = shift((rows, cols, vals))
-                    return (rows, cols, vals, out)
+                return ring(local, out0, tile0)
 
-                state = ring_loop(
-                    nr, body, (*fields, out0), shift_tile_only, unroll=unroll
-                )
-                return state[3]
-
-            in_specs = (_DENSE_SPEC, _TILE_SPEC, _TILE_SPEC, _TILE_SPEC)
+            in_specs = (_DENSE_SPEC, *form.specs(_TILE_SPEC), _TILE_SPEC)
             out_specs = _DENSE_SPEC
 
         else:
             raise ValueError(op)
 
-        fn = self._finalize_program(
-            key,
-            jax.jit(shard_map(prog, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)),
-        )
-        self._programs[key] = fn
-        return fn
+        return self._shard_program(key, form, prog, in_specs, out_specs)
 
     # ------------------------------------------------------------------ #
     # Public ops
@@ -501,19 +302,23 @@ class SparseShift15D(DistributedSparse):
     def sddmm_a(self, A, B, s_vals):
         t = self.S_tiles
         prog = self._program("sddmm", use_st=False)
-        return self._timed("sddmmA", prog, A, B, *self._sddmm_args(t, s_vals))
+        return self._timed(
+            "sddmmA", prog, A, B, *self._tile_args(t, s_vals, mask=True)
+        )
 
     def sddmm_b(self, A, B, st_vals):
         t = self.ST_tiles
         prog = self._program("sddmm", use_st=True)
-        return self._timed("sddmmB", prog, B, A, *self._sddmm_args(t, st_vals))
+        return self._timed(
+            "sddmmB", prog, B, A, *self._tile_args(t, st_vals, mask=True)
+        )
 
     def spmm_a(self, A, B, s_vals):
         t = self.S_tiles
         prog = self._program("spmm", use_st=False)
-        return self._timed("spmmA", prog, B, *self._spmm_args(t, s_vals))
+        return self._timed("spmmA", prog, B, *self._tile_args(t, s_vals))
 
     def spmm_b(self, A, B, st_vals):
         t = self.ST_tiles
         prog = self._program("spmm", use_st=True)
-        return self._timed("spmmB", prog, A, *self._spmm_args(t, st_vals))
+        return self._timed("spmmB", prog, A, *self._tile_args(t, st_vals))

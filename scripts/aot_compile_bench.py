@@ -48,7 +48,7 @@ def main() -> int:
 
     # Compile exactly what the on-device worker would run: get_kernel
     # ("auto") resolves to the bf16 Mosaic kernel on TPU; BENCH_KERNEL=xla
-    # gets the flat XLA program.
+    # runs the ring on the flat XLA form of the tile seam.
     if os.environ.get("BENCH_KERNEL", "auto") == "xla":
         kernel = XlaKernel()
     else:

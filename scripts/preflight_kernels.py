@@ -176,8 +176,8 @@ def compile_rsplit(cfg: dict) -> dict:
            "strategy": "15d_sparse", "kernel": "pallas-bf16 blocked",
            "topology": TOPOLOGY}
     for op, call_args in (
-        ("sddmm", (A, B, *alg._sddmm_args(alg.S_tiles, vals))),
-        ("spmm", (B, *alg._spmm_args(alg.S_tiles, vals))),
+        ("sddmm", (A, B, *alg._tile_args(alg.S_tiles, vals, mask=True))),
+        ("spmm", (B, *alg._tile_args(alg.S_tiles, vals))),
     ):
         t0 = time.monotonic()
         prog = alg._program(op, False)

@@ -9,8 +9,10 @@ device memory) fails here and costs no chip time. The strategy is built on
 the host CPU and retargeted at one described device; its tile arrays enter
 the compiled programs as arguments, as the models pass them.
 
-The last two run chip_smoke.py's phases at logM=8 on the CPU mesh, and
-check that it refuses to report anything without a TPU.
+Then each strategy's SDDMM and SpMM rings compile on four described
+chips at a small size, and each kernel's launch compiles alone. The last
+two run chip_smoke.py's phases at logM=8 on the CPU mesh, and check that
+it refuses to report anything without a TPU.
 """
 
 import json
@@ -188,6 +190,62 @@ def test_compiled_launch_is_named_by_kind(one_chip_mesh, op):
                        r"\"tpu_custom_call\"", text)
     assert calls and all(c.split(".")[0] == pk.kernel_name(op)
                          for c in calls), calls
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The described v5e's four devices, JAX's persistent cache off
+    around their compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield list(topo.devices)[:4]
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("name,c", [
+    ("15d_fusion1", 2),
+    ("15d_sparse", 1),
+    ("25d_dense_replicate", 1),
+    ("25d_sparse_replicate", 1),
+])
+def test_strategy_ring_compiles_for_v5e(four_chips, name, c):
+    """Each strategy's SDDMM and SpMM ring programs, on the blocked
+    bf16 Mosaic kernel and four described v5e chips at a small size:
+    every launch in the compiled program is named by its kernel. The
+    CPU suite reaches these rings' blocked form in interpret mode only."""
+    import re
+
+    from distributed_sddmm_tpu.bench.harness import make_algorithm
+    from distributed_sddmm_tpu.ops.pallas_kernels import kernel_name
+    from distributed_sddmm_tpu.utils.coo import HostCOO
+
+    S = HostCOO.erdos_renyi(512, 512, 8, seed=5, values="normal")
+    alg = make_algorithm(
+        name, S, 512, c, devices=jax.devices()[:4],
+        kernel=PallasKernel(precision="bf16", interpret=False),
+    )
+    assert alg._use_blocked(alg.S_tiles) and alg._use_blocked(alg.ST_tiles)
+    A = alg.dummy_initialize(MatMode.A)
+    B = alg.dummy_initialize(MatMode.B)
+    vals = alg.like_s_values(1.0)
+    g = alg.grid
+    alg.grid = make_grid(g.nr, g.nc, g.nh, adjacency=g.adjacency,
+                         devices=four_chips)
+    alg._programs.clear()
+    # The public ops hand their program and operands to _timed; keep them
+    # instead of running.
+    alg._timed = lambda op_name, prog, *args, **kw: (prog, args)
+    for op, call in (("sddmm", alg.sddmm_a), ("spmm", alg.spmm_a)):
+        prog, args = call(A, B, vals)
+        text = prog.lower(*_sds(alg, args)).compile().as_text()
+        calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                           r"\"tpu_custom_call\"", text)
+        assert calls and all(k.split(".")[0] == kernel_name(op)
+                             for k in calls), (op, calls)
 
 
 def test_smoke_phases_on_cpu_mesh(tmp_path, monkeypatch):

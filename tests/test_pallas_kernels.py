@@ -392,8 +392,10 @@ class TestPallasTileKernels:
 
 
 class TestPallasDistributed:
-    """XLA and Pallas kernels must produce identical fingerprints through
-    the full distributed 1.5D dense-shift programs."""
+    """The two forms of the tile seam (``FlatTiles`` on the XLA kernel,
+    ``BlockedTiles`` on the Pallas kernel) must produce identical
+    fingerprints through the one 1.5D dense-shift ring program each op
+    builds."""
 
     # Off-diagonal (c, fusion) combos are slow-marked: the c axis and
     # the fusion axis each keep a fast representative on the diagonal,
@@ -433,8 +435,9 @@ class TestPallasDistributed:
 
 class TestPallasAllAlgorithms:
     """Every strategy (including the tile-rotating and fiber-replicated
-    ones) runs its ops through the blocked Pallas kernels with fingerprints
-    identical to the XLA path — the scratch.cpp protocol across kernels."""
+    ones) runs its one ring program per op through both forms of the tile
+    seam, flat XLA and blocked Pallas, with identical fingerprints — the
+    scratch.cpp protocol across kernels."""
 
     # The (c=2, p=8) rows are slow-marked: each algorithm keeps its
     # fast pallas-vs-xla identity representative at (1, 4), and
