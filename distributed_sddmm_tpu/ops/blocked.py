@@ -56,9 +56,11 @@ DEFAULT_BLOCK_COLS = (int(os.environ["DSDDMM_BLOCK_COLS"])
 
 # Scatter contraction form ("bt"/"nt") and step batching for the Pallas
 # kernels (consumed by ops/pallas_kernels.PallasKernel.__init__); defined
-# here so every knob default lives in one module.
+# here so every knob default lives in one module. Step batching is on:
+# each grid step gathers the stationary side and scatters once for its G
+# chunks (PERF.md section 5 has its chip cost).
 DEFAULT_SCATTER_FORM = os.environ.get("DSDDMM_SCATTER_FORM", "bt")
-DEFAULT_BATCH_STEP = os.environ.get("DSDDMM_BATCH_STEP", "0") not in ("", "0")
+DEFAULT_BATCH_STEP = os.environ.get("DSDDMM_BATCH_STEP", "1") not in ("", "0")
 
 
 def knob_env_defaults() -> dict:
@@ -105,37 +107,51 @@ TILE_VMEM_LIMIT = 32 << 20
 
 
 def tile_vmem_bytes(bm: int, bn: int, R: int, group: int,
-                    itemsize: int = 2) -> int:
+                    itemsize: int = 2, batch_step: bool = True) -> int:
     """VMEM a fused tile kernel holds at one geometry: the double-buffered
     ``at`` window and ``group`` ``bt`` windows (MXU operands of
     ``itemsize`` bytes), the double-buffered float32 output window, its
-    accumulator, and the gather one-hots with their int32 compares. The
-    windows are what Mosaic allocates on the kernel's stack (a v5e
-    compile of fused 2048x2048 at R=256, group 4, asks for 16.02 MiB);
-    SDDMM and SpMM hold a subset."""
+    accumulator, and the gather one-hots. The step-batched body holds the
+    stationary side's ``[bm, group*CHUNK]`` one-hot with its compare
+    mask, one moving-side ``[bn, CHUNK]`` one-hot at a time, and the
+    step's gathered float32 rows of both sides (``[R, group*CHUNK]``
+    each); the per-chunk body a ``[bm, CHUNK]`` and a ``[bn, CHUNK]``
+    one-hot with their int32 compares. Each count bounds the scoped VMEM
+    that v5e compiles of that body asked for (fused, R=128 and 256, group
+    4 and 8, 512 to 4096 on either axis; PERF.md section 5); SDDMM and
+    SpMM hold a subset."""
     windows = 2 * R * bm * itemsize + 2 * group * R * bn * itemsize
     out = 2 * R * bm * 4 + R * bm * 4
-    one_hots = (bm + bn) * CHUNK * (itemsize + 4)
+    if batch_step:
+        one_hots = ((group * bm + bn) * CHUNK * (itemsize + 1)
+                    + 2 * R * group * CHUNK * 4)
+    else:
+        one_hots = (bm + bn) * CHUNK * (itemsize + 4)
     return windows + out + one_hots
 
 
 # The density rule. A chunk costs the same whatever its fill: three
-# one-hot matmuls of fixed extent ([R,bm]@[bm,128], [R,bn]@[bn,128],
-# [R,128]@[128,bm]) and a bt-window DMA. Blocks sized to the density
-# therefore trade dearer chunks for fewer, fuller ones. The cost below is
-# the fused kernel's (ALS and the pair run it; SDDMM and SpMM run a
-# subset of its work): t = t0 + k * R * (2*bm + bn), fitted to
-# scripts/tune_blocks.py on a v5e at group 4 (logM 18, nnz/row 32, R=128
-# at seven geometries from 512x512 to 2048x2048 and R=256 at 512x512,
-# within 4% of each; PERF.md section 5).
+# one-hot matmuls of fixed extent (the stationary gather [R,bm]@[bm,128]
+# and scatter [R,128]@[128,bm], the moving gather [R,bn]@[bn,128]) and a
+# bt-window DMA. Blocks sized to the density therefore trade dearer
+# chunks for fewer, fuller ones. The cost below is the step-batched fused
+# kernel's (ALS and the pair run it; SDDMM and SpMM run a subset of its
+# work): t = t0 + R * (k_m*bm + k_n*bn), the stationary (bm) side batched
+# over a grid step's chunks, the moving (bn) side chunk by chunk, fitted
+# to scripts/tune_blocks.py on a v5e at group 4 (logM 18, nnz/row 32,
+# R=128 at 512x512, 1024x1024, 1024x2048, 2048x1024, 2048x2048 and
+# 4096x1024, R=256 at 512x1024 and 1024x1024; within 4% of each; PERF.md
+# section 5).
 #: Block sizes the rule weighs on each axis (pick_block clamps them).
 RULE_BLOCKS = (256, 512, 1024, 2048, 4096)
-_CHUNK_FIXED_NS = 269.1    # t0: per chunk, whatever the geometry
-_CHUNK_MXU_NS = 1.432e-3   # k: per unit of R * (2*bm + bn)
+_CHUNK_FIXED_NS = 137.86     # t0: per chunk, whatever the geometry
+_CHUNK_ROW_NS = 2.6303e-3    # k_m: per unit of R * bm (gather and scatter)
+_CHUNK_COL_NS = 1.4577e-3    # k_n: per unit of R * bn (moving gather)
 _HBM_BYTES_PER_NS = 819.0  # v5e HBM bandwidth, for the bt-window DMA
 #: A geometry replaces BASE_BLOCK only where it models at least this much
 #: cheaper: the fit misses single geometries by up to 4%, and 256-row
-#: blocks run 15% slower than it says.
+#: blocks ran 15% slower than the per-chunk body's fit said (not
+#: measured step-batched).
 _RULE_MARGIN = 0.9
 #: Largest (bucket, row block, col block) grid at the smallest candidate
 #: the rule counts; a larger tile set keeps BASE_BLOCK.
@@ -145,7 +161,7 @@ _RULE_MAX_CELLS = 1 << 25
 def chunk_time_ns(bm: int, bn: int, R: int) -> float:
     """Modeled time of one fused-kernel chunk: the larger of its one-hot
     MXU work and its bt-window DMA (bfloat16 operands)."""
-    mxu = _CHUNK_FIXED_NS + _CHUNK_MXU_NS * R * (2 * bm + bn)
+    mxu = _CHUNK_FIXED_NS + R * (_CHUNK_ROW_NS * bm + _CHUNK_COL_NS * bn)
     return max(mxu, R * bn * 2 / _HBM_BYTES_PER_NS)
 
 
@@ -207,9 +223,10 @@ def choose_blocks(n_buckets, bucket, local_r, local_c, tile_rows, tile_cols,
                   R: int, group: int = 1) -> tuple:
     """``(bm, bn)`` for a tile set: the geometry of least modeled kernel
     time (exact chunk count × :func:`chunk_time_ns`) among those whose
-    :func:`tile_vmem_bytes` at ``R`` fits :data:`TILE_VMEM_LIMIT`, ties
-    to the smaller footprint. BASE_BLOCK square stays where the best is
-    not ``_RULE_MARGIN`` of its time, or where there is nothing to count."""
+    step-batched :func:`tile_vmem_bytes` at ``R`` fits
+    :data:`TILE_VMEM_LIMIT`, ties to the smaller footprint. BASE_BLOCK
+    square stays where the best is not ``_RULE_MARGIN`` of its time, or
+    where there is nothing to count."""
     base = (pick_block(tile_rows, BASE_BLOCK), pick_block(tile_cols, BASE_BLOCK))
     chunks = block_chunk_counts(n_buckets, bucket, local_r, local_c,
                                 tile_rows, tile_cols, group)

@@ -644,7 +644,8 @@ def _tile_call(
         raise ValueError(op)
 
     params = {"dimension_semantics": ("arbitrary",)}
-    vmem = blocked.tile_vmem_bytes(bm, bn, R, G, bt.dtype.itemsize)
+    vmem = blocked.tile_vmem_bytes(bm, bn, R, G, bt.dtype.itemsize,
+                                   batch_step or single_step)
     if vmem > blocked.DEFAULT_SCOPED_VMEM:
         params["vmem_limit_bytes"] = max(blocked.TILE_VMEM_LIMIT, vmem)
 
@@ -856,7 +857,8 @@ class PallasKernel:
     ``batch_step``: batch the stationary-side gather and the scatter across
     a grid step's G sub-chunks into single [.., G*CHUNK]-wide MXU ops
     (legal because group alignment pins a step inside one row-block
-    window); identical numerics. Env default: ``DSDDMM_BATCH_STEP``.
+    window); identical numerics. Env default: ``DSDDMM_BATCH_STEP``, on
+    unless set to 0.
     """
 
     is_blocked = True

@@ -520,9 +520,10 @@ class DistributedSparse(abc.ABC):
     def _note_tile_metrics(self) -> None:
         """Record each tile set's counted padded-lane fraction, its block
         geometry (``block_rows`` x ``block_cols``, in the encoding's own
-        orientation) and its chunks a (device, tile) bucket as per-op
-        metric gauges (scraped via ``/metrics`` and landed in bench
-        records): the waste the density rule and the codegen banked
+        orientation), its chunks a (device, tile) bucket, and whether its
+        launches run the step-batched kernel body (``batched_step``, 1 or
+        0) as per-op metric gauges (scraped via ``/metrics`` and landed in
+        bench records): the waste the density rule and the codegen banked
         variants exist to shrink, and what they chose. Called by strategy
         constructors once tiles exist."""
         a_side = ("sddmmA", "spmmA", "fusedSpMM", "cgStep", "gatLayer")
@@ -532,10 +533,16 @@ class DistributedSparse(abc.ABC):
             if frac is None:
                 continue
             bm, bn = tiles.blk_geom[:2]
+            bands = getattr(tiles, "blk_bands", None)
+            if bands:
+                batched = all(b.body in ("batched", "single") for b in bands)
+            else:
+                batched = getattr(self.kernel, "batch_step", False)
             for op in ops:
                 self.metrics.note(
                     op, padded_lane_frac=round(frac, 6), block_rows=bm,
                     block_cols=bn, n_chunks=int(tiles.blk_meta.shape[-1]),
+                    batched_step=int(batched),
                 )
 
     def initial_shift(self, A, B, mode: KernelMode):

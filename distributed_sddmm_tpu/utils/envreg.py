@@ -54,7 +54,7 @@ KNOBS: dict[str, Knob] = {k.name: k for k in [
     _K("DSDDMM_ATTN_STREAM_BUDGET", "int", "16777216",
        "element budget past which the masked-softmax row stats switch "
        "to the streaming max/denominator scan (ops/kernels.py)"),
-    _K("DSDDMM_BATCH_STEP", "flag", "0",
+    _K("DSDDMM_BATCH_STEP", "flag", "1",
        "batch grid steps in the blocked Pallas kernels (README: step "
        "batching)"),
     _K("DSDDMM_BLOCK_COLS", "int", "auto",

@@ -53,14 +53,14 @@ def main() -> int:
 
         S, A, B, _flops = tune.build_inputs(log_m, npr, R)
         kern = XlaKernel()
-        rows, cols, vals = tune.xla_operands(S)
-        steps = tune.xla_steps(kern, rows, cols, vals, S, A)
+        steps = tune.xla_steps(kern, S)
+        state = tune.xla_state(*tune.xla_operands(S), A, B)
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name=TOPOLOGY)
         report = {"ok": True, "kernel": "xla", "compile_s": {}}
         for name, step in steps.items():
             report["compile_s"][name] = aot.compile_chain_pair(
-                step, (B, vals), trials, topo.devices[0], out_dir, name)
+                step, state, trials, topo.devices[0], out_dir, name)
         (out_dir / "meta.json").write_text(json.dumps(report, indent=1))
         print(json.dumps(report))
         return 0
@@ -86,7 +86,8 @@ def main() -> int:
                          scatter_form=tune.SCATTER_FORM,
                          batch_step=tune.BATCH_STEP)
 
-    steps = tune.pallas_steps(kernp, blk, cvals, S, A)
+    steps = tune.pallas_steps(kernp, S)
+    state = tune.pallas_state(blk, cvals, A, B)
 
     topo = topologies.get_topology_desc(platform="tpu", topology_name=TOPOLOGY)
     dev = topo.devices[0]
@@ -98,7 +99,7 @@ def main() -> int:
         "chunk": tune.CHUNK}, "compile_s": {}}
     for op in ops:
         times = aot.compile_chain_pair(
-            steps[op], (B, cvals), trials, dev, out_dir, op)
+            steps[op], state, trials, dev, out_dir, op)
         report["compile_s"][op] = times
     (out_dir / "meta.json").write_text(json.dumps(report, indent=1))
     print(json.dumps(report))

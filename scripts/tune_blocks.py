@@ -85,43 +85,62 @@ def xla_operands(S):
             jnp.array(S.vals.astype(np.float32)))
 
 
-def xla_steps(kern, rows, cols, vals, S, A) -> dict:
+def xla_steps(kern, S) -> dict:
     """The XLA-kernel chained-trial step functions (shared with the
-    offline AOT compiler). The moving operand B rides the chained state."""
+    offline AOT compiler). Every array rides the chained state
+    (:func:`xla_state`), so it enters the timed program as an argument:
+    a closed-over array would be baked into each compile as a constant.
+    The moving operand B is the state's first leaf."""
 
     def sddmm_step(state):
-        Bs, v = state
+        Bs, v, A, rows, cols = state
         out = kern.sddmm(rows, cols, v, A, Bs)
-        return (Bs + out.sum() * 1e-30, v)
+        return (Bs + out.sum() * 1e-30, v, A, rows, cols)
 
     def spmm_step(state):
-        Bs, _ = state
-        return (Bs + kern.spmm(rows, cols, vals, Bs, S.M)[: S.N] * 1e-12, _)
+        Bs, v, A, rows, cols = state
+        Bs = Bs + kern.spmm(rows, cols, v, Bs, S.M)[: S.N] * 1e-12
+        return (Bs, v, A, rows, cols)
 
     return {"xla_sddmm": sddmm_step, "xla_spmm": spmm_step}
 
 
-def pallas_steps(kernp, blk, cvals, S, A) -> dict:
+def xla_state(rows, cols, vals, A, B) -> tuple:
+    """The XLA chains' initial state: ``(B, vals, A, rows, cols)``."""
+    return (B, vals, A, rows, cols)
+
+
+def pallas_steps(kernp, S) -> dict:
     """The three chained-trial step functions (shared with the offline AOT
     compiler so the serialized programs are byte-identical in structure).
-    The moving operand B is not closed over — it arrives via the chained
-    state."""
+    The state (:func:`pallas_state`) carries every array, the tile's chunk
+    arrays included, so each enters the timed program as an argument (a
+    closed-over array would be baked in as a constant: about 300 MB a
+    compile at logM 18). The moving operand B is the state's first leaf."""
 
     def fused_step(state):
-        Bs, _ = state
-        o, _mid = kernp.fused_tile(blk, cvals, A, Bs)
-        return (Bs + o[: S.N] * 1e-12, _)
+        Bs, v, A, blk = state
+        o, _mid = kernp.fused_tile(blk, v, A, Bs)
+        return (Bs + o[: S.N] * 1e-12, v, A, blk)
 
     def sddmm_step(state):
-        Bs, v = state
+        Bs, v, A, blk = state
         out = kernp.sddmm_tile(blk, v, A, Bs)
-        return (Bs + out.sum() * 1e-30, v)
+        return (Bs + out.sum() * 1e-30, v, A, blk)
 
     def spmm_step(state):
-        Bs, _ = state
-        return (Bs + kernp.spmm_tile(blk, cvals, Bs, S.M)[: S.N] * 1e-12, _)
+        Bs, v, A, blk = state
+        return (Bs + kernp.spmm_tile(blk, v, Bs, S.M)[: S.N] * 1e-12, v, A,
+                blk)
 
     return {"fused": fused_step, "sddmm": sddmm_step, "spmm": spmm_step}
+
+
+def pallas_state(blk, cvals, A, B) -> tuple:
+    """The Pallas chains' initial state: ``(B, cvals, A, blk)`` (``blk``
+    is a pytree whose chunk arrays are leaves and whose geometry is
+    static)."""
+    return (B, cvals, A, blk)
 
 
 def clamp_tombstone(log_m: int, npr: int, R: int, meta,
@@ -170,13 +189,13 @@ def main():
 
     if not SKIP_XLA:
         kern = XlaKernel()
-        rows, cols, vals = xla_operands(S)
-        steps = xla_steps(kern, rows, cols, vals, S, A)
+        steps = xla_steps(kern, S)
+        state = xla_state(*xla_operands(S), A, B)
 
         t_sddmm, aot_s = _timed_op("xla_sddmm", steps["xla_sddmm"],
-                                   (B, vals), trials)
+                                   state, trials)
         t_spmm, aot_m = _timed_op("xla_spmm", steps["xla_spmm"],
-                                  (B, vals), trials)
+                                  state, trials)
         rec = {"kernel": "xla", "logM": log_m, "npr": npr, "R": R,
                "aot": aot_s and aot_m,
                "sddmm_ms": t_sddmm * 1e3, "spmm_ms": t_spmm * 1e3,
@@ -194,13 +213,14 @@ def main():
                 clamp_tombstone(log_m, npr, R, meta, bm_pref, bn_pref)
             ), flush=True)
             continue
-        steps = pallas_steps(kernp, blk, cvals, S, A)
+        steps = pallas_steps(kernp, S)
+        state = pallas_state(blk, cvals, A, B)
 
-        t_f, used_aot = _timed_op("fused", steps["fused"], (B, cvals), trials)
+        t_f, used_aot = _timed_op("fused", steps["fused"], state, trials)
         t_s = t_m = None
         if not FUSED_ONLY:
-            t_s, aot_s = _timed_op("sddmm", steps["sddmm"], (B, cvals), trials)
-            t_m, aot_m = _timed_op("spmm", steps["spmm"], (B, cvals), trials)
+            t_s, aot_s = _timed_op("sddmm", steps["sddmm"], state, trials)
+            t_m, aot_m = _timed_op("spmm", steps["spmm"], state, trials)
             used_aot = used_aot and aot_s and aot_m
         occ = float((~meta.pad_lane).mean())
         rec = {"kernel": "pallas-bf16", "logM": log_m, "npr": npr, "R": R,

@@ -82,10 +82,21 @@ def test_tune_blocks_setup_shapes():
     from distributed_sddmm_tpu.ops.pallas_kernels import PallasKernel
 
     kernp = PallasKernel(precision="f32", interpret=True)
-    steps = tune.pallas_steps(kernp, blk, cvals, S, A)
+    steps = tune.pallas_steps(kernp, S)
     assert set(steps) == {"fused", "sddmm", "spmm"}
-    out = steps["fused"]((B, cvals))
-    assert out[0].shape == B.shape
+    state = tune.pallas_state(blk, cvals, A, B)
+    for step in steps.values():
+        out = step(state)
+        assert out[0].shape == B.shape
+        # Every other leaf passes through unchanged, so the timed chain
+        # takes the operands and the chunk arrays as arguments.
+        assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(out[1:]),
+                                          jax.tree_util.tree_leaves(state[1:])))
+    # A chained program's constants hold no operand: every array is a
+    # parameter of the lowered chain.
+    lowered = aot._chain(steps["fused"], 2).lower(state)
+    assert (len(jax.tree_util.tree_leaves(lowered.in_avals))
+            == len(jax.tree_util.tree_leaves(state)) == 6)
 
 
 def test_inject_program_roundtrip(tmp_path):

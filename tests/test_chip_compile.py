@@ -192,6 +192,42 @@ def test_compiled_launch_is_named_by_kind(one_chip_mesh, op):
                          for c in calls), calls
 
 
+@pytest.mark.parametrize("bm,bn,R", [
+    (1024, 1024, 128), (1024, 2048, 128), (512, 1024, 256),
+    (4096, 1024, 128),
+])
+def test_batched_launch_fits_its_count(one_chip_mesh, monkeypatch, bm, bn,
+                                       R):
+    """The step-batched fused launch compiles with its scoped VMEM limit
+    set to ``tile_vmem_bytes``, at the cells' geometries and at one whose
+    per-chunk count stays under Mosaic's 16 MiB default while the batched
+    body needs more."""
+    import jax.numpy as jnp
+
+    from distributed_sddmm_tpu.ops import blocked
+    from distributed_sddmm_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(blocked, "DEFAULT_SCOPED_VMEM", 0)
+    monkeypatch.setattr(blocked, "TILE_VMEM_LIMIT", 0)
+    pk._tile_call.clear_cache()
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(one_chip_mesh, P()))
+
+    C, G = 16, 4
+    lr, sv = sds((C, pk.CHUNK), jnp.int32), sds((C, pk.CHUNK), jnp.float32)
+    geom = (bm, bn, 2, 2, G, False, "bt", True, False)
+    fn = jax.jit(lambda meta, lr, lc, sv, at, bt: pk._geom_call(
+        geom, "fused", meta, lr, lc, sv, at, bt))
+    try:
+        fn.lower(sds((C,), jnp.int32), lr, lr, sv,
+                 sds((R, 2 * bm), jnp.bfloat16),
+                 sds((R, 2 * bn), jnp.bfloat16)).compile()
+    finally:
+        pk._tile_call.clear_cache()
+
+
 @pytest.fixture(scope="module")
 def four_chips(topo):
     """The described v5e's four devices, JAX's persistent cache off

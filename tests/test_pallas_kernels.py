@@ -211,6 +211,47 @@ class TestDensityRule:
         bm, bn = choose_blocks(1, bucket, rows, cols, n, n, R=R, group=4)
         assert tile_vmem_bytes(bm, bn, R, 4) <= TILE_VMEM_LIMIT
 
+    def test_batched_one_hot_grows_with_group(self):
+        """The step-batched body holds the stationary side's one-hot for
+        the whole step: its count beyond the per-chunk body's grows with
+        G (and is all that differs at equal windows)."""
+        from distributed_sddmm_tpu.ops.blocked import tile_vmem_bytes
+
+        for bm, bn, R in ((1024, 1024, 128), (512, 1024, 256)):
+            extra = [tile_vmem_bytes(bm, bn, R, g)
+                     - tile_vmem_bytes(bm, bn, R, g, batch_step=False)
+                     for g in (1, 2, 4, 8)]
+            assert all(b - a >= bm * CHUNK * 3
+                       for a, b in zip(extra, extra[1:])), extra
+            assert extra[2] > 0
+
+    def test_batched_footprint_rejects_geometry(self, monkeypatch):
+        """Under a limit that the per-chunk footprint of the rule's pick
+        fits and its step-batched footprint does not, the rule passes
+        that geometry over for one whose batched footprint fits."""
+        from distributed_sddmm_tpu.ops import blocked
+
+        bucket, rows, cols, n = _uniform_tiles(8)
+        batched = blocked.tile_vmem_bytes
+
+        def per_chunk(*a, **k):
+            return batched(*a, **{**k, "batch_step": False})
+
+        monkeypatch.setattr(blocked, "tile_vmem_bytes", per_chunk)
+        pick = blocked.choose_blocks(1, bucket, rows, cols, n, n, R=128,
+                                     group=4)
+        assert pick != (512, 512)
+        monkeypatch.setattr(blocked, "TILE_VMEM_LIMIT",
+                            per_chunk(*pick, 128, 4))
+        assert batched(*pick, 128, 4) > blocked.TILE_VMEM_LIMIT
+        assert blocked.choose_blocks(1, bucket, rows, cols, n, n, R=128,
+                                     group=4) == pick
+        monkeypatch.setattr(blocked, "tile_vmem_bytes", batched)
+        got = blocked.choose_blocks(1, bucket, rows, cols, n, n, R=128,
+                                    group=4)
+        assert got != pick
+        assert batched(*got, 128, 4) <= blocked.TILE_VMEM_LIMIT
+
     def test_explicit_geometry_wins(self, monkeypatch):
         """A caller's blocks, pinned module defaults (the env knobs, an
         autotune plan) and an unknown R all bypass the rule; pinned at
@@ -243,7 +284,8 @@ class TestDensityRule:
 
     def test_strategy_notes_its_geometry(self):
         """A blocked strategy builds with its R, and its metrics carry each
-        tile set's geometry and chunk count beside the padded fraction."""
+        tile set's geometry and chunk count beside the padded fraction,
+        and whether its launches run the step-batched body (the default)."""
         S = HostCOO.erdos_renyi(260, 220, 5, seed=3, values="normal")
         alg = DenseShift15D(S, R=16, kernel=PallasKernel(precision="f32",
                                                          interpret=True))
@@ -254,16 +296,24 @@ class TestDensityRule:
             assert gauges[op]["block_cols"] == bn
             assert gauges[op]["n_chunks"] == tiles.blk_meta.shape[-1]
             assert 0.0 <= gauges[op]["padded_lane_frac"] < 1.0
+            assert gauges[op]["batched_step"] == 1
+        per_chunk = DenseShift15D(S, R=16, kernel=PallasKernel(
+            precision="f32", interpret=True, batch_step=False))
+        gauges = per_chunk.metrics.gauges()
+        assert gauges["fusedSpMM"]["batched_step"] == 0
+        assert gauges["spmmB"]["batched_step"] == 0
 
 
 def _oracle_case(precision, tol, group, form, batch, blocks=None,
-                 slow=False):
+                 slow=False, R=32):
     """One row of the oracle test, its id the five kernel knobs (and the
-    geometry where one is pinned)."""
+    geometry where one is pinned, and R where it is not 32)."""
     case_id = "-".join(map(str, (precision, tol, group, form, batch)))
     if blocks:
         case_id += f"-{blocks[0]}x{blocks[1]}"
-    return pytest.param(precision, tol, group, form, batch, blocks,
+    if R != 32:
+        case_id += f"-R{R}"
+    return pytest.param(precision, tol, group, form, batch, blocks, R,
                         id=case_id,
                         marks=[pytest.mark.slow] if slow else [])
 
@@ -276,8 +326,10 @@ class TestPallasTileKernels:
     # The rectangular geometries are ones the density rule
     # (ops/blocked.choose_blocks) picks for sparse tile sets; their frame
     # is wide enough that pick_block keeps them.
+    # The step-batched bf16 rows at pinned geometries are the cells':
+    # GAT's 512x1024 (at R=256 too) and the four-chip pair's 1024x2048.
     @pytest.mark.parametrize(
-        "precision,tol,group,form,batch,blocks",
+        "precision,tol,group,form,batch,blocks,R",
         [
             _oracle_case("f32", 1e-5, 1, "bt", False),
             _oracle_case("bf16", 3e-2, 1, "bt", False),
@@ -291,11 +343,14 @@ class TestPallasTileKernels:
             _oracle_case("bf16", 3e-2, 4, "bt", True),
             _oracle_case("f32", 1e-5, 4, "bt", False, (512, 1024)),
             _oracle_case("bf16", 3e-2, 4, "bt", False, (1024, 2048)),
+            _oracle_case("bf16", 3e-2, 4, "bt", True, (512, 1024)),
+            _oracle_case("bf16", 3e-2, 4, "bt", True, (1024, 2048)),
+            _oracle_case("bf16", 3e-2, 4, "bt", True, (512, 1024), R=256),
         ],
     )
     def test_against_oracle(self, precision, tol, group, form, batch,
-                            blocks):
-        Mr, Nc, R = (700, 500, 32) if blocks is None else (1100, 2100, 32)
+                            blocks, R):
+        Mr, Nc = (700, 500) if blocks is None else (1100, 2100)
         rows, cols, meta, blk, vals, rng = _tile_setup(
             Mr=Mr, Nc=Nc, group=group, blocks=blocks)
         A = rng.standard_normal((Mr, R)).astype(np.float32)
