@@ -5,19 +5,21 @@ the sparse matrix: every generator here returns a unit-valued
 :class:`~distributed_sddmm_tpu.utils.coo.HostCOO` whose pattern is the
 attention mask (``vals == 1`` at attended positions — the ``gate != 0``
 indicator the softmax kernels read; callers may rescale values to carry
-per-edge logit weights or temperature). Three families, the structured
+per-edge logit weights or temperature). Four families, the structured
 regimes the codegen band selector must degenerate gracefully on
 (ROADMAP item 5 / NeutronSparse-style structure routing):
 
 * :func:`sliding_window` — each token attends to its ±w neighborhood
   (near-uniform nnz/row: the anti-power-law stress case for banding);
+* :func:`causal_window` — each token attends to itself and the
+  ``window - 1`` tokens before it (a decoder's sliding-window layer);
 * :func:`bigbird` — sliding window ∪ global tokens (attend/attended
   everywhere) ∪ seeded random links, the BigBird recipe;
 * :func:`graph_mask` — the pattern of an existing sparse matrix (the
   GAT adjacency path: attention over graph edges).
 
-:func:`from_spec` parses the ``--mask`` CLI grammar
-(``window:8``, ``bigbird:w=8,g=2,r=2``, ``graph``) so bench records can
+:func:`from_spec` parses the ``--mask`` CLI grammar (``window:8``,
+``causal:128``, ``bigbird:w=8,g=2,r=2``, ``graph``) so bench records can
 carry the mask as one printable config axis.
 
 Import discipline: numpy + HostCOO only (no jax) — mask construction is
@@ -49,6 +51,23 @@ def sliding_window(n: int, window: int = 8) -> HostCOO:
     rows = np.repeat(np.arange(n, dtype=np.int64), offs.size)
     cols = rows + np.tile(offs, n)
     keep = (cols >= 0) & (cols < n)
+    return HostCOO(
+        rows=rows[keep], cols=cols[keep], vals=np.ones(int(keep.sum())),
+        M=n, N=n,
+    )
+
+
+def causal_window(n: int, window: int = 128) -> HostCOO:
+    """Each row ``i`` attends to columns ``[i-window+1, i]`` (clipped at
+    row 0): ``window`` keys with the diagonal, the causal sliding window
+    of a decoder layer. ``n*window - window*(window-1)/2`` nonzeros for
+    ``n >= window``; rows sorted, columns ascending within a row."""
+    if window < 1:
+        raise ValueError(f"window must be >= 1, got {window}")
+    offs = np.arange(-(window - 1), 1, dtype=np.int64)
+    rows = np.repeat(np.arange(n, dtype=np.int64), offs.size)
+    cols = rows + np.tile(offs, n)
+    keep = cols >= 0
     return HostCOO(
         rows=rows[keep], cols=cols[keep], vals=np.ones(int(keep.sum())),
         M=n, N=n,
@@ -103,6 +122,7 @@ def from_spec(
     into bench records and the runstore config axes verbatim):
 
     * ``window:<w>`` — :func:`sliding_window` with half-width ``w``;
+    * ``causal:<w>`` — :func:`causal_window` with ``w`` keys a row;
     * ``bigbird:w=<w>,g=<g>,r=<r>`` — :func:`bigbird` (all keys
       optional, defaults ``w=8,g=2,r=2``);
     * ``graph`` — :func:`graph_mask` over ``graph`` (the benchmark's
@@ -111,6 +131,8 @@ def from_spec(
     fam, _, rest = spec.partition(":")
     if fam == "window":
         return sliding_window(n, _int_param(spec, "window", rest, "w", 8))
+    if fam == "causal":
+        return causal_window(n, _int_param(spec, "causal", rest, "w", 128))
     if fam == "bigbird":
         kw = {"w": 8, "g": 2, "r": 2}
         for part in filter(None, rest.split(",")):
@@ -135,7 +157,7 @@ def from_spec(
             "(parse_dynamic_spec)"
         )
     raise ValueError(
-        f"unknown mask spec {spec!r}; expected window:<w>, "
+        f"unknown mask spec {spec!r}; expected window:<w>, causal:<w>, "
         "bigbird:w=..,g=..,r=.., graph, or topk:<k>"
     )
 

@@ -334,6 +334,13 @@ class FlatTiles:
         return self.kernel.attn_normalize(
             rows_f, gate_f, z_f, m, d).reshape(shape)
 
+    @staticmethod
+    def sink_stats(sink):
+        """A per-row sink logit ``[out_rows]`` as one more softmax
+        partition in :meth:`softmax`'s stat frame: ``(m, d) = (sink, 1)``
+        (it joins every denominator and carries no value)."""
+        return sink, jnp.ones_like(sink)
+
 
 def attn_merge_stats(stats):
     """Combine per-partition masked-softmax row stats into one frame.

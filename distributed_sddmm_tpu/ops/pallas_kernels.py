@@ -488,6 +488,45 @@ def _make_attn_norm_body(G):
     return body
 
 
+#: Chunks whose packed metadata one kernel launch prefetches into scalar
+#: memory (SMEM). The metadata is one int32 per chunk and a v5e core has
+#: 1 MiB of SMEM, which 2^18 chunks fill exactly (the logM=18 R-mat at
+#: 512x512 blocks is 262,144 chunks, and Mosaic refuses it). A longer
+#: chunk list runs as consecutive launches of at most this many chunks
+#: (:func:`_tile_call`, :func:`_attn_call`).
+MAX_PREFETCH_CHUNKS = 1 << 17
+
+
+def _segment_steps(group: int) -> int:
+    """Grid steps of ``group`` chunks that one launch walks: a chunk
+    list longer than :data:`MAX_PREFETCH_CHUNKS` runs as several."""
+    return max(MAX_PREFETCH_CHUNKS // group, 1)
+
+
+def launches(n_chunks: int, group: int) -> int:
+    """Launches one chunk-list kernel op makes over ``n_chunks`` chunks
+    (:func:`_tile_call`, :func:`_attn_call`)."""
+    steps = max(n_chunks // group, 1)
+    return -(-steps // _segment_steps(group))
+
+
+def _open_close(m):
+    """A segment's metadata ``m`` with its first chunk opening and its
+    last closing the accumulator (set in turn, so that a one-chunk
+    segment carries both flags)."""
+    m = m.at[0].set(m[0] | 1)
+    return m.at[-1].set(m[-1] | 2)
+
+
+def _flushed(m, gr_blocks, single_step):
+    """``[gr_blocks]`` bool: the row blocks a segment with metadata ``m``
+    wrote, every step's window in the single-step bodies and the flushed
+    groups' windows otherwise (the rest of its output is never
+    written)."""
+    wrote = jnp.ones(m.shape, bool) if single_step else ((m >> 1) & 1) == 1
+    return jnp.zeros(gr_blocks, bool).at[_meta_gr(m, slice(None))].max(wrote)
+
+
 def kernel_name(op: str) -> str:
     """A launch's name, ``<op>_tiles``: the kernel's Mosaic name and the
     name of its custom-call instruction in compiled HLO and in a device
@@ -511,7 +550,15 @@ def _attn_call(
     logits, both in chunk layout [C, CHUNK]; ``m``/``d`` are the merged
     (rows_pad, 1) row stats (``attn_norm`` only). Returns ``(m, d)``
     for ``attn_reduce``, the normalized chunk values for ``attn_norm``.
-    """
+
+    A chunk list longer than :data:`MAX_PREFETCH_CHUNKS` runs as one
+    launch per segment of at most that many chunks, as
+    :func:`_tile_call` does. An ``attn_reduce`` segment closes its
+    running stats at its own ends, so a row-block group cut by a segment
+    boundary leaves partial stats on each side; each segment's stats
+    count only at the row blocks it flushed, and the segments' stats
+    merge by :func:`ops.kernels.attn_merge_stats`. An ``attn_norm``
+    segment writes its own chunks."""
     C = lr.shape[0]
     G = group
     if C % G:
@@ -521,7 +568,6 @@ def _attn_call(
     gv3 = gv.reshape(steps, G, CHUNK)
     zv3 = zv.reshape(steps, G, CHUNK)
 
-    chunk_spec = pl.BlockSpec((1, G, CHUNK), lambda t, mm: (t, 0, 0))
     md_spec = pl.BlockSpec((bm, 1), lambda t, mm: (_meta_gr(mm, t * G), 0))
     md_shape = jax.ShapeDtypeStruct((gr_blocks * bm, 1), jnp.float32)
 
@@ -532,45 +578,66 @@ def _attn_call(
             body = _make_attn_reduce_body(G)
             scratch = [pltpu.VMEM((bm, 1), jnp.float32),
                        pltpu.VMEM((bm, 1), jnp.float32)]
-        in_specs = [chunk_spec, chunk_spec, chunk_spec]
         operands = (lr3, gv3, zv3)
-        out_specs, out_shapes = [md_spec, md_spec], [md_shape, md_shape]
     elif op == "attn_norm":
         body, scratch = _make_attn_norm_body(G), []
-        in_specs = [chunk_spec, chunk_spec, chunk_spec, md_spec, md_spec]
         operands = (lr3, gv3, zv3, m, d)
-        out_specs = [chunk_spec]
-        out_shapes = [jax.ShapeDtypeStruct((steps, G, CHUNK), jnp.float32)]
     else:
         raise ValueError(op)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(steps,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
-    outs = pl.pallas_call(
-        body,
-        grid_spec=grid_spec,
-        out_shape=out_shapes,
-        compiler_params=compat.pallas_tpu_compiler_params(
-            dimension_semantics=("arbitrary",)
-        ),
-        interpret=interpret,
-        name=kernel_name(op),
-    )(meta, *operands)
-    return outs if op == "attn_reduce" else outs[0]
+    def launch(mm, s0, n):
+        """Steps ``[s0, s0 + n)`` with ``mm`` = their metadata."""
+        if s0:
+            chunk_in = pl.BlockSpec((1, G, CHUNK),
+                                    lambda t, mm: (t + s0, 0, 0))
+        else:
+            chunk_in = pl.BlockSpec((1, G, CHUNK), lambda t, mm: (t, 0, 0))
+        if op == "attn_reduce":
+            in_specs = [chunk_in, chunk_in, chunk_in]
+            out_specs, out_shapes = [md_spec, md_spec], [md_shape, md_shape]
+        else:
+            in_specs = [chunk_in, chunk_in, chunk_in, md_spec, md_spec]
+            out_specs = [pl.BlockSpec((1, G, CHUNK), lambda t, mm: (t, 0, 0))]
+            out_shapes = [jax.ShapeDtypeStruct((n, G, CHUNK), jnp.float32)]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n,),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+        )
+        outs = pl.pallas_call(
+            body,
+            grid_spec=grid_spec,
+            out_shape=out_shapes,
+            compiler_params=compat.pallas_tpu_compiler_params(
+                dimension_semantics=("arbitrary",)
+            ),
+            interpret=interpret,
+            name=kernel_name(op),
+        )(mm, *operands)
+        return outs if op == "attn_reduce" else outs[0]
 
+    seg = _segment_steps(G)
+    if steps <= seg:
+        return launch(meta, 0, steps)
 
-#: Chunks whose packed metadata one kernel launch prefetches into scalar
-#: memory (SMEM). The metadata is one int32 per chunk and a v5e core has
-#: 1 MiB of SMEM, which 2^18 chunks fill exactly (the logM=18 R-mat at
-#: 512x512 blocks is 262,144 chunks, and Mosaic refuses it). A longer
-#: chunk list runs as consecutive launches of at most this many chunks
-#: (:func:`_tile_call`).
-MAX_PREFETCH_CHUNKS = 1 << 17
+    parts = []
+    for s0 in range(0, steps, seg):
+        n = min(seg, steps - s0)
+        mm = meta[s0 * G:(s0 + n) * G]
+        if op == "attn_norm":
+            parts.append(launch(mm, s0, n))
+            continue
+        if not single_step:
+            mm = _open_close(mm)
+        ms, ds = launch(mm, s0, n)
+        rows = jnp.repeat(_flushed(mm, gr_blocks, single_step), bm)[:, None]
+        parts.append((jnp.where(rows, ms, jnp.float32(ATTN_NEG)),
+                      jnp.where(rows, ds, 0.0)))
+    if op == "attn_norm":
+        return jnp.concatenate(parts, axis=0)
+    return attn_merge_stats(parts)
 
 
 @functools.partial(
@@ -675,7 +742,7 @@ def _tile_call(
             name=kernel_name(op),
         )(m, lr3, lc3, sv3, *dense)
 
-    seg = max(MAX_PREFETCH_CHUNKS // G, 1)
+    seg = _segment_steps(G)
     if steps <= seg:
         return launch(meta, 0, steps)
 
@@ -684,17 +751,12 @@ def _tile_call(
         n = min(seg, steps - s0)
         m = meta[s0 * G:(s0 + n) * G]
         if op != "sddmm" and not single_step:
-            m = m.at[0].set(m[0] | 1).at[-1].set(m[-1] | 2)
+            m = _open_close(m)
         res = launch(m, s0, n)
         if op != "spmm":
             mids.append(res[-1])
         if op != "sddmm":
-            # Row blocks this segment wrote: every step's window in the
-            # single-step bodies, the flushed groups' windows otherwise.
-            wrote = (jnp.ones(m.shape, bool) if single_step
-                     else ((m >> 1) & 1) == 1)
-            flushed = jnp.zeros(gr_blocks, bool).at[
-                _meta_gr(m, slice(None))].max(wrote)
+            flushed = _flushed(m, gr_blocks, single_step)
             part = res[0].reshape(R, gr_blocks, bm)
             outs.append(jnp.where(flushed[None, :, None], part, 0.0))
     result = []
@@ -1142,3 +1204,10 @@ class BlockedTiles:
             for s in range(T)
         ]
         return jnp.stack(probs)
+
+    def sink_stats(self, sink):
+        """A per-row sink logit ``[out_rows]`` as one more softmax
+        partition in the ``[rows_pad, 1]`` stat frame of :meth:`softmax`:
+        ``(m, d) = (sink, 1)``; pad rows hold no entries."""
+        m = jnp.pad(sink, (0, self.rows_pad - sink.shape[0]))[:, None]
+        return m, jnp.ones_like(m)

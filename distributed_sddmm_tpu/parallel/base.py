@@ -520,29 +520,39 @@ class DistributedSparse(abc.ABC):
     def _note_tile_metrics(self) -> None:
         """Record each tile set's counted padded-lane fraction, its block
         geometry (``block_rows`` x ``block_cols``, in the encoding's own
-        orientation), its chunks a (device, tile) bucket, and whether its
+        orientation), its chunks a (device, tile) bucket, whether its
         launches run the step-batched kernel body (``batched_step``, 1 or
-        0) as per-op metric gauges (scraped via ``/metrics`` and landed in
-        bench records): the waste the density rule and the codegen banked
-        variants exist to shrink, and what they chose. Called by strategy
-        constructors once tiles exist."""
-        a_side = ("sddmmA", "spmmA", "fusedSpMM", "cgStep", "gatLayer")
+        0), and for the attention ops the launches each epilogue kernel
+        makes (``attn_segments``, at most ``MAX_PREFETCH_CHUNKS`` chunks
+        a launch) as per-op metric gauges (scraped via ``/metrics`` and
+        landed in bench records): the waste the density rule and the
+        codegen banked variants exist to shrink, and what they chose.
+        Called by strategy constructors once tiles exist."""
+        attn = ("fusedAttn", "attnSoftmax", "swaLayer")
+        a_side = ("sddmmA", "spmmA", "fusedSpMM", "cgStep", "gatLayer",
+                  *attn)
         b_side = ("sddmmB", "spmmB")
+        from distributed_sddmm_tpu.ops.pallas_kernels import launches
+
         for tiles, ops in ((self.S_tiles, a_side), (self.ST_tiles, b_side)):
             frac = getattr(tiles, "blk_pad_frac", None)
             if frac is None:
                 continue
             bm, bn = tiles.blk_geom[:2]
+            n_chunks = int(tiles.blk_meta.shape[-1])
             bands = getattr(tiles, "blk_bands", None)
             if bands:
                 batched = all(b.body in ("batched", "single") for b in bands)
+                segments = sum(launches(b.c1 - b.c0, b.group) for b in bands)
             else:
                 batched = getattr(self.kernel, "batch_step", False)
+                segments = launches(n_chunks, tiles.blk_geom[4])
             for op in ops:
                 self.metrics.note(
                     op, padded_lane_frac=round(frac, 6), block_rows=bm,
-                    block_cols=bn, n_chunks=int(tiles.blk_meta.shape[-1]),
+                    block_cols=bn, n_chunks=n_chunks,
                     batched_step=int(batched),
+                    **({"attn_segments": segments} if op in attn else {}),
                 )
 
     def initial_shift(self, A, B, mode: KernelMode):

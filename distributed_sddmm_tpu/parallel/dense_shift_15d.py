@@ -35,6 +35,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from distributed_sddmm_tpu.common import MatMode, divide_round_up
+from distributed_sddmm_tpu.ops.kernels import attn_merge_stats
 from distributed_sddmm_tpu.parallel.base import DistributedSparse
 from distributed_sddmm_tpu.parallel.loops import (
     abl_all_gather, abl_ppermute, abl_psum_scatter, ring_loop,
@@ -49,6 +50,9 @@ _DENSE_SPEC = P(("rows", "cols"), None)
 # The layers axis is unused (nh=1); leaving it out of the tile spec lets
 # shard_map statically prove dense outputs are replicated over it.
 _TILE_SPEC = P("rows", "cols", None, None, None)
+#: The fused-attention programs: keys as values (``attn``), a value
+#: operand of its own (``_v``), a per-row sink logit (``_sink``).
+ATTN_OPS = ("attn", "attn_v", "attn_sink", "attn_v_sink")
 
 
 class DenseShift15D(DistributedSparse):
@@ -211,13 +215,14 @@ class DenseShift15D(DistributedSparse):
     def _program(self, op: str, use_st: bool):
         """Build (and cache) the jitted shard_map program for one op.
 
-        ``op`` in {"sddmm", "spmm", "fused", "fused_twopass", "attn",
-        "attn_softmax"}; ``use_st`` selects the transposed tile set
-        (B-output variants). The moving operand always rotates along the
-        ``rows`` axis; the stationary operand is replicated over the
-        ``cols`` axis. The local tile work goes through the tile seam
-        (``_tile_ops``): flat XLA ops, or the feature-major Pallas tile
-        kernels when the tile set carries chunk lists.
+        ``op`` in {"sddmm", "spmm", "fused", "fused_twopass",
+        "attn_softmax"} or one of ``ATTN_OPS``; ``use_st`` selects the
+        transposed tile set (B-output variants). The moving operand
+        always rotates along the ``rows`` axis; the stationary operand
+        is replicated over the ``cols`` axis. The local tile work goes
+        through the tile seam (``_tile_ops``): flat XLA ops, or the
+        feature-major Pallas tile kernels when the tile set carries
+        chunk lists.
         """
         key = self._program_cache_key(op, use_st)
         if key in self._programs:
@@ -367,7 +372,7 @@ class DenseShift15D(DistributedSparse):
 
             in_specs, out_specs = two_dense_in, pair_out
 
-        elif op in ("fused_twopass", "attn"):
+        elif op in ("fused_twopass", *ATTN_OPS):
             # fusion 1, "replication reuse": one all_gather feeds two ring
             # passes (SDDMM then SpMM) in one compiled program — the
             # functional equivalent of `initial_replicate=false` on the
@@ -377,21 +382,45 @@ class DenseShift15D(DistributedSparse):
             # of the device's rows lands before any weight is formed
             # (segment stats + a [rows]-vector merge over the replication
             # axis) — one compiled program, no dense logits materialized.
+            # ``attn_v*`` take a value operand of its own width, which the
+            # SpMM pass rotates in the keys' place (the keys' pass then
+            # need not bring them home); ``attn*_sink`` a per-row sink
+            # logit ``[rows, 1]`` in the stationary operand's layout,
+            # merged in after the cross-device merge (each device holds
+            # only its columns' partial stats).
+            with_v = op in ("attn_v", "attn_v_sink")
+            with_sink = op.endswith("_sink")
+            n_extra = int(with_v) + int(with_sink)
 
-            def prog(stat, mov, *targs):
+            def prog(stat, mov, *args):
+                extra, targs = args[:n_extra], args[n_extra:]
                 fields, vals = unpack(targs)
                 x_r = form.rows(replicate(stat))
                 out_vals, mov = sddmm_pass(
-                    x_r, mov, fields, vals, complete_rotation=True
+                    x_r, mov, fields, vals, complete_rotation=not with_v
                 )
-                if op == "attn":
-                    out_vals = form.softmax(
-                        fields, vals, out_vals, self._merge_stats_cols
-                    )
+                if op != "fused_twopass":
+                    merge = self._merge_stats_cols
+                    if with_sink:
+                        sink = extra[-1]
+                        if c > 1:
+                            # f32 on every wire, as the stat merge is.
+                            sink = abl_all_gather(sink, "cols", axis=0,
+                                                  tiled=True, size=c)
+                        sink_stats = form.sink_stats(sink[:, 0])
+
+                        def merge(m, d):
+                            return attn_merge_stats(
+                                [self._merge_stats_cols(m, d), sink_stats])
+                    out_vals = form.softmax(fields, vals, out_vals, merge)
+                if with_v:
+                    mov = extra[0]
                 return (spmm_pass(mov, fields, out_vals),
                         out_vals.reshape(1, 1, 1, T, max_nnz))
 
-            in_specs, out_specs = two_dense_in, pair_out
+            in_specs = (_DENSE_SPEC, _DENSE_SPEC, *([_DENSE_SPEC] * n_extra),
+                        *two_dense_in[2:])
+            out_specs = pair_out
 
         elif op == "attn_softmax":
             # Standalone masked softmax over tile-layout logits — the
@@ -526,7 +555,8 @@ class DenseShift15D(DistributedSparse):
     # Fused block-sparse attention (SDDMM → masked softmax → SpMM)
     # ------------------------------------------------------------------ #
 
-    def fused_attention(self, A, B, s_vals, mode: MatMode = MatMode.A):
+    def fused_attention(self, A, B, s_vals, mode: MatMode = MatMode.A,
+                        V=None, sink=None):
         """One compiled program: SDDMM logits at the mask pattern, a
         numerically-stable row-wise masked softmax over the sparse
         values (``s_vals != 0`` is the mask indicator; fully masked
@@ -534,19 +564,29 @@ class DenseShift15D(DistributedSparse):
         logit matrix ever exists. Returns ``(new_dense, probs)`` with
         ``probs`` the attention weights in tile layout. Independent of
         ``fusion_approach`` (the softmax forces the twopass structure:
-        a row's denominator needs its complete logit set)."""
-        if mode == MatMode.A:
-            prog = self._program("attn", use_st=False)
-            return self._timed(
-                "fusedAttn", prog, A, B,
-                *self._tile_args(self.S_tiles, s_vals),
-            )
-        prog = self._program("attn", use_st=True)
+        a row's denominator needs its complete logit set).
+
+        ``V`` (A mode): the aggregated operand, in B's rows and of its
+        own width; by default the keys ``B`` themselves. ``sink``
+        (A mode): a per-row sink logit ``[M_pad, 1]`` in A's sharding,
+        which joins each row's softmax denominator and carries no
+        value."""
+        prog, operands = self.attention_program(
+            s_vals, mode, v=V is not None, sink=sink is not None)
+        extra = tuple(x for x in (V, sink) if x is not None)
         return self._timed(
-            "fusedAttn", prog, B, A,
-            *self._tile_args(self.ST_tiles, s_vals),
-            _comm_op="fusedAttnB",
+            "fusedAttn", prog, A, B, *extra, *operands,
+            _comm_op="fusedAttnB" if mode == MatMode.B else None,
         )
+
+    @staticmethod
+    def _attn_op(mode: MatMode, v: bool, sink: bool) -> str:
+        """The fused-attention program's op for its operands."""
+        if (v or sink) and mode != MatMode.A:
+            raise NotImplementedError(
+                "fused attention takes a value operand or sinks in A mode "
+                "only (output rows are the mask's rows)")
+        return "attn" + ("_v" if v else "") + ("_sink" if sink else "")
 
     def attention_softmax(self, s_vals, logits, mode: MatMode = MatMode.A):
         """Standalone masked softmax over tile-layout logit values — the
@@ -573,14 +613,19 @@ class DenseShift15D(DistributedSparse):
         )
         return out, probs
 
-    def attention_program(self, s_vals, mode: MatMode = MatMode.A):
-        """Raw-program accessor: ``f(A, B) -> (out, probs)`` for one
+    def attention_program(self, s_vals, mode: MatMode = MatMode.A,
+                          v: bool = False, sink: bool = False):
+        """Raw-program accessor: ``(f, operands)`` with
+        ``f(A, B, [V,] [sink,] *operands) -> (out, probs)`` running one
         compiled fused-attention dispatch (no host-side timing wrappers
-        — serving and AOT compiles chain this)."""
+        — models and AOT compiles chain this); ``V`` and ``sink`` as in
+        :meth:`fused_attention`, present when ``v`` / ``sink`` say so;
+        ``operands`` are the tile structure and ``s_vals``."""
+        op = self._attn_op(mode, v, sink)
         use_st = mode == MatMode.B
         tiles = self.ST_tiles if use_st else self.S_tiles
-        prog = self._program("attn", use_st)
-        args = self._tile_args(tiles, s_vals)
+        prog = self._program(op, use_st)
+        operands = self._tile_args(tiles, s_vals)
         if use_st:
-            return lambda A, B: prog(B, A, *args)
-        return lambda A, B: prog(A, B, *args)
+            return (lambda A, B, *ops: prog(B, A, *ops)), operands
+        return prog, operands

@@ -127,7 +127,8 @@ def cost_log_len() -> int:
 _OP_KEY_TOKENS = {
     "fusedSpMM": ("fused", "fused_twopass"),
     "fusedSpMMB": ("fused", "fused_twopass"),
-    "fusedAttn": ("attn",), "fusedAttnB": ("attn",),
+    "fusedAttn": ("attn", "attn_v", "attn_sink", "attn_v_sink"),
+    "fusedAttnB": ("attn",),
     "attnSoftmax": ("attn_softmax",),
     "sddmmA": ("sddmm",), "sddmmB": ("sddmm",),
     "spmmA": ("spmm",), "spmmB": ("spmm",),

@@ -71,14 +71,18 @@ def fused_spmm_b(S: HostCOO, A: np.ndarray, B: np.ndarray) -> np.ndarray:
     return spmm_b(S.with_values(mid), A)
 
 
-def masked_softmax(S: HostCOO, logits: np.ndarray) -> np.ndarray:
+def masked_softmax(
+    S: HostCOO, logits: np.ndarray, sink: np.ndarray | None = None
+) -> np.ndarray:
     """Row-wise masked softmax over the sparse logit values (float64).
 
     Entries with ``S.vals == 0`` are masked out (the same ``gate != 0``
     indicator the device kernels use); a row whose entries are all
     masked — or that has no entries at all — gets exactly-zero weights,
     never NaN. The max subtraction matches the device kernels' stable
-    formulation so f32 comparisons are apples-to-apples.
+    formulation so f32 comparisons are apples-to-apples. ``sink``
+    (``[M]``): a per-row logit that joins the row's max and denominator
+    and carries no value.
     """
     from distributed_sddmm_tpu.ops.kernels import ATTN_NEG
 
@@ -86,10 +90,14 @@ def masked_softmax(S: HostCOO, logits: np.ndarray) -> np.ndarray:
     gate = S.vals != 0
     m = np.full(S.M, ATTN_NEG)
     np.maximum.at(m, S.rows[gate], z[gate])
+    if sink is not None:
+        m = np.maximum(m, sink)
     e = np.zeros_like(z)
     e[gate] = np.exp(z[gate] - m[S.rows[gate]])
     d = np.zeros(S.M)
     np.add.at(d, S.rows, e)
+    if sink is not None:
+        d += np.exp(sink - m)
     out = np.zeros_like(z)
     ok = gate & (d[S.rows] > 0)
     out[ok] = e[ok] / d[S.rows[ok]]
@@ -97,13 +105,15 @@ def masked_softmax(S: HostCOO, logits: np.ndarray) -> np.ndarray:
 
 
 def fused_attention_a(
-    S: HostCOO, A: np.ndarray, B: np.ndarray
+    S: HostCOO, A: np.ndarray, B: np.ndarray, V: np.ndarray | None = None,
+    sink: np.ndarray | None = None,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Block-sparse attention reference: SDDMM logits → row-wise masked
-    softmax → SpMM aggregation, all float64. Returns ``(out [M, R],
-    probs [nnz])`` in S's nonzero order."""
-    probs = masked_softmax(S, sddmm(S, A, B))
-    return spmm_a(S.with_values(probs), B), probs
+    softmax (with ``sink``, see :func:`masked_softmax`) → SpMM
+    aggregation of ``V`` (``B`` by default), all float64. Returns
+    ``(out [M, R_v], probs [nnz])`` in S's nonzero order."""
+    probs = masked_softmax(S, sddmm(S, A, B), sink)
+    return spmm_a(S.with_values(probs), B if V is None else V), probs
 
 
 def dummy_dense(n_rows: int, R: int, dtype=np.float64) -> np.ndarray:

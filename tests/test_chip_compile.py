@@ -9,6 +9,10 @@ device memory) fails here and costs no chip time. The strategy is built on
 the host CPU and retargeted at one described device; its tile arrays enter
 the compiled programs as arguments, as the models pass them.
 
+One KV group of MiMo-V2-Flash's sliding-window attention layer
+compiles at its published widths over 2,048 tokens, its attention
+epilogue in several SMEM segments.
+
 Then each strategy's SDDMM and SpMM rings compile on four described
 chips at a small size, and each kernel's launch compiles alone. The last
 two run chip_smoke.py's phases at logM=8 on the CPU mesh, and check that
@@ -136,6 +140,61 @@ def test_gat_layer_compiles_for_v5e(smoke_alg):
                              sharding=alg.a_sharding())
     args = (X, _sds(alg, layer.weights, P()), *_sds(alg, operands))
     _check_compiled(prog.lower(*args).compile())
+
+
+def test_swa_layer_compiles_for_v5e(topo, monkeypatch):
+    """One KV group of MiMo-V2-Flash's window layer at its published
+    widths (hidden 4096, 8 query heads, q/k 192, v 128, window 128) over
+    2,048 tokens, the attention epilogue's chunk list cut into several
+    SMEM segments as the 32,768-token prefill's is: the whole layer,
+    projections, RoPE, the fused attention with V and sinks, and Wo, is
+    one program of Mosaic kernels that fits a v5e."""
+    import re
+
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from distributed_sddmm_tpu import masks
+    from distributed_sddmm_tpu.models.swa import SlidingWindowAttention
+    from distributed_sddmm_tpu.ops import pallas_kernels
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    T = 2048
+    model = SlidingWindowAttention(
+        masks.causal_window(T, 128), 8, 4096, 192, 128, 64, 10000.0, 0.707,
+        kernel=PallasKernel(precision="bf16", interpret=False),
+        devices=jax.devices()[:1])
+    alg = model.d_ops
+    C = alg.S_tiles.blk_lr.shape[-2]
+    monkeypatch.setattr(pallas_kernels, "MAX_PREFETCH_CHUNKS", C // 3)
+    g = alg.grid
+    alg.grid = make_grid(g.nr, g.nc, g.nh, adjacency=g.adjacency,
+                         devices=[topo.devices[0]])
+    alg._programs.clear()
+    pallas_kernels._attn_call.clear_cache()
+    pallas_kernels._tile_call.clear_cache()
+    try:
+        assert pallas_kernels.launches(C, alg.S_tiles.blk_geom[4]) > 2
+        prog, operands = model._layer_program()
+        x = jax.ShapeDtypeStruct((T, 4096), jnp.float32,
+                                 sharding=NamedSharding(alg.grid.mesh, P()))
+        args = (x, _sds(alg, model.params, P()), _sds(alg, model.cos, P()),
+                _sds(alg, model.sin, P()), *_sds(alg, operands))
+        compiled = prog.lower(*args).compile()
+    finally:
+        pallas_kernels._attn_call.clear_cache()
+        pallas_kernels._tile_call.clear_cache()
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+    _check_compiled(compiled)
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", compiled.as_text())
+    kinds = [c.split(".")[0] for c in calls]
+    for op in ("sddmm", "spmm", "attn_reduce", "attn_norm"):
+        assert kinds.count(pallas_kernels.kernel_name(op)) >= (
+            3 if op.startswith("attn") else 1), kinds
 
 
 @pytest.fixture(scope="module")

@@ -57,7 +57,9 @@ def test_two_process_pod_matches_single_process():
                     rec = json.loads(l)
                 except ValueError:
                     continue
-                if rec.get("probe"):
+                # Gloo's C++ log lines interleave on stdout, so a torn
+                # one ("1") can parse as JSON: records are objects.
+                if isinstance(rec, dict) and rec.get("probe"):
                     probe_lines.append(rec)
             if p.returncode != 0:
                 if any(r.get("probe_ok") for r in probe_lines):
@@ -80,7 +82,7 @@ def test_two_process_pod_matches_single_process():
                     rec = json.loads(l)
                 except ValueError:
                     continue
-                if not rec.get("probe"):
+                if isinstance(rec, dict) and not rec.get("probe"):
                     results[rec["pid"]] = rec
                     break
     finally:
